@@ -87,12 +87,12 @@ def _median(f, iters=TIMED_ITERS):
 
 def bench_aggregate(schema, rows, max_ht, make_engine, S, n_concurrent=32,
                     depth=6, n_batches=12):
-    """Aggregate scans two ways: single-scan latency (one fetch cycle on
-    the tunnel link dominates it) and SERVER THROUGHPUT — concurrent
-    aggregate scans pipelined through the async batch API, the shape a
-    tserver actually runs, where the link round trip amortizes across
-    whole batches and the device's scan rate is what's measured. The
-    headline is the throughput number; latency rides in the details."""
+    """Aggregate scans two ways: single-scan latency (one synchronous
+    device->host fetch cycle sits inside it) and SERVER THROUGHPUT —
+    concurrent aggregate scans pipelined through the async batch API,
+    the shape a tserver actually runs, where the fetch cycle amortizes
+    across whole batches and the device's scan rate is what's measured.
+    The headline is the throughput number; latency rides in the details."""
     import collections
 
     tpu = make_engine("tpu", schema, {"rows_per_block": 2048})
@@ -1538,14 +1538,22 @@ def _section_subprocess(name, timeout_s=1800):
             d = json.loads(line[2:])
         except ValueError:
             continue
-        if isinstance(d, dict) and "metric" in d and \
-                d["metric"] != "jit_compiles_per_entry":
+        if isinstance(d, dict) and d.get("metric") not in (
+                None, "jit_compiles_per_entry", "device"):
             subs.append(d)
     if not subs:
         subs = [{"metric": name, "error": f"section subprocess rc={rc}"}]
     return subs, rc
 
 
+# Sections whose numbers are device metrics (they build a "tpu" engine or
+# run device kernels): refused on any backend but the TPU, because the
+# same code runs on XLA's CPU backend and would print CPU timings under
+# device metric names.
+_DEVICE_SECTIONS = frozenset((
+    "aggregate", "ycsb_e", "point_read", "ycsb_mix", "multisource",
+    "oversubscribed", "oversubscribed_friendly", "kernel_scan", "tpch",
+    "write", "device_flush", "compact", "traffic"))
 # Sections that consume the shared engine pair bench_aggregate builds.
 _DEP_AGG = ("aggregate", "ycsb_e", "point_read", "multisource")
 # Sections that consume the shared (schema, rows) dataset.
@@ -1553,23 +1561,37 @@ _NEED_ROWS = _DEP_AGG + ("oversubscribed", "write", "device_flush",
                          "compact")
 
 
-def main():
+def main() -> int:
     import yugabyte_db_tpu.storage.tpu_engine  # noqa: F401 registers 'tpu'
     from yugabyte_db_tpu import storage as S
     from yugabyte_db_tpu.storage import make_engine
+    from yugabyte_db_tpu.utils import jitting
 
+    # Exported to the section children below, so one command shares one
+    # compile cache across its processes.
+    jitting.enable_compile_cache()
     if COMPILE_WITNESS or CWITNESS_OUT:
-        from yugabyte_db_tpu.utils import jitting
         jitting.enable_compile_witness()
 
     def want(name):
         return (ONLY is None or name in ONLY) and name not in SKIP
 
-    sections = {}  # name -> rc (0 ok; >0 exception; <0 signal/timeout)
+    # name -> rc (0 ok; 1 exception; 2 refused; <0 signal/timeout)
+    sections = {}
     subs = []
+    device = {}
+
+    def refused(name) -> bool:
+        if name not in _DEVICE_SECTIONS or device["platform"] == "tpu":
+            return False
+        sections[name] = 2
+        subs.append({"metric": name, "error":
+                     f"refused: device section on the "
+                     f"{device['platform']} backend"})
+        return True
 
     def run(name, fn):
-        if not want(name):
+        if not want(name) or refused(name):
             return
         try:
             out = fn()
@@ -1581,18 +1603,29 @@ def main():
 
     # Cluster sections first (host-CPU-bound: they measure low after the
     # TPU workloads' background threads/memory are resident). On a full
-    # run each one is isolated in a child interpreter; with --only we ARE
-    # the child (or the user asked for exactly this section): in-process.
-    for cname, cfn in (("cluster_write", bench_cluster_write),
-                       ("ycsb_a_cluster", bench_ycsb_a_cluster),
-                       ("traffic", bench_traffic)):
-        if not want(cname):
-            continue
-        if ONLY is None:
-            csubs, rc = _section_subprocess(cname)
-            sections[cname] = rc
-            subs.extend(csubs)
-        else:
+    # run each one is isolated in a child interpreter, and they run
+    # BEFORE this process initialises its JAX backend: a chip belongs to
+    # one process, and a child that needs it ("traffic") would fail or
+    # hang under a parent that holds it. With --only we ARE the child
+    # (or the user asked for exactly this section): in-process, below.
+    cluster_sections = (("cluster_write", bench_cluster_write),
+                        ("ycsb_a_cluster", bench_ycsb_a_cluster),
+                        ("traffic", bench_traffic))
+    if ONLY is None:
+        for cname, _cfn in cluster_sections:
+            if want(cname):
+                csubs, rc = _section_subprocess(cname)
+                sections[cname] = rc
+                subs.extend(csubs)
+
+    import jax
+
+    devs = jax.devices()
+    device.update(platform=devs[0].platform, kind=devs[0].device_kind,
+                  count=len(devs), jax=jax.__version__)
+    print("# " + json.dumps({"metric": "device", **device}))
+    if ONLY is not None:
+        for cname, cfn in cluster_sections:
             run(cname, cfn)
 
     schema = rows = max_ht = None
@@ -1603,7 +1636,7 @@ def main():
         rows, max_ht = _make_rows(schema, NUM_KEYS)
 
     tpu = cpu = headline = None
-    if any(want(n) for n in _DEP_AGG):
+    if any(want(n) for n in _DEP_AGG) and not refused("aggregate"):
         try:
             tpu, cpu, versions, headline = bench_aggregate(
                 schema, rows, max_ht, make_engine, S)
@@ -1652,6 +1685,7 @@ def main():
     if headline is not None and want("aggregate"):
         headline["details"] = details
         headline["sections"] = sections
+        headline["device"] = device
         headline["baseline_note"] = (
             "vs_baseline compares one chip against a calibrated C++-class "
             "16-vCPU reference NODE (~29K scanned rows/s/vCPU, BASELINE.md); "
@@ -1660,9 +1694,10 @@ def main():
     else:
         # Partial run (--only/--skip without the headline section):
         # still end with ONE machine-readable JSON line.
-        print(json.dumps({"metric": "bench_sections",
+        print(json.dumps({"metric": "bench_sections", "device": device,
                           "sections": sections, "details": details}))
+    return 1 if any(sections.values()) else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

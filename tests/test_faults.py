@@ -59,7 +59,11 @@ def test_sync_point_orders_threads():
         SYNC_POINT.disable_and_clear()
 
 
-def test_sync_point_timeout_and_disable():
+def test_sync_point_timeout_and_disable(monkeypatch):
+    # The production hook and its timeout path, at 0.2 s instead of the
+    # hook's 10 s default (process(point, arg=None, timeout_s=10.0)).
+    monkeypatch.setattr(type(SYNC_POINT).process, "__defaults__",
+                        (None, 0.2))
     SYNC_POINT.load_dependency([("never", "waits")])
     SYNC_POINT.enable()
     try:

@@ -1,8 +1,9 @@
 """Pallas flat-aggregate kernel vs the XLA/CPU oracle.
 
-Runs in pallas interpret mode (CPU backend); the same program compiles
-natively on TPU (probed by bench/engine integration behind the
-``tpu_engine_use_pallas`` flag).
+Runs in pallas interpret mode (CPU backend). The same program compiles
+through Mosaic on the chip: ``chip_smoke.py`` does that once, at B=64k,
+R=2048, and compares it with the CPU oracle. Nothing selects the kernel in
+service yet — ``--tpu_engine_use_pallas`` has no reader (ROADMAP C3).
 """
 
 import random

@@ -29,6 +29,15 @@ TERMINAL_CODES = frozenset(
      "duplicate_key"})
 
 
+# Scans do work in proportion to the data under them (a read over
+# several runs or a live memtable is merged on the host row by row), so
+# one attempt may take the call's whole remaining budget. Every other
+# tablet RPC is bounded work: 5 s without a reply means the target is
+# gone, and the next replica is tried.
+SCAN_METHODS = frozenset({"ts.scan", "ts.scan_wire"})
+ATTEMPT_CAP_S = 5.0
+
+
 class TabletOpFailed(Exception):
     pass
 
@@ -242,7 +251,8 @@ class YBClient:
             if prefer is not None and prefer in loc.replicas:
                 targets = [prefer] + [t for t in targets if t != prefer]
             for target in targets:
-                transport_timeout = attempt.timeout(5.0)
+                transport_timeout = attempt.timeout(
+                    None if method in SCAN_METHODS else ATTEMPT_CAP_S)
                 # Server-side budget: stay below the transport timeout
                 # so the server's own timed_out beats the socket's.
                 payload["timeout"] = max(0.05,

@@ -540,7 +540,6 @@ class YBSession:
         drop rows."""
         mark_rows, mark_scanned = len(state["rows"]), state["scanned"]
         resume = None
-        mesh_timeout = min(5.0, timeout_s)
         while True:
             remaining = (None if spec.limit is None
                          else spec.limit - len(state["rows"]))
@@ -552,16 +551,19 @@ class YBSession:
                            projection=spec.projection, limit=remaining)
             payload = {"tablet_ids": [g.tablet_id for g in group],
                        "spec": wire.encode_spec(sub),
-                       # Budget rides server-side (below the transport
-                       # timeout) so a slow pin returns a clean timed_out
-                       # and the per-tablet fallback still has time.
-                       "timeout": max(0.05, round(mesh_timeout * 0.8, 3))}
+                       # The caller's budget, as a per-tablet ts.scan
+                       # gets it: the first request for a new run set
+                       # builds, uploads and compiles the stack, which
+                       # takes as long as the tablets are big. It rides
+                       # server-side below the transport timeout so a
+                       # slow pin returns a clean timed_out.
+                       "timeout": max(0.05, round(timeout_s * 0.8, 3))}
             if resume is not None:
                 payload["resume"] = resume
             try:
                 resp = self.client.transport.send(
                     leader, "ts.multi_row_scan", payload,
-                    timeout=mesh_timeout)
+                    timeout=timeout_s)
             except Exception as e:  # noqa: BLE001 — per-tablet fallback
                 count_swallowed("session.multi_row_scan", e)
                 resp = {}
@@ -631,18 +633,16 @@ class YBSession:
                 sub = ScanSpec(lower=spec.lower, upper=spec.upper,
                                read_ht=read_ht, predicates=spec.predicates,
                                aggregates=partial_aggs)
-                mesh_timeout = min(5.0, timeout_s)
                 try:
-                    # Budget rides server-side (below the transport
-                    # timeout) so a slow pin returns a clean timed_out
-                    # and the per-tablet fallback still has time to run.
+                    # The caller's budget (see _mesh_row_pages), riding
+                    # server-side below the transport timeout so a slow
+                    # pin returns a clean timed_out.
                     resp = self.client.transport.send(
                         leader, "ts.multi_agg_scan",
                         {"tablet_ids": [g.tablet_id for g in group],
                          "spec": wire.encode_spec(sub),
-                         "timeout": max(0.05,
-                                        round(mesh_timeout * 0.8, 3))},
-                        timeout=mesh_timeout)
+                         "timeout": max(0.05, round(timeout_s * 0.8, 3))},
+                        timeout=timeout_s)
                 except Exception as e:  # noqa: BLE001 — per-tablet fallback
                     count_swallowed("session.multi_agg_scan", e)
                     continue

@@ -616,6 +616,9 @@ if __name__ == "__main__":  # replay a failing seed: python -m ... <seed>
     import sys
     import tempfile
 
+    from yugabyte_db_tpu.utils.jitting import enable_compile_cache
+
+    enable_compile_cache()
     argv = list(sys.argv[1:])
     wout = cwout = rwout = None
     if "--witness-out" in argv:

@@ -130,12 +130,12 @@ class MiniCluster:
             self.transport.close()
 
     # -- helpers ------------------------------------------------------------
-    def client(self, name: str = "client",
-               cloud_info: dict | None = None) -> YBClient:
-        if self.transport_kind == "local":
-            return YBClient(self.transport.bind(name), self.master_uuids,
-                            cloud_info=cloud_info)
-        return YBClient(self.transport, self.master_uuids,
+    def client(self, name: str = "client", cloud_info: dict | None = None,
+               rpc_timeout_s: float = 10.0) -> YBClient:
+        transport = (self.transport.bind(name)
+                     if self.transport_kind == "local" else self.transport)
+        return YBClient(transport, self.master_uuids,
+                        default_rpc_timeout_s=rpc_timeout_s,
                         cloud_info=cloud_info)
 
     def start_webservers(self) -> dict:
@@ -150,30 +150,34 @@ class MiniCluster:
         return addrs
 
     def start_cql_server(self, host: str = "127.0.0.1", port: int = 0,
-                         **cluster_kwargs):
+                         rpc_timeout_s: float = 10.0, **cluster_kwargs):
         """Start a CQL native-protocol proxy over this cluster (the
         reference shape: the tserver process spawns the CQL server on
-        port 9042, tablet_server_main.cc:211). Returns (server, (host,
-        port)); caller shuts the server down."""
+        port 9042, tablet_server_main.cc:211). ``rpc_timeout_s`` is the
+        proxy's budget for one tablet RPC — what bounds a statement.
+        Returns (server, (host, port)); caller shuts the server down."""
         from yugabyte_db_tpu.yql.cql.client_cluster import ClientCluster
         from yugabyte_db_tpu.yql.cql.server import CQLServer
 
-        server = CQLServer(ClientCluster(self.client("cql-proxy"),
-                                         **cluster_kwargs))
+        server = CQLServer(ClientCluster(
+            self.client("cql-proxy", rpc_timeout_s=rpc_timeout_s),
+            **cluster_kwargs))
         addr = server.listen(host, port)
         return server, addr
 
     def start_pg_server(self, host: str = "127.0.0.1", port: int = 0,
-                        **cluster_kwargs):
+                        rpc_timeout_s: float = 10.0, **cluster_kwargs):
         """Start a PostgreSQL wire-protocol frontend over this cluster
         (the reference shape: the tserver spawns the SQL frontend on port
-        5433, tablet_server_main.cc:160). Returns (server, (host, port));
-        caller shuts the server down."""
+        5433, tablet_server_main.cc:160). ``rpc_timeout_s`` as for
+        start_cql_server. Returns (server, (host, port)); caller shuts
+        the server down."""
         from yugabyte_db_tpu.yql.cql.client_cluster import ClientCluster
         from yugabyte_db_tpu.yql.pgsql.wire import PgServer
 
-        server = PgServer(ClientCluster(self.client("pg-proxy"),
-                                        **cluster_kwargs))
+        server = PgServer(ClientCluster(
+            self.client("pg-proxy", rpc_timeout_s=rpc_timeout_s),
+            **cluster_kwargs))
         addr = server.listen(host, port)
         return server, addr
 

@@ -658,6 +658,9 @@ if __name__ == "__main__":  # replay a failing seed: python -m ... <seed>
     import sys
     import tempfile
 
+    from yugabyte_db_tpu.utils.jitting import enable_compile_cache
+
+    enable_compile_cache()
     with tempfile.TemporaryDirectory() as root:
         out = run_sweep(root, int(sys.argv[1]) if len(sys.argv) > 1
                         else 1234)

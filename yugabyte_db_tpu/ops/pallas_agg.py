@@ -8,9 +8,10 @@ EXACTLY what ops.scan's flat path + ops.agg_fold compute for eligible
 signatures: COUNT(*) / COUNT(col), exact SUM over int32/int64 columns
 (16-bit limb partials), and MIN/MAX over int32/int64 ordered planes,
 under device-exact i32/i64 predicates, on single-version-per-key runs.
-The XLA path remains the default and the oracle; the flag
-``tpu_engine_use_pallas`` routes eligible aggregate scans here
-(tests pin both paths to identical results; interpret mode covers CPU).
+The XLA path remains the default and the oracle. Nothing routes scans
+here yet: the ``tpu_engine_use_pallas`` flag has no reader (ROADMAP C3).
+Tests pin both paths to identical results in interpret mode on the CPU;
+``chip_smoke.py`` compiles the kernel through Mosaic on the chip.
 
 Layout notes (pallas_guide.md): blocks are (8 tablet-blocks x R rows) so
 the sublane dimension meets the (8, 128) int32 tile minimum and R (a

@@ -140,8 +140,13 @@ def _window_parts(sig, r, base, m):
     return vals
 
 
-def gather_rows(sig: GatherSig, run, iparams, fparams):
-    """Traced program over one scan's params. Returns i32 [M+1, W]."""
+def gather_rows(sig: GatherSig, run, iparams, fparams, carry=None):
+    """Traced program over one scan's params. Returns i32 [M+1, W].
+
+    ``carry`` maps each replicated initial loop-carry value to the type
+    the loop body returns; a caller tracing this under shard_map passes
+    its mark-as-device-varying function (the body's outputs vary with
+    the shard's planes), the vmapped single-device caller passes None."""
     K, R, M = sig.K, sig.R, sig.M
     N = K * R
     W, col_offs = out_layout(sig)
@@ -197,6 +202,8 @@ def gather_rows(sig: GatherSig, run, iparams, fparams):
         return (w + jnp.int32(1), count, scanned, buf)
 
     init = (w_first, jnp.int32(0), jnp.int32(0), buf)
+    if carry is not None:
+        init = jax.tree.map(carry, init)
     w_end, count, scanned, buf = lax.while_loop(cond, body, init)
     tail = jnp.zeros((W,), jnp.int32).at[0].set(count).at[1].set(
         scanned).at[2].set(w_end)

@@ -1,67 +1,25 @@
-"""JAX mesh-API compatibility seam for the sharded read path.
-
-The mesh program targets two generations of the JAX SPMD API:
-
-- ``jax.shard_map`` + ``jax.lax.pcast`` (the varying-types world,
-  jax >= 0.6): collective-carrying loop bodies must mark replicated
-  initial carries as device-varying before the ``fori_loop`` traces.
-- ``jax.experimental.shard_map.shard_map`` (0.4.x): no varying types;
-  replication is checked structurally, and ``check_rep=False`` is
-  required for bodies whose per-device control flow diverges (the row
-  page program's ``while_loop`` runs a different trip count per shard).
+"""The mesh API seam for the sharded read path.
 
 Every shard_map in parallel/ goes through :func:`shard_map` /
-:func:`varying` below, so the one version split lives here.  When
-NEITHER API exists the mesh path is unavailable: :func:`mesh_unavailable`
-returns the reason string, callers fall back to the per-tablet host
-path, and the test suite's capability probe (tests/conftest.py) skips
-the mesh rigs with that reason instead of failing them.
+:func:`varying` below. The installed JAX types values inside a
+``jax.shard_map`` body as replicated or device-varying per mesh axis
+(``check_vma``): a loop whose body returns device-varying values needs
+initial carries of the same type, which is what :func:`varying` marks.
 """
 
 from __future__ import annotations
 
 import jax
 
-_UNAVAILABLE: str | None = None
-_SHARD_MAP = None
-_MODERN = hasattr(jax, "shard_map")
-
-if _MODERN:
-    _SHARD_MAP = jax.shard_map
-else:
-    try:
-        from jax.experimental.shard_map import shard_map as _SHARD_MAP
-    except ImportError:  # pragma: no cover - no known-good API present
-        _UNAVAILABLE = ("jax %s has neither jax.shard_map nor "
-                        "jax.experimental.shard_map" % jax.__version__)
-
-
-def mesh_unavailable() -> str | None:
-    """None when a usable shard_map exists, else the reason string."""
-    return _UNAVAILABLE
-
 
 def shard_map(body, mesh, in_specs, out_specs):
-    """Version-portable shard_map.
-
-    The experimental API defaults to replication CHECKING, which rejects
-    per-device-divergent control flow (and psum-of-loop-carry shapes)
-    that the typed API expresses with varying types — disable it there;
-    the modern API needs no flag.
-    """
-    if _UNAVAILABLE is not None:
-        raise RuntimeError(_UNAVAILABLE)
-    if _MODERN:
-        return _SHARD_MAP(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-    return _SHARD_MAP(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def varying(x, axes):
-    """Mark a replicated value as device-varying over ``axes`` before it
-    becomes a collective-carrying loop carry.  Identity on the 0.4.x
-    API, where no varying type system exists."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    return x
+    """``x`` typed device-varying over every axis of ``axes`` (a value
+    already varying over some of them only gains the rest) — the initial
+    value of a loop carry whose body output depends on the shard."""
+    need = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    return jax.lax.pcast(x, need, to="varying") if need else x

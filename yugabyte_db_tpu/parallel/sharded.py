@@ -591,13 +591,14 @@ def _page_body(sig: RG.GatherSig, Tl: int, Bl: int, R: int,
     w_last/row_lo/row_hi/scan_from slots; each shard rebases them to its
     own block range (clipping to empty when the tablet's range misses
     the shard) so the while_loop walks only overlapping windows — the
-    per-device trip counts diverge, which is exactly what the compat
-    seam's check_rep=False / varying-types split exists for."""
+    per-device trip counts diverge, so every loop carry is typed
+    device-varying (meshcompat.varying)."""
     base = jax.lax.axis_index("b") * (Bl * R)
     KR = sig.K * R
     Wl = Bl // sig.K
     outs = []
-    counts = meshcompat.varying(jnp.int32(0), ("t", "b"))
+    varying = functools.partial(meshcompat.varying, axes=("t", "b"))
+    counts = varying(jnp.int32(0))
     for t in range(Tl):
         local = _tablet_slice(run, t)
         ip = iparams[t]
@@ -611,7 +612,7 @@ def _page_body(sig: RG.GatherSig, Tl: int, Bl: int, R: int,
         head = jnp.stack([w_first, w_last, lo, hi, ip[4], ip[5], ip[6],
                           ip[7], sf])
         ipl = jnp.concatenate([head, ip[RG.PARAM_FIXED:]])
-        buf = RG.gather_rows(sig, local, ipl, fparams)
+        buf = RG.gather_rows(sig, local, ipl, fparams, carry=varying)
         counts = counts + buf[sig.M, 0]
         outs.append(buf[None, None])
     # The per-device match-count combine rides ICI; the buffers ride the

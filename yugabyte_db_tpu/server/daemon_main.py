@@ -33,6 +33,23 @@ def parse_topology(spec: str) -> dict[str, tuple[str, int]]:
     return out
 
 
+def claim_device() -> str:
+    """The device identity for the start-up line. A tserver pinned to
+    the CPU backend (``JAX_PLATFORMS=cpu`` — every daemon yb_ctl starts
+    without ``--engine tpu``) does not initialise JAX at all. Any other
+    tserver initialises its backend NOW, so a missing chip stops the
+    daemon at start-up with JAX's own error instead of at the first
+    flush."""
+    from yugabyte_db_tpu.utils.jitting import enable_compile_cache
+
+    if enable_compile_cache() is None:
+        return "cpu(pinned)"
+    import jax
+
+    devs = jax.devices()
+    return f"{devs[0].platform}:{devs[0].device_kind}x{len(devs)}"
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="yb-daemon")
     ap.add_argument("--role", choices=("master", "tserver"), required=True)
@@ -69,6 +86,7 @@ def main(argv=None) -> int:
         daemon = TabletServer(args.uuid, args.data_dir, transport,
                               master_uuids, fsync=not args.no_fsync,
                               engine_options=None)
+    device = claim_device() if args.role == "tserver" else "none"
     messenger = Messenger(args.uuid, num_workers=16)
     # Consensus traffic rides a dedicated pool: user writes block their
     # workers on majority replication, and the raft RPCs that complete
@@ -80,7 +98,7 @@ def main(argv=None) -> int:
     daemon.start()
     web_addr = daemon.start_webserver("127.0.0.1", args.web_port)
     print(f"{args.role} {args.uuid} rpc={bound[0]}:{bound[1]} "
-          f"web={web_addr[0]}:{web_addr[1]}", flush=True)
+          f"web={web_addr[0]}:{web_addr[1]} device={device}", flush=True)
 
     stop = threading.Event()
 

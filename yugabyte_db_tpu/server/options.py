@@ -25,7 +25,6 @@ class ServerOptions:
 @dataclass
 class TabletServerOptions(ServerOptions):
     heartbeat_interval_s: float = 0.5
-    tablet_storage_engine: str = "cpu"
     # Topology labels for zone-aware placement (reference: CloudInfoPB,
     # src/yb/master/master.proto:172): {"cloud", "region", "zone"}.
     cloud_info: dict | None = None

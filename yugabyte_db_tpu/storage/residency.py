@@ -59,7 +59,7 @@ HIGH_PRI_POOL_RATIO = 0.8
 
 # Device bucket for callers that never name a device (single-chip hosts,
 # tests driving the cache directly).  Callers on a real mesh pass
-# "<platform>:<id>" strings (parallel.meshcompat.device_label).
+# "<platform>:<id>" strings (ops.device_run.device_label).
 DEFAULT_DEVICE = "device:0"
 
 # Sentinel payload for externally-owned residency (bytes uploaded outside

@@ -17,6 +17,13 @@ class AdminError(Exception):
     pass
 
 
+# Flush and compaction run for as long as the tablet's data takes, and
+# compile device programs the first time: a control RPC's budget (10 s,
+# 3 s a send) is not theirs. Timing one out reads as "leader down", and
+# the failover then sends the same maintenance op to the followers.
+MAINTENANCE_RPC_TIMEOUT_S = 2000.0     # 600 s a send
+
+
 class AdminClient:
     """Thin admin wrapper over a cluster Transport.
 
@@ -158,8 +165,11 @@ class AdminClient:
                     timeout_s: float = 10.0) -> dict:
         """Send to the tablet's leader, following not_leader hints and
         failing over to other replicas when the reported leader is down
-        (re-fetching the location each round — it may have moved)."""
+        (re-fetching the location each round — it may have moved). One
+        send may take 0.3 of the budget, so a leader that is down leaves
+        time to reach the next replica."""
         deadline = time.monotonic() + timeout_s
+        rpc_timeout_s = 0.3 * timeout_s
         last = "unreachable"
         while True:
             loc = self.locate_tablet(tablet_id)
@@ -170,7 +180,7 @@ class AdminClient:
             for target in candidates:
                 try:
                     resp = self.transport.send(target, method, payload,
-                                               timeout=3.0)
+                                               timeout=rpc_timeout_s)
                 except TransportError as e:
                     last = str(e)
                     continue
@@ -182,7 +192,7 @@ class AdminClient:
                             and h not in already):
                         try:
                             resp = self.transport.send(h, method, payload,
-                                                       timeout=3.0)
+                                                       timeout=rpc_timeout_s)
                             if resp.get("code") != "not_leader":
                                 return resp
                         except TransportError as e:
@@ -206,22 +216,25 @@ class AdminClient:
         if resp.get("code") != "ok":
             raise AdminError(f"leader_stepdown: {resp.get('code')}")
 
-    def flush_table(self, table: str) -> int:
+    def _maintenance(self, table: str, method: str, **payload) -> int:
         n = 0
         for t in self.table_locations(table):
-            self._leader_rpc(t["tablet_id"], "ts.flush",
-                             {"tablet_id": t["tablet_id"]})
+            resp = self._leader_rpc(
+                t["tablet_id"], method,
+                dict(payload, tablet_id=t["tablet_id"]),
+                timeout_s=MAINTENANCE_RPC_TIMEOUT_S)
+            if resp.get("code") != "ok":
+                raise AdminError(f"{method} on {t['tablet_id']}: "
+                                 f"{resp.get('message', resp.get('code'))}")
             n += 1
         return n
 
+    def flush_table(self, table: str) -> int:
+        return self._maintenance(table, "ts.flush")
+
     def compact_table(self, table: str, history_cutoff_ht: int = 0) -> int:
-        n = 0
-        for t in self.table_locations(table):
-            self._leader_rpc(t["tablet_id"], "ts.compact",
-                             {"tablet_id": t["tablet_id"],
-                              "history_cutoff_ht": history_cutoff_ht})
-            n += 1
-        return n
+        return self._maintenance(table, "ts.compact",
+                                 history_cutoff_ht=history_cutoff_ht)
 
     def snapshot_table(self, table: str, snapshot_id: str,
                        op: str = "create_snapshot") -> int:

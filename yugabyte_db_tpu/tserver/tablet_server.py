@@ -821,13 +821,17 @@ class TabletServer:
                 FLAGS.get("max_clock_skew_us") << BITS_FOR_LOGICAL):
             return {"code": "invalid_read_time"}
         peer.tablet.clock.update(HybridTime(read_ht))
-        # Default below the client's 5s per-attempt transport timeout
-        # (client.py tablet_rpc) so the clean "timed_out" reply reaches
-        # the caller instead of a transport error.
         if not peer.tablet.mvcc.wait_for_safe_time(
                 HybridTime(read_ht), timeout=timeout):
             return {"code": "timed_out"}
         return None
+
+    # A read waits this long at most for the replica to catch up with
+    # its read point, whatever budget the scan itself has (client.py
+    # gives a scan attempt the call's whole budget): a replica that
+    # cannot is behind or deposed, and the clean "timed_out" sends the
+    # client to the next one while it still has time.
+    READ_GATE_WAIT_S = 4.0
 
     def _rpc_deadline(self, p: dict) -> Deadline:
         """The propagated deadline of one read RPC: the client debits
@@ -864,8 +868,8 @@ class TabletServer:
         peer.ops_seen += len(specs)  # split-manager load signal
         explicit = [s.read_ht for s in specs if s.read_ht != wire.MAX_HT]
         if explicit:
-            timeout = (deadline.timeout() if deadline is not None
-                       else p.get("timeout", 4.0))
+            timeout = (deadline.timeout(self.READ_GATE_WAIT_S)
+                       if deadline is not None else p.get("timeout", 4.0))
             err = self._pin_read_point(peer, max(explicit), timeout)
             if err is not None:
                 return None, None, err
@@ -878,8 +882,8 @@ class TabletServer:
             # propagated_ht), or it would read below them.
             from yugabyte_db_tpu.utils.hybrid_time import HybridTime as _HT
 
-            timeout = (deadline.timeout() if deadline is not None
-                       else p.get("timeout", 4.0))
+            timeout = (deadline.timeout(self.READ_GATE_WAIT_S)
+                       if deadline is not None else p.get("timeout", 4.0))
             if not peer.tablet.mvcc.wait_for_safe_time(_HT(prop),
                                                        timeout=timeout):
                 return None, None, {"code": "timed_out"}
@@ -1347,8 +1351,9 @@ class TabletServer:
             for peer in peers:
                 if deadline.expired():
                     return None, None, {"code": "timed_out"}
-                err = self._pin_read_point(peer, spec.read_ht,
-                                           deadline.timeout())
+                err = self._pin_read_point(
+                    peer, spec.read_ht,
+                    deadline.timeout(self.READ_GATE_WAIT_S))
                 if err is not None:
                     return None, None, err
         for peer in peers:

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import functools
 import json
+import os
 import threading
 
 # entry name -> declared max_compiles, in registration order. Filled at
@@ -161,6 +162,43 @@ def declared_contracts() -> dict[str, int]:
     """entry -> max_compiles for every contract registered at runtime."""
     with _CONTRACTS_LOCK:
         return dict(_CONTRACTS)
+
+
+# -- the persistent compile cache ---------------------------------------------
+
+# One fixed directory inside the checkout (git-ignored), never a temp
+# name: the path is part of the cache's key, so a directory that moves
+# never hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
+
+
+def enable_compile_cache() -> str | None:
+    """Keep compiled device programs across processes; returns the
+    directory. Every process that compiles device programs (daemon,
+    chip_smoke, bench, sweep CLIs) calls this before its first compile.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses that
+    directory and nothing here names another; otherwise the cache lives
+    at :data:`COMPILE_CACHE_DIR`, and the variable is exported so child
+    processes share it. Short compiles persist too (JAX's default keeps
+    only programs that took over a second to compile).
+
+    A process pinned to the CPU backend (``JAX_PLATFORMS=cpu``: the
+    tests, a rehearsal, yb_ctl's cpu daemons) gets no cache and None:
+    the cache is for the chip, test runs stay independent of each other,
+    and XLA:CPU logs a machine-feature warning on every cache hit."""
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        return None
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.environ["JAX_COMPILATION_CACHE_DIR"] = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 # -- the declaration decorator ------------------------------------------------

@@ -253,6 +253,15 @@ def count_swallowed(site: str, exc: object = None) -> None:
         _SWALLOW_LOG.debug("count_swallowed failed at site %s", site)
 
 
+def swallowed_errors() -> dict[str, int]:
+    """The ``yb_swallowed_errors`` snapshot, {site: count} — what a
+    fallback hid. A run that must prove no fallback fired reads this."""
+    with _SWALLOW_LOCK:
+        ents = dict(_SWALLOW_ENTITIES)
+    return {site: ent.counter("yb_swallowed_errors").get()
+            for site, ent in sorted(ents.items())}
+
+
 # -- fault-injection observability -------------------------------------------
 _FAULT_ENTITIES: dict[str, MetricEntity] = {}
 _FAULT_LOCK = threading.Lock()

@@ -167,7 +167,7 @@ SELECT l_returnflag, l_linestatus,
        avg(l_quantity) AS avg_qty,
        avg(l_extendedprice) AS avg_price,
        count(*) AS count_order
-FROM lineitem
+FROM {table}
 WHERE l_shipdate <= {cutoff}
 GROUP BY l_returnflag, l_linestatus
 ORDER BY l_returnflag, l_linestatus
@@ -175,18 +175,19 @@ ORDER BY l_returnflag, l_linestatus
 
 Q6_SQL = """
 SELECT sum(l_extendedprice * l_discount) AS revenue
-FROM lineitem
+FROM {table}
 WHERE l_shipdate >= {lo} AND l_shipdate < {hi}
   AND l_discount >= {dlo} AND l_discount <= {dhi}
   AND l_quantity < {qty}
 """
 
 
-def q1_sql(ship_cutoff: int = 10471) -> str:
-    return Q1_SQL.format(cutoff=ship_cutoff)
+def q1_sql(ship_cutoff: int = 10471, table: str = "lineitem") -> str:
+    return Q1_SQL.format(cutoff=ship_cutoff, table=table)
 
 
 def q6_sql(date_lo: int = 9131, discount: int = 6,
-           quantity: int = 24) -> str:
+           quantity: int = 24, table: str = "lineitem") -> str:
     return Q6_SQL.format(lo=date_lo, hi=date_lo + 365,
-                         dlo=discount - 1, dhi=discount + 1, qty=quantity)
+                         dlo=discount - 1, dhi=discount + 1, qty=quantity,
+                         table=table)
