@@ -115,14 +115,15 @@ def test_rpcz_endpoint_serves_request_traces(tmp_path):
 
 def test_trace_events_and_stacks():
     from yugabyte_db_tpu.utils.trace import (TRACE_EVENTS, dump_stacks,
-                                             trace_event)
+                                             span)
 
-    with trace_event("unit-span", tablet="t1"):
-        pass
+    with trace_request("svc.method") as t:     # the ring is fed under a
+        with span("unit-span", tablet="t1"):   # request's trace
+            pass
     events = TRACE_EVENTS.dump()["traceEvents"]
     mine = [e for e in events if e["name"] == "unit-span"]
     assert mine and mine[-1]["ph"] == "X" and mine[-1]["dur"] >= 0
-    assert mine[-1]["args"] == {"tablet": "t1"}
+    assert mine[-1]["args"] == {"tablet": "t1", "trace_id": t.trace_id}
     stacks = dump_stacks()
     assert "MainThread" in stacks and "test_trace_events_and_stacks" in stacks
 

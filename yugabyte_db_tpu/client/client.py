@@ -14,6 +14,7 @@ from yugabyte_db_tpu.client.meta_cache import MetaCache, TabletLocation
 from yugabyte_db_tpu.consensus.transport import TransportError
 from yugabyte_db_tpu.models.partition import compute_hash_code
 from yugabyte_db_tpu.models.schema import ColumnSchema, Schema
+from yugabyte_db_tpu.utils import trace
 from yugabyte_db_tpu.utils.metrics import count_swallowed
 from yugabyte_db_tpu.utils.retry import RetryPolicy
 from yugabyte_db_tpu.utils.status import TabletSplit
@@ -243,6 +244,7 @@ class YBClient:
         caller instead of a transport error)."""
         payload = dict(payload, tablet_id=loc.tablet_id)
         payload.setdefault("propagated_ht", self.last_observed_ht)
+        trace.inject(payload)  # the request's id, for the server's Trace
         tried_refresh = False
         last = None
         for attempt in self.retry_policy.attempts(timeout_s=timeout_s):

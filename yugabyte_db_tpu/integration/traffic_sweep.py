@@ -74,8 +74,7 @@ from yugabyte_db_tpu.models.schema import ColumnKind, ColumnSchema
 from yugabyte_db_tpu.storage.residency import hbm_cache
 from yugabyte_db_tpu.storage.scan_spec import AggSpec, Predicate, ScanSpec
 from yugabyte_db_tpu.utils.memtracker import root_tracker
-from yugabyte_db_tpu.utils.metrics import (count_swallowed,
-                                           observe_request_latency)
+from yugabyte_db_tpu.utils.metrics import count_swallowed
 from yugabyte_db_tpu.utils.status import TabletSplit
 
 PROTOCOLS = ("ycsb_a", "ycsb_b", "ycsb_e", "tpch", "redis")
@@ -344,7 +343,6 @@ class TrafficSweep:
         dt = time.monotonic() - t0
         self.latencies[proto].append(dt)
         self.ops_done[proto] += 1
-        observe_request_latency(proto, dt)
 
     def _zkey(self) -> str:
         return self.keys[self.zipf.sample(self.rng)]

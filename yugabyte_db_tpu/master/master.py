@@ -29,7 +29,8 @@ from yugabyte_db_tpu.tablet.wal import Log
 from yugabyte_db_tpu.utils.hybrid_time import HybridClock
 from yugabyte_db_tpu.utils.metrics import count_swallowed
 from yugabyte_db_tpu.utils.retry import Deadline
-from yugabyte_db_tpu.utils.trace import RpczStore, trace_request
+from yugabyte_db_tpu.utils import trace as _trace
+from yugabyte_db_tpu.utils.trace import RpczStore
 
 SYS_CATALOG_ID = "sys.catalog"
 
@@ -211,11 +212,12 @@ class Master:
     # -- rpc dispatch --------------------------------------------------------
     def handle(self, method: str, payload: dict):
         start = time.monotonic()
-        with trace_request(method) as t:
+        ent = self._rpc_entity(method)
+        with _trace.adopted(method, payload) as t:
+            _trace.record_queue_wait(ent.histogram("rpc_queue_us"))
             try:
                 return self._dispatch(method, payload)
             finally:
-                ent = self._rpc_entity(method)
                 ent.counter("rpc_requests_total").increment()
                 ent.histogram("rpc_latency_us").observe_duration_us(start)
                 t.finish()  # duration must be final before sampling

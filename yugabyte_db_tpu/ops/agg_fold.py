@@ -28,6 +28,7 @@ import numpy as np
 from yugabyte_db_tpu.ops import scan as dscan
 from yugabyte_db_tpu.ops.scan import I32_MAX, I32_MIN
 from yugabyte_db_tpu.utils import planes as PL
+from yugabyte_db_tpu.utils import jitting
 from yugabyte_db_tpu.utils.jitting import compile_contract
 
 DIGITS = 8  # base-2^16 digit vector length for exact integer sums
@@ -350,4 +351,4 @@ def compiled_full_aggregate(sig: dscan.ScanSig):
             w_first, w_last, lambda w, c: body(w, c), init)
         return pack(sig.aggs, acc, scanned)
 
-    return jax.jit(fn)
+    return jitting.jit(fn, "full_aggregate", sig.tag())

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from yugabyte_db_tpu.ops import encodings
 from yugabyte_db_tpu.ops.scan import I32_MAX, le2
+from yugabyte_db_tpu.utils import jitting
 from yugabyte_db_tpu.utils.jitting import compile_contract
 
 
@@ -92,7 +93,8 @@ def gc_mask(num_cols: int, N: int, s, cutoff_planes):
 @functools.lru_cache(maxsize=32)
 @compile_contract("gc_mask", max_compiles=32)
 def compiled_gc_mask(num_cols: int, N: int):
-    return jax.jit(functools.partial(gc_mask, num_cols, N))
+    return jitting.jit(functools.partial(gc_mask, num_cols, N), "gc_mask",
+                       jitting.tag(cols=num_cols))
 
 
 # -- host-vectorized twin ----------------------------------------------------

@@ -80,6 +80,46 @@ def plane_nbytes(run: ColumnarRun, window_blocks: int) -> int:
     return total
 
 
+def _tree_nbytes(tree) -> int:
+    total = 0
+    stack = [tree]
+    while stack:
+        node = stack.pop()
+        if isinstance(node, dict):
+            stack.extend(node.values())
+        else:
+            total += int(node.size) * node.dtype.itemsize
+    return total
+
+
+def program_read_bytes(arrays: dict, named: tuple, presence: tuple,
+                       flat: bool, arith: tuple = ()) -> int:
+    """Resident bytes of the planes one aggregate program reads from a
+    run's device ``arrays``, as its signature names them: the MVCC
+    planes every resolve touches (``group_start`` too unless the run is
+    flat), the ``set`` and ``isnull`` planes of every column in
+    ``presence`` (a row exists if any of its columns is non-null, so the
+    resolve reads them all: ``sig.cols``), and the ``cmp`` planes of the
+    ``named`` columns alone (group, aggregate and predicate columns;
+    ``arith`` planes for float predicates). Not the other columns' value
+    planes: XLA drops what the program never uses. Compressed leaves
+    count at their resident (encoded) size."""
+    total = sum(_tree_nbytes(arrays[n]) for n in (
+        "valid", "tomb", "live", "ht_hi", "ht_lo", "exp_hi", "exp_lo"))
+    if not flat:
+        total += _tree_nbytes(arrays["group_start"])
+    cols = arrays["cols"]
+    for cid in presence:
+        total += _tree_nbytes(cols[cid]["set"]) \
+            + _tree_nbytes(cols[cid]["isnull"])
+    for cid in named:
+        total += _tree_nbytes(cols[cid]["cmp"])
+    for cid in arith:
+        if "arith" in cols[cid]:
+            total += _tree_nbytes(cols[cid]["arith"])
+    return total
+
+
 class DeviceRun:
     """Uploads a ColumnarRun, padding the block axis to a multiple of the
     window size so window tiling never clamps (clamped dynamic slices would
@@ -182,12 +222,4 @@ class DeviceRun:
         """Device-resident bytes of this run's planes — the HBM
         footprint the engine accounts under the root->device MemTracker
         subtree (/memz)."""
-        total = 0
-        stack = [self.arrays]
-        while stack:
-            node = stack.pop()
-            if isinstance(node, dict):
-                stack.extend(node.values())
-            else:
-                total += int(node.size) * node.dtype.itemsize
-        return total
+        return _tree_nbytes(self.arrays)

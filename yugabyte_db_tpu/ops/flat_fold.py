@@ -33,6 +33,7 @@ from yugabyte_db_tpu.ops import agg_fold
 from yugabyte_db_tpu.ops import encodings
 from yugabyte_db_tpu.ops import scan as dscan
 from yugabyte_db_tpu.ops.scan import le2
+from yugabyte_db_tpu.utils import jitting
 from yugabyte_db_tpu.utils.jitting import compile_contract
 
 # np scalars, not jnp: module import must not touch the backend.
@@ -265,4 +266,4 @@ def compiled_flat_aggregate(sig: dscan.ScanSig):
                                 "n": n})
         return agg_fold.pack(sig.aggs, acc, scanned)
 
-    return jax.jit(fn)
+    return jitting.jit(fn, "flat_aggregate", sig.tag())

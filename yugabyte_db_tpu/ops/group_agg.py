@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from yugabyte_db_tpu.ops.scan import I32_MAX, I32_MIN, resolve_window
+from yugabyte_db_tpu.utils import jitting
 from yugabyte_db_tpu.utils.jitting import compile_contract
 
 NUM_BUCKETS = 512
@@ -70,6 +71,13 @@ class GroupAggSig:
     flat: bool
     group_cols: tuple    # tuple[(col_id, planes)]
     aggs: tuple          # tuple[GAgg]
+
+    def tag(self) -> str:
+        """What the query decides of the program, for its name
+        (utils.jitting.tag): TPC-H Q1 is ``g2a8p1f1_...``, Q6
+        ``g0a1p4f1_...``, whatever the run's size."""
+        return jitting.tag(groups=self.group_cols, aggs=self.aggs,
+                           preds=self.preds, flat=self.flat)
 
 
 def _eval_factor(expr, cmp_w, idx, flat):
@@ -278,4 +286,5 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
 @functools.lru_cache(maxsize=64)
 @compile_contract("grouped_aggregate", max_compiles=64)
 def compiled_grouped(sig: GroupAggSig):
-    return jax.jit(functools.partial(grouped_aggregate, sig))
+    return jitting.jit(functools.partial(grouped_aggregate, sig),
+                       "grouped_aggregate", sig.tag())

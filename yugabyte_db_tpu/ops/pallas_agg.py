@@ -29,6 +29,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from yugabyte_db_tpu.ops.scan import I32_MAX, I32_MIN, AggSig, PredSig
+from yugabyte_db_tpu.utils import jitting
 from yugabyte_db_tpu.utils.jitting import compile_contract
 
 BLOCKS_PER_STEP = 8
@@ -216,7 +217,8 @@ def compiled_flat_aggregate(B: int, R: int, aggs: tuple, preds: tuple,
     def fn(tensors, iparams):
         return call(iparams, *tensors)
 
-    return jax.jit(fn)
+    return jitting.jit(fn, "pallas_flat_aggregate",
+                       jitting.tag(aggs=aggs, preds=preds, cols=col_order))
 
 
 def gather_tensors(dev_arrays, col_order):

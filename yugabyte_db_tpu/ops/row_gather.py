@@ -38,6 +38,7 @@ import numpy as np
 from jax import lax
 
 from yugabyte_db_tpu.ops.scan import resolve_window
+from yugabyte_db_tpu.utils import jitting
 from yugabyte_db_tpu.utils.jitting import compile_contract
 
 # Fixed slots at the head of the int32 params vector; predicate literal
@@ -73,6 +74,12 @@ class GatherSig:
                          # M matches, while_loop over windows); False: one
                          # whole window emitted in place (start=-1 marks
                          # non-matches; the host compacts with numpy)
+
+    def tag(self) -> str:
+        """What the query decides of the program, for its name
+        (utils.jitting.tag)."""
+        return jitting.tag(out=self.out_cols, preds=self.preds,
+                           flat=self.flat, packed=self.packed)
 
 
 def out_layout(sig: GatherSig):
@@ -215,4 +222,5 @@ def gather_rows(sig: GatherSig, run, iparams, fparams, carry=None):
 def compiled_gather_batch(sig: GatherSig, G: int):
     """G scans per dispatch: (run, i32[G,P], f32[G,F]) -> i32[G, M+1, W]."""
     fn = functools.partial(gather_rows, sig)
-    return jax.jit(jax.vmap(fn, in_axes=(None, 0, 0)))
+    return jitting.jit(jax.vmap(fn, in_axes=(None, 0, 0)), "gather_batch",
+                       sig.tag())

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from yugabyte_db_tpu.ops import encodings
+from yugabyte_db_tpu.utils import jitting
 from yugabyte_db_tpu.utils.jitting import compile_contract
 
 I32_MIN = np.int32(np.iinfo(np.int32).min)
@@ -99,6 +100,12 @@ class ScanSig:
                         # flat): small bounds unlock the shifted-mask
                         # resolve (ops.lookback_fold) instead of
                         # segmented scans
+
+    def tag(self) -> str:
+        """What the query decides of the program, for its name
+        (utils.jitting.tag): not B, R, K, nor the table's columns."""
+        return jitting.tag(aggs=self.aggs, preds=self.preds,
+                           flat=self.flat, lookback=self.lookback)
 
 
 # -- the program ------------------------------------------------------------
@@ -450,4 +457,4 @@ def _eval_agg(name, ag: AggSig, result, col_idx, col_has, col_notnull,
 def compiled_scan(sig: ScanSig):
     """One compiled XLA program per static scan signature."""
     fn = functools.partial(scan_window, sig)
-    return jax.jit(fn)
+    return jitting.jit(fn, "scan_window", sig.tag())

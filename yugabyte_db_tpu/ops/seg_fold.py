@@ -36,6 +36,7 @@ from yugabyte_db_tpu.ops import encodings
 from yugabyte_db_tpu.ops import flat_fold
 from yugabyte_db_tpu.ops import scan as dscan
 from yugabyte_db_tpu.ops.scan import I32_MIN, le2
+from yugabyte_db_tpu.utils import jitting
 from yugabyte_db_tpu.utils.jitting import compile_contract
 
 
@@ -157,4 +158,4 @@ def compiled_seg_aggregate(sig: dscan.ScanSig):
         return flat_fold.finish_groups(sig, gs, live_any, col_notnull,
                                        col_val, row_lo, row_hi, pred_lits)
 
-    return jax.jit(fn)
+    return jitting.jit(fn, "seg_aggregate", sig.tag())

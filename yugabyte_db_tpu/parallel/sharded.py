@@ -40,6 +40,7 @@ from yugabyte_db_tpu.ops import encodings
 from yugabyte_db_tpu.ops import row_gather as RG
 from yugabyte_db_tpu.ops import scan as dscan
 from yugabyte_db_tpu.parallel import meshcompat
+from yugabyte_db_tpu.utils import jitting
 from yugabyte_db_tpu.utils.jitting import compile_contract
 from yugabyte_db_tpu.ops.agg_fold import (agg_init, check_limb_bound,
                                           finalize, fold_window, lower_aggs,
@@ -471,7 +472,7 @@ def _compiled_dist_agg(sig: dscan.ScanSig, mesh: Mesh, enc_struct,
     body = functools.partial(_shard_body, sig, Tl, Bl, sig.R)
     smapped = meshcompat.shard_map(body, mesh, in_specs,
                                    (_acc_specs(sig), P()))
-    return jax.jit(smapped)
+    return jitting.jit(smapped, "dist_agg", sig.tag())
 
 
 @functools.lru_cache(maxsize=32)
@@ -486,7 +487,8 @@ def _compiled_stack_update(padded_T: int, B: int, R: int, cols_desc):
                 d, s.astype(d.dtype), (t,) + (0,) * (d.ndim - 1)),
             dst, src)
 
-    return jax.jit(upd)
+    return jitting.jit(upd, "stack_update",
+                       jitting.tag(cols=cols_desc))
 
 
 def _acc_specs(sig):
@@ -631,7 +633,7 @@ def _compiled_dist_page(sig: RG.GatherSig, mesh: Mesh, enc_struct,
         body, mesh,
         (_specs_from_struct(enc_struct, spec_tb), P("t"), P()),
         (P("t", "b"), P()))
-    return jax.jit(smapped)
+    return jitting.jit(smapped, "dist_page", sig.tag())
 
 
 def sharded_row_page(st: ShardedTablets, spec: ScanSpec,

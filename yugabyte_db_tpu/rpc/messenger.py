@@ -16,7 +16,7 @@ import struct
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
-from yugabyte_db_tpu.utils import codec
+from yugabyte_db_tpu.utils import codec, trace
 
 _LEN = struct.Struct("<I")
 MAX_FRAME = 64 * 1024 * 1024
@@ -196,11 +196,15 @@ class Messenger:
                 except Exception:
                     self._close_conn(conn)
                     return
+                # The wait from here to the handler's start (a worker of
+                # the pool, the calls queued before it on an ordered
+                # connection) is the handler's owner's rpc_queue_us.
+                stamp = trace.arrival_stamp()
                 if conn.context.ordered_responses:
                     # Replies pair with requests by order: serialize
                     # handler execution per connection.
                     with conn.out_lock:
-                        conn.call_queue.extend(calls)
+                        conn.call_queue.extend((stamp, c) for c in calls)
                         start_drain = calls and not conn.draining
                         if start_drain:
                             conn.draining = True
@@ -209,7 +213,7 @@ class Messenger:
                 else:
                     for call in calls:
                         self._pool_for(call[1]).submit(
-                            self._dispatch, conn, call)
+                            self._dispatch, conn, call, stamp)
         if mask & selectors.EVENT_WRITE:
             self._try_write(conn)
 
@@ -219,16 +223,19 @@ class Messenger:
                 if not conn.call_queue or conn.closed:
                     conn.draining = False
                     return
-                call = conn.call_queue.pop(0)
-            self._dispatch(conn, call)
+                stamp, call = conn.call_queue.pop(0)
+            self._dispatch(conn, call, stamp)
 
-    def _dispatch(self, conn: _Connection, call) -> None:
+    def _dispatch(self, conn: _Connection, call, stamp=None) -> None:
         """Worker-side: run the handler, enqueue the response.
+        ``stamp`` is when the call's frame was parsed
+        (utils.trace.record_queue_wait reads it in the handler).
 
         A handler with ``takes_conn = True`` receives the connection as
         its first argument — foreign protocols with server-push frames
         (Redis pubsub/monitor) address pushes via send_on(conn, ...)."""
         call_id, method, body = call
+        trace.set_arrival(stamp)
         try:
             if getattr(conn.handler, "takes_conn", False):
                 result = conn.handler(conn, method, body)
@@ -237,6 +244,8 @@ class Messenger:
             response = (call_id, "ok", result)
         except Exception as e:  # propagate as remote error
             response = (call_id, "error", f"{type(e).__name__}: {e}")
+        finally:
+            trace.set_arrival(None)
         try:
             out = conn.context.serialize(response)
         except Exception:

@@ -35,6 +35,7 @@ from __future__ import annotations
 import bisect
 import functools
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +48,8 @@ from yugabyte_db_tpu.ops import encodings
 from yugabyte_db_tpu.ops import scan as dscan
 from yugabyte_db_tpu.ops.device_run import (DeviceRun, device_label,
                                             dtype_kind, padded_blocks,
-                                            plane_nbytes)
+                                            plane_nbytes,
+                                            program_read_bytes)
 from yugabyte_db_tpu.storage.residency import device_nbytes, hbm_cache
 from yugabyte_db_tpu.storage.breaker import CircuitBreaker
 from yugabyte_db_tpu.storage.columnar import ColumnarRun
@@ -60,6 +62,7 @@ from yugabyte_db_tpu.storage.row_version import MAX_HT, RowVersion
 from yugabyte_db_tpu.storage.scan_spec import ScanResult, ScanSpec
 from yugabyte_db_tpu.utils import planes as P
 from yugabyte_db_tpu.utils.fault_injection import FaultInjected, maybe_fault
+from yugabyte_db_tpu.utils import jitting, metrics, trace
 from yugabyte_db_tpu.utils.jitting import compile_contract
 from yugabyte_db_tpu.utils.metrics import (count_flush_path,
                                            count_host_verify_rows,
@@ -130,8 +133,15 @@ class TpuRun:
             device=device_label(self.jax_device))
 
     def _build_dev(self):
-        d = DeviceRun(self.crun, PAD_BLOCKS, device=self.jax_device)
-        return d, d.nbytes
+        # (host time: pad and device_put every plane; the transfers
+        # themselves may still be in flight when this returns, and what
+        # is left of them shows in the first fetch's wait)
+        with trace.span("engine.upload", metrics.device_upload_histogram(),
+                        seconds=True) as sp:
+            d = DeviceRun(self.crun, PAD_BLOCKS, device=self.jax_device)
+            sp.labels["bytes"] = nbytes = d.nbytes
+        metrics.count_device_upload_bytes(nbytes)
+        return d, nbytes
 
     def _nbytes_hint(self) -> int:
         if self._dev_nbytes_hint is None:
@@ -199,6 +209,57 @@ class TpuRun:
             t = pallas_agg.gather_tensors(self.dev.arrays, col_order)
             cache.aux_put(self._res_key, aux_key, t, device_nbytes(t))
         return t
+
+
+def _set_phase(sp, phase: str, route: str) -> None:
+    """Point a span at ``yb_engine_phase_us{phase, route}``."""
+    sp.labels["route"] = route
+    sp.histogram = metrics.engine_phase_histogram(phase, route)
+
+
+def _record_phase(phase: str, route: str, wall_ns: int, ns: int) -> None:
+    trace.record_span("engine." + phase, wall_ns, ns // 1000,
+                      metrics.engine_phase_histogram(phase, route),
+                      route=route)
+
+
+def _count_dispatch(entry: str, dev, sig) -> None:
+    """One aggregate program of ``entry`` dispatched over ``dev``'s
+    planes: ``yb_device_dispatches`` and, for the roofline, the resident
+    bytes of the planes ``sig`` names (ops.device_run.program_read_bytes:
+    its group, aggregate and predicate columns' value planes, every
+    column's presence planes, the MVCC planes; not the rest of the
+    run). A vmapped batch counts its planes once. The sum is kept on
+    ``dev`` (one upload of one run) by signature."""
+    cache = dev.__dict__.setdefault("_read_bytes", {})
+    nbytes = cache.get(sig)
+    if nbytes is None:
+        nbytes = cache[sig] = _sig_read_bytes(dev.arrays, sig)
+    metrics.count_device_dispatch(entry, nbytes)
+
+
+def _sig_read_bytes(arrays: dict, sig) -> int:
+    named: list = [cid for cid, _planes in getattr(sig, "group_cols", ())]
+    arith: list = []
+    for a in sig.aggs:
+        if a.col_id is not None:
+            named.append(a.col_id)
+        named.extend(getattr(a, "need_cols", ()))
+        if getattr(a, "kind", None) in ("f32", "f64"):
+            arith.append(a.col_id)
+    for ps in sig.preds:
+        (arith if ps.kind == "f32" else named).append(ps.col_id)
+    return program_read_bytes(
+        arrays, tuple(dict.fromkeys(named)),
+        tuple(cs.col_id for cs in sig.cols), sig.flat,
+        tuple(dict.fromkeys(arith)))
+
+
+def _batch_route(plans: list) -> str:
+    """A batch's label: ``_plan_scan``'s tag when its plans share one
+    (host, page, issued, agg_deferred, grouped_deferred, gather)."""
+    tags = {plan[0] for plan in plans}
+    return tags.pop() if len(tags) == 1 else "mixed" if tags else "empty"
 
 
 class _MaskedRun:
@@ -777,7 +838,7 @@ class TpuStorageEngine(StorageEngine):
                     TpuStorageEngine._scatter_warmed.add(key)
                 try:
                     idx = jnp.full((b,), size, dtype=jnp.int32)
-                    TpuStorageEngine._scatter_invalid(valid, idx)
+                    TpuStorageEngine.scatter_invalid(valid, idx)
                 except Exception as e:  # noqa: BLE001 — warmup best-effort
                     count_swallowed("tpu_engine.scatter_warmup", e)
 
@@ -1479,6 +1540,19 @@ class TpuStorageEngine(StorageEngine):
         (and between finish()-time rounds), unwinding residency pins."""
         if deadline is not None:
             deadline.check("tpu_engine.scan_batch")
+        # Phase "issue" of the batch: planning of every spec, pins, the
+        # deferred batch dispatches, copy_to_host_async. Once a batch,
+        # under the route its plans took ("failed" if it raises).
+        with trace.span("engine.issue") as sp:
+            route = "failed"
+            try:
+                batch = self._issue_batch(specs, deadline)
+                route = batch.route
+            finally:
+                _set_phase(sp, "issue", route)
+        return batch
+
+    def _issue_batch(self, specs: list[ScanSpec], deadline):
         if not self.breaker.allow():
             return _HostServeBatch(self, specs, deadline)
         try:
@@ -1587,7 +1661,8 @@ class TpuStorageEngine(StorageEngine):
             return _AsyncBatch(self, results, host_plans, issued_outs,
                                gathers, states, pending, dispatches,
                                pages, pre_work, pins, specs=specs,
-                               deadline=deadline)
+                               deadline=deadline,
+                               route=_batch_route(plans))
         except BaseException:
             for trun in pins:
                 trun.unpin()
@@ -2529,7 +2604,9 @@ class TpuStorageEngine(StorageEngine):
 
         sig, ip, fp = prep
         fn = group_agg.compiled_grouped(sig)
-        out = fn(trun.dev.arrays, ip, fp)
+        dev = trun.dev
+        out = fn(dev.arrays, ip, fp)
+        _count_dispatch("grouped_aggregate", dev, sig)
         return ("issued", out,
                 self._grouped_finish(trun, spec, exact_preds, sig))
 
@@ -2543,7 +2620,8 @@ class TpuStorageEngine(StorageEngine):
         from yugabyte_db_tpu.ops import group_agg
 
         base = group_agg.compiled_grouped(sig)
-        return jax.jit(jax.vmap(base, in_axes=(None, 0, 0)))
+        return jitting.jit(jax.vmap(base, in_axes=(None, 0, 0)),
+                           "batched_grouped", sig.tag())
 
     def _plan_grouped_batch(self, items):
         """Batched grouped aggregates (the concurrent TPC-H Q1 shape):
@@ -2575,7 +2653,9 @@ class TpuStorageEngine(StorageEngine):
                 ip_b[i] = np.asarray(ip)
                 fp_b[i] = np.asarray(fp)
             fn = self._batched_grouped_fn(sig)
-            res = fn(trun.dev.arrays, ip_b, fp_b)
+            dev = trun.dev
+            res = fn(dev.arrays, ip_b, fp_b)
+            _count_dispatch("batched_grouped", dev, sig)
             for i, (pi, trun_i, spec, exact, sig_i, _ip, _fp) in \
                     enumerate(grp):
                 fin1 = self._grouped_finish(trun_i, spec, exact, sig_i)
@@ -2717,14 +2797,14 @@ class TpuStorageEngine(StorageEngine):
     @staticmethod
     @compile_contract("scatter_invalid", max_compiles=64)
     @jax.jit
-    def _scatter_invalid(valid, idx):
+    def scatter_invalid(valid, idx):
         flat = valid.reshape(-1)
         return flat.at[idx].set(False, mode="drop").reshape(valid.shape)
 
     @staticmethod
     @compile_contract("scatter_invalid_bits", max_compiles=64)
     @jax.jit
-    def _scatter_invalid_bits(bw, idx):
+    def scatter_invalid_bits(bw, idx):
         """Bit-packed valid plane (--tpu_plane_encoding): decode the
         words and scatter-clear in ONE fused program — the masked
         overlay substitutes a plain bool plane, which every kernel
@@ -2857,9 +2937,9 @@ class TpuStorageEngine(StorageEngine):
         pidx = np.full(bucket, size, dtype=np.int32)
         pidx[:idx.size] = idx
         masked_valid = (
-            TpuStorageEngine._scatter_invalid_bits(
+            TpuStorageEngine.scatter_invalid_bits(
                 vleaf["bits"]["bw"], jnp.asarray(pidx)) if packed
-            else TpuStorageEngine._scatter_invalid(
+            else TpuStorageEngine.scatter_invalid(
                 vleaf, jnp.asarray(pidx)))
         masked_arrays = dict(primary.dev.arrays, valid=masked_valid)
         return _MaskedRun(primary, masked_arrays)
@@ -3188,8 +3268,9 @@ class TpuStorageEngine(StorageEngine):
         ONE dispatch. The run planes broadcast; everything else maps.
         Distinct batch sizes retrace inside the jit cache."""
         base = TpuStorageEngine._agg_route_fn(route, sig)
-        return jax.jit(jax.vmap(base,
-                                in_axes=(None, 0, 0, 0, 0, 0, 0, 0)))
+        return jitting.jit(
+            jax.vmap(base, in_axes=(None, 0, 0, 0, 0, 0, 0, 0)),
+            "batched_agg", f"{route}_{sig.tag()}")
 
     def _plan_device_aggregate_batch(self, items):
         """Batched device aggregates: group deferred specs by
@@ -3244,9 +3325,11 @@ class TpuStorageEngine(StorageEngine):
                 for k, l in enumerate(lits):
                     lits_b[k][i] = l
             fn = self._batched_agg_fn(route, sig)
-            ivec, fvec = fn(trun.dev.arrays, row_lo_b, row_hi_b,
+            dev = trun.dev
+            ivec, fvec = fn(dev.arrays, row_lo_b, row_hi_b,
                             planes_b[0], planes_b[1], planes_b[2],
                             planes_b[3], tuple(lits_b))
+            _count_dispatch("batched_agg", dev, sig)
             for i, (pi, _t, spec, (_sig, _r, _rlo, _rhi, _pl, _lits,
                                    dev_aggs, lowering)) in enumerate(grp):
                 fin1 = self._agg_finish(spec, dev_aggs, lowering,
@@ -3266,18 +3349,20 @@ class TpuStorageEngine(StorageEngine):
         r_hi_, r_lo_, e_hi_, e_lo_ = (jnp.int32(v) for v in planes)
         pred_lits = tuple(jnp.asarray(l) for l in lits)
         fn = self._agg_route_fn(route, sig)
+        dev = trun.dev
         if route == "full":
-            W = trun.dev.B // sig.K
+            W = dev.B // sig.K
             w_first, w_last = agg_fold.window_bounds(row_lo, row_hi,
                                                      sig.R, sig.K, W)
-            ivec, fvec = fn(trun.dev.arrays, jnp.int32(row_lo),
+            ivec, fvec = fn(dev.arrays, jnp.int32(row_lo),
                             jnp.int32(row_hi),
                             jnp.int32(w_first), jnp.int32(w_last),
                             r_hi_, r_lo_, e_hi_, e_lo_, pred_lits)
         else:
-            ivec, fvec = fn(trun.dev.arrays, jnp.int32(row_lo),
+            ivec, fvec = fn(dev.arrays, jnp.int32(row_lo),
                             jnp.int32(row_hi), r_hi_, r_lo_, e_hi_, e_lo_,
                             pred_lits)
+        _count_dispatch(route + "_aggregate", dev, sig)
         return [ivec, fvec], self._agg_finish(spec, dev_aggs, lowering,
                                               raw=raw)
 
@@ -3290,7 +3375,9 @@ class _AsyncBatch:
 
     def __init__(self, eng, results, host_plans, issued_outs, gathers,
                  states, pending, dispatches, pages=(), pre_work=(),
-                 pins=(), specs=(), deadline=None):
+                 pins=(), specs=(), deadline=None, route="mixed"):
+        self.route = route        # _batch_route of the plans
+        self._fetch_ns = 0        # phase "wait_fetch", summed over rounds
         self.eng = eng
         self.results = results
         self.host_plans = host_plans
@@ -3322,6 +3409,19 @@ class _AsyncBatch:
     def finish(self) -> list[ScanResult]:
         if self._done:
             return self.results
+        wall, t0 = time.time_ns(), time.perf_counter_ns()
+        try:
+            return self._finish_or_reserve()
+        finally:
+            # Once a batch: "wait_fetch" is the device_get calls (device
+            # queue + run + transfer, as the host feels it), "finish"
+            # the rest (pre_work, host plans, pages, decode, rounds).
+            total = time.perf_counter_ns() - t0
+            _record_phase("wait_fetch", self.route, wall, self._fetch_ns)
+            _record_phase("finish", self.route, wall,
+                          total - self._fetch_ns)
+
+    def _finish_or_reserve(self) -> list[ScanResult]:
         try:
             out = self._finish()
         except DEVICE_FAULT_TYPES as e:
@@ -3331,6 +3431,7 @@ class _AsyncBatch:
             # (MVCC: later writes are invisible at spec.read_ht).
             self._release_pins()
             self.eng.breaker.record_failure(e)
+            self.route = "breaker_host"
             self.results = self.eng._serve_host_batch(self.specs,
                                                       self.deadline)
             self._done = True
@@ -3340,6 +3441,12 @@ class _AsyncBatch:
             raise
         self._release_pins()
         self.eng.breaker.record_success()
+        return out
+
+    def _fetch(self, tree):
+        t0 = time.perf_counter_ns()
+        out = jax.device_get(tree)
+        self._fetch_ns += time.perf_counter_ns() - t0
         return out
 
     def _check_deadline(self) -> None:
@@ -3365,7 +3472,7 @@ class _AsyncBatch:
                 results[pi] = res
         # One fetch for everything issued in round 1 (device_get reuses
         # buffers the async copies already landed).
-        disp_bufs, issued_np = jax.device_get(
+        disp_bufs, issued_np = self._fetch(
             [[d for _c, d in self.dispatches],
              [o for _pi, o, _f in self.issued_outs]])
         for (pi, _outs, fin), f in zip(self.issued_outs, issued_np):
@@ -3379,7 +3486,7 @@ class _AsyncBatch:
         while pending:
             self._check_deadline()
             dispatches = eng._issue_round(self.states, pending)
-            disp_bufs = jax.device_get([d for _c, d in dispatches])
+            disp_bufs = self._fetch([d for _c, d in dispatches])
             pending = eng._feed_round(self.states, pending, dispatches,
                                       disp_bufs)
         for pi, st in self.gathers:
@@ -3394,6 +3501,8 @@ class _HostServeBatch:
     during planning). Nothing was issued to the device and no residency
     pins are held; finish() serves the whole batch from the host."""
 
+    route = "breaker_host"
+
     def __init__(self, eng, specs, deadline=None):
         self.eng = eng
         self.specs = list(specs)
@@ -3404,8 +3513,15 @@ class _HostServeBatch:
     def finish(self) -> list[ScanResult]:
         if self._done:
             return self.results
-        self.results = self.eng._serve_host_batch(self.specs,
-                                                  self.deadline)
+        wall, t0 = time.time_ns(), time.perf_counter_ns()
+        try:
+            self.results = self.eng._serve_host_batch(self.specs,
+                                                      self.deadline)
+        finally:
+            # (no device work: the fetch phase is there, and empty)
+            _record_phase("wait_fetch", self.route, wall, 0)
+            _record_phase("finish", self.route, wall,
+                          time.perf_counter_ns() - t0)
         self._done = True
         return self.results
 

@@ -24,7 +24,8 @@ from yugabyte_db_tpu.tserver.tablet_manager import (TabletNotFound,
 from yugabyte_db_tpu.utils.metrics import count_swallowed
 from yugabyte_db_tpu.utils.retry import Deadline, DeadlineExpired
 from yugabyte_db_tpu.utils.status import TabletSplit
-from yugabyte_db_tpu.utils.trace import TRACE, RpczStore, trace_request
+from yugabyte_db_tpu.utils import trace as _trace
+from yugabyte_db_tpu.utils.trace import TRACE, RpczStore
 
 
 class TabletServer:
@@ -128,7 +129,8 @@ class TabletServer:
                 for p in self.tablet_manager.peers()]
 
         self.webserver.add_json_handler("/tablets", _tablet_rows)
-        self.webserver.add_json_handler("/rpcz", self.rpcz.dump)
+        self.webserver.add_json_handler("/rpcz", lambda: dict(
+            self.rpcz.dump(), frontends=_trace.FRONTEND_RPCZ.dump()))
         self.webserver.add_dashboard("/dashboards/tablets", "Tablets",
                                      _tablet_rows)
 
@@ -208,7 +210,12 @@ class TabletServer:
         import time as _time
 
         start = _time.monotonic()
-        with trace_request(method) as t:
+        ent = self._rpc_entity(method)
+        # The caller's trace id, if its payload carries one: a /rpcz
+        # sample of a scan then holds the engine's phases under the id
+        # the wire frontend gave the statement.
+        with _trace.adopted(method, payload) as t:
+            _trace.record_queue_wait(ent.histogram("rpc_queue_us"))
             try:
                 return self._dispatch(method, payload)
             except TabletSplit as e:
@@ -219,7 +226,6 @@ class TabletServer:
                 # every write path funnels here.
                 return {"code": "tablet_split", "tablet_id": e.tablet_id}
             finally:
-                ent = self._rpc_entity(method)
                 ent.counter("rpc_requests_total").increment()
                 ent.histogram("rpc_latency_us").observe_duration_us(start)
                 t.finish()  # duration must be final before sampling

@@ -30,8 +30,11 @@ This module supplies both halves of the discipline, mirroring
 Every compile event also bumps ``yb_jit_compiles{entry=...}`` on the
 process metric registry (witness on or off), so every daemon's
 ``/metrics`` scrape and every bench round can prove zero steady-state
-recompiles. When the witness is disabled the per-dispatch cost is two
-compiled-cache-size probes (C++ attribute reads on the jit object).
+recompiles, and records the seconds the compiling dispatch took as the
+span ``engine.compile`` (``yb_jit_compile_seconds{entry=...}``, and a
+line of the request's /rpcz sample). When the witness is disabled the
+per-dispatch cost is two compiled-cache-size probes (C++ attribute
+reads on the jit object) and one clock read.
 """
 
 from __future__ import annotations
@@ -40,6 +43,8 @@ import functools
 import json
 import os
 import threading
+import time
+import zlib
 
 # entry name -> declared max_compiles, in registration order. Filled at
 # import time by @compile_contract decorations; read by the witness dump
@@ -201,6 +206,38 @@ def enable_compile_cache() -> str | None:
     return path
 
 
+# -- names for the device programs ---------------------------------------------
+
+def tag(**parts) -> str:
+    """A short descriptor of what a compiled program computes, the same
+    in every process and run: per part its first letter and its length
+    (a tuple) or value (an int or bool), then six hex digits of a CRC
+    over the parts' ``repr`` (dataclasses, tuples, strings and numbers
+    only: nothing whose repr holds an address). Pass what the query
+    decides (group columns, aggregates, predicates, flat or not), not
+    shapes (B, R, K): one query keeps one name whatever the run's size,
+    and an entry has at most ``max_compiles`` distinct tags."""
+    head = "".join(
+        f"{k[0]}{len(v) if isinstance(v, (tuple, list)) else int(v)}"
+        for k, v in parts.items())
+    crc = zlib.crc32(repr(sorted(parts.items())).encode()) & 0xFFFFFF
+    return f"{head}_{crc:06x}"
+
+
+def jit(fn, entry: str, tag: str | None = None, **jit_kwargs):
+    """``jax.jit(fn)`` under the name ``<entry>[_<tag>]``: the XLA module
+    is ``jit_<entry>_<tag>`` in a device trace, where a bare
+    ``functools.partial`` would be ``jit__unknown``. Every
+    ``@compile_contract`` factory builds its jit here. The name is all
+    that changes: ``fn`` is traced as it is."""
+    import jax
+
+    named = functools.partial(fn)
+    named.__name__ = named.__qualname__ = \
+        f"{entry}_{tag}" if tag else entry
+    return jax.jit(named, **jit_kwargs)
+
+
 # -- the declaration decorator ------------------------------------------------
 
 def _is_jitted(obj) -> bool:
@@ -208,10 +245,15 @@ def _is_jitted(obj) -> bool:
     return callable(obj) and hasattr(obj, "_cache_size")
 
 
-def _note_compiles(entry: str, n: int) -> None:
-    from yugabyte_db_tpu.utils import metrics
+def _note_compiles(entry: str, n: int, wall_ns: int, seconds: float) -> None:
+    from yugabyte_db_tpu.utils import metrics, trace
 
     metrics.count_jit_compile(entry, n)
+    # The dispatch that compiled: its whole call, trace + compile (or
+    # the load from the persistent cache) + enqueue.
+    trace.record_span("engine.compile", wall_ns, int(seconds * 1e6),
+                      metrics.jit_compile_histogram(entry), seconds=True,
+                      entry=entry)
     if _WITNESS.enabled:
         _WITNESS.record(entry, n)
 
@@ -234,6 +276,7 @@ class ContractedJit:
             before = fn._cache_size()
         except Exception:  # noqa: BLE001 — probe is best-effort
             before = None
+        t0 = time.perf_counter()
         out = fn(*args, **kwargs)
         if before is not None:
             try:
@@ -241,7 +284,9 @@ class ContractedJit:
             except Exception:  # noqa: BLE001 — probe is best-effort
                 delta = 0
             if delta > 0:
-                _note_compiles(self._entry, delta)
+                dt = time.perf_counter() - t0
+                _note_compiles(self._entry, delta,
+                               time.time_ns() - int(dt * 1e9), dt)
         return out
 
     def __getattr__(self, name):
@@ -254,7 +299,8 @@ def compile_contract(entry: str, max_compiles: int):
     Pure-literal usage only (string + int constants), so the static pass
     can read the declaration off the AST. Two shapes:
 
-    - a **factory** returning ``jax.jit(...)`` — decorate *under* the
+    - a **factory** returning ``jitting.jit(fn, entry, tag)`` (which
+      names the program after the entry) — decorate *under* the
       ``lru_cache`` so the signature cache keeps one wrapper per
       signature::
 

@@ -19,6 +19,7 @@ everything in one pass, grouping series by metric name.
 
 from __future__ import annotations
 
+import bisect
 import logging
 import threading
 import time
@@ -74,12 +75,7 @@ class Histogram:
         self._lock = threading.Lock()
 
     def observe(self, v) -> None:
-        i = 0
-        for i, b in enumerate(self.buckets):
-            if v <= b:
-                break
-        else:
-            i = len(self.buckets)
+        i = bisect.bisect_left(self.buckets, v)   # first bound >= v
         with self._lock:
             self.counts[i] += 1
             self.count += 1
@@ -419,8 +415,9 @@ def observe_lock_hold_s(cls: str, seconds: float) -> None:
 
 def resource_witness_entity() -> MetricEntity:
     """The process-registry entity carrying the resource-witness
-    counters (``yb_resource_pin_acquires`` / ``yb_resource_pin_releases``
-    / ``yb_resource_holds_across_blocking``) — process-wide, so the
+    counters (``yb_resource_pin_acquires`` /
+    ``yb_resource_pin_releases``; holds across blocking calls are in the
+    witness dump, with their sites) — process-wide, so the
     series render on every daemon's /metrics scrape."""
     global _RESOURCE_WITNESS_ENTITY
     with _SERVE_LOCK:
@@ -490,14 +487,6 @@ def flush_path_count(path: str) -> int:
     with _SERVE_LOCK:
         ent = _FLUSH_PATH_ENTITIES.get(path)
     return ent.counter("yb_flush_device").get() if ent is not None else 0
-
-
-def group_commit_percentile(p: float):
-    """Approximate percentile of ``yb_group_commit_batch_size`` (0 when
-    no group-commit round has been recorded) — bench/test introspection."""
-    h = _write_path_entity().histogram("yb_group_commit_batch_size",
-                                       buckets=BATCH_SIZE_BUCKETS)
-    return h.percentile(p)
 
 
 # -- plane-encoding observability ---------------------------------------------
@@ -597,7 +586,7 @@ def count_host_verify_rows(n: int) -> None:
 _ELASTICITY_ENTITY: MetricEntity | None = None
 _REQ_LATENCY_ENTITIES: dict[str, MetricEntity] = {}
 
-# Request latencies are client-observed seconds: sub-ms point ops up
+# Request latencies are seconds at the frontend: sub-ms point ops up
 # through multi-second split-stall retries must all land in-range.
 REQUEST_LATENCY_S_BUCKETS = tuple(1e-5 * (2 ** i) for i in range(22))
 
@@ -639,12 +628,12 @@ def leader_moves_total() -> int:
 
 
 def observe_request_latency(proto: str, seconds: float) -> None:
-    """Record one client-observed request latency into the
-    per-protocol histogram ``yb_request_latency_seconds{proto=...}``
-    on the process registry. The traffic sweep feeds this from every
-    op it issues (ycsb_a/ycsb_b/ycsb_e/tpch/redis) and asserts its
-    per-protocol p99 SLOs against the same series a dashboard scrape
-    sees. Never raises."""
+    """Record one statement's latency as its wire frontend saw it
+    (message decoded to reply bytes built) into
+    ``yb_request_latency_seconds{proto=pg|cql|redis}`` on the process
+    registry. Fed by the frontends themselves (yql/pgsql/wire.py,
+    yql/cql/server.py, yql/redis/server.py), never by a client. Never
+    raises."""
     try:
         with _SERVE_LOCK:
             ent = _REQ_LATENCY_ENTITIES.get(proto)
@@ -657,12 +646,74 @@ def observe_request_latency(proto: str, seconds: float) -> None:
         _SWALLOW_LOG.debug("observe_request_latency failed for %s", proto)
 
 
-def request_latency_percentile(proto: str, p: float):
-    """Approximate percentile (seconds) of one protocol's
-    ``yb_request_latency_seconds`` series; 0 when nothing observed."""
-    with _SERVE_LOCK:
-        ent = _REQ_LATENCY_ENTITIES.get(proto)
+# -- span observability (utils/trace.py) ---------------------------------------
+# One labeled entity per (series, label values), made on first use; the
+# hot path is one dict lookup. docs/observability.md lists every series
+# here with the span that feeds it and who reads it.
+_SPAN_ENTITIES: dict[tuple, MetricEntity] = {}
+
+
+def _span_entity(key: tuple, **labels) -> MetricEntity:
+    ent = _SPAN_ENTITIES.get(key)
     if ent is None:
-        return 0
-    return ent.histogram("yb_request_latency_seconds",
-                         buckets=REQUEST_LATENCY_S_BUCKETS).percentile(p)
+        with _SERVE_LOCK:
+            ent = _SPAN_ENTITIES.get(key)
+            if ent is None:
+                ent = _SPAN_ENTITIES[key] = _PROCESS_REGISTRY.entity(
+                    **labels)
+    return ent
+
+
+def span_histogram(name: str) -> Histogram:
+    """``yb_span_us{span=name}``: where a span with no histogram of
+    its own is observed (microseconds)."""
+    return _span_entity(("span", name), span=name).histogram("yb_span_us")
+
+
+def rpc_queue_histogram(method: str) -> Histogram:
+    """``rpc_queue_us{method=pg|cql|redis}`` for the wire frontends (a
+    tserver or master keeps its own, per method, beside
+    ``rpc_latency_us`` in its registry): frame parsed to handler
+    started."""
+    return _span_entity(("queue", method), method=method).histogram(
+        "rpc_queue_us")
+
+
+def engine_phase_histogram(phase: str, route: str) -> Histogram:
+    """``yb_engine_phase_us{phase=issue|wait_fetch|finish, route}``:
+    one observation per scan batch and phase (storage/tpu_engine.py);
+    ``route`` is ``_plan_scan``'s tag of the batch's plans (``mixed``
+    when they differ, ``breaker_host`` for the breaker's fallback)."""
+    return _span_entity(("phase", phase, route), phase=phase,
+                        route=route).histogram("yb_engine_phase_us")
+
+
+def jit_compile_histogram(entry: str) -> Histogram:
+    """``yb_jit_compile_seconds{entry}``: seconds a dispatch spent
+    tracing and compiling, beside ``yb_jit_compiles{entry}``."""
+    return _span_entity(("compile", entry), entry=entry).histogram(
+        "yb_jit_compile_seconds", buckets=REQUEST_LATENCY_S_BUCKETS)
+
+
+def device_upload_histogram() -> Histogram:
+    """``yb_device_upload_seconds``: host time of one run's upload
+    (pad, ``device_put`` of every plane)."""
+    return _span_entity(("upload",)).histogram(
+        "yb_device_upload_seconds", buckets=REQUEST_LATENCY_S_BUCKETS)
+
+
+def count_device_upload_bytes(n: int) -> None:
+    """``yb_device_upload_bytes``: bytes those uploads put on the
+    device."""
+    _span_entity(("upload",)).counter("yb_device_upload_bytes").increment(n)
+
+
+def count_device_dispatch(entry: str, read_bytes: int) -> None:
+    """One device program dispatched for ``entry``:
+    ``yb_device_dispatches{entry}`` += 1 and
+    ``yb_device_program_read_bytes{entry}`` += the resident bytes of the
+    planes its signature names (``ops.device_run.program_read_bytes``),
+    the bytes a roofline sets against the program's device time."""
+    ent = _span_entity(("dispatch", entry), entry=entry)
+    ent.counter("yb_device_dispatches").increment()
+    ent.counter("yb_device_program_read_bytes").increment(read_bytes)

@@ -177,9 +177,6 @@ class ResourceWitness:
                     if row is None:
                         row = self._holds[(cls, kind)] = [0, site]
                     row[0] += 1
-            from yugabyte_db_tpu.utils.metrics import resource_witness_entity
-            resource_witness_entity().counter(
-                "yb_resource_holds_across_blocking").increment()
         except Exception:  # noqa: BLE001 — witness must never throw
             _LOG.debug("note_blocking recording failed for %s", kind)
 

@@ -19,6 +19,7 @@ import threading
 
 from yugabyte_db_tpu.models.datatypes import DataType
 from yugabyte_db_tpu.rpc.messenger import ConnectionContext, Messenger
+from yugabyte_db_tpu.utils import trace
 from yugabyte_db_tpu.utils.metrics import (count_swallowed,
                                            observe_serve_batch)
 from yugabyte_db_tpu.utils.status import (AlreadyPresent, InvalidArgument,
@@ -427,7 +428,14 @@ class CQLServer:
         def handler(_method, payload):
             processor, stream, opcode, body = payload
             if opcode == "execute_batch":
-                return self.service.handle_execute_batch(processor, body)
+                # n statements answered together: n observations
+                with trace.statement("cql", n=len(body)):
+                    return self.service.handle_execute_batch(processor,
+                                                             body)
+            if opcode in (W.OP_QUERY, W.OP_EXECUTE):
+                with trace.statement("cql"):
+                    return self.service.handle_call(processor, stream,
+                                                    opcode, body)
             return self.service.handle_call(processor, stream, opcode, body)
 
         class _Ctx(CQLConnectionContext):

@@ -22,6 +22,7 @@ SQL semantic notes (vs the CQL processor):
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 
 from yugabyte_db_tpu.models.datatypes import DataType
@@ -29,6 +30,7 @@ from yugabyte_db_tpu.models.schema import ColumnKind, ColumnSchema, Schema
 from yugabyte_db_tpu.storage import expr as X
 from yugabyte_db_tpu.storage.row_version import MAX_HT, RowVersion
 from yugabyte_db_tpu.storage.scan_spec import AggSpec, Predicate, ScanSpec
+from yugabyte_db_tpu.utils import trace
 from yugabyte_db_tpu.utils.status import AlreadyPresent, InvalidArgument
 from yugabyte_db_tpu.yql.pgsql import ast
 from yugabyte_db_tpu.yql.pgsql.operations import combine_grouped
@@ -1667,12 +1669,23 @@ class PgProcessor:
         src/yb/yql/pggate/pg_doc_op.h:111 — async batched doc ops):
         keep several tablets' reads in flight and yield results in
         tablet order, so the next tablet's fetch overlaps this one's
-        result consumption. Single-tablet plans stay synchronous."""
+        result consumption. Single-tablet plans stay synchronous.
+
+        The span ``pg.scan_wait`` is the time a scan waited for a
+        ``pg-docop`` worker (submit until the worker starts it; 0 on the
+        synchronous branch): the pool is one for every session of the
+        process."""
         if len(tablets) <= 1:
             for t in tablets:
+                trace.record_span("pg.scan_wait", time.time_ns(), 0)
                 yield t, t.scan(spec_of(t))
             return
         import collections
+
+        def scan_after_wait(t, spec, wall_ns, t0_ns):
+            trace.record_span("pg.scan_wait", wall_ns,
+                              (time.perf_counter_ns() - t0_ns) // 1000)
+            return t.scan(spec)
 
         pool = self._scan_pool()
         futs = collections.deque()
@@ -1681,7 +1694,11 @@ class PgProcessor:
         while idx < len(tablets) or futs:
             while idx < len(tablets) and len(futs) < inflight:
                 t = tablets[idx]
-                futs.append((t, pool.submit(t.scan, spec_of(t))))
+                # (in_context: the statement's Trace follows the scan to
+                # the worker, and from there into the RPC's payload)
+                futs.append((t, pool.submit(
+                    trace.in_context(scan_after_wait), t, spec_of(t),
+                    time.time_ns(), time.perf_counter_ns())))
                 idx += 1
             t, fut = futs.popleft()
             yield t, fut.result()

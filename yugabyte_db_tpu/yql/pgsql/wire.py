@@ -24,6 +24,7 @@ from __future__ import annotations
 import struct
 
 from yugabyte_db_tpu.rpc.messenger import ConnectionContext, Messenger
+from yugabyte_db_tpu.utils import trace
 from yugabyte_db_tpu.utils.status import (AlreadyPresent, InvalidArgument,
                                           NotFound)
 from yugabyte_db_tpu.yql.pgsql.executor import PgProcessor, PgResult
@@ -242,11 +243,20 @@ class PgServiceImpl:
             return error_response("not authenticated", "28000") \
                 + ready_for_query()
         if kind == "Q":
-            return self._query(ctx, payload)
+            # One statement to the histogram and to /rpcz: the message
+            # decoded until its reply bytes are built.
+            with trace.statement("pg"):
+                return self._query(ctx, payload)
         if kind in "PBDECH":
             if ctx.skip_until_sync:
                 return b""  # discard until Sync after an error
             try:
+                if kind in "DE" and self._will_run_portal(ctx, kind,
+                                                          payload):
+                    # The message that executes the portal is the
+                    # statement (a Describe does, when it comes first).
+                    with trace.statement("pg"):
+                        return self._extended(ctx, kind, payload)
                 return self._extended(ctx, kind, payload)
             except Exception as e:  # noqa: BLE001 — protocol error reply
                 ctx.skip_until_sync = True
@@ -363,6 +373,15 @@ class PgServiceImpl:
             return _msg(b"3", b"")  # CloseComplete
         # 'H' Flush: responses are written immediately; nothing buffered.
         return b""
+
+    def _will_run_portal(self, ctx, kind: str, payload: bytes) -> bool:
+        """Whether this Describe or Execute names a bound portal that
+        has not run yet."""
+        if kind == "D" and chr(payload[0]) == "S":
+            return False
+        name, _pos = self._cstr(payload, 1 if kind == "D" else 0)
+        p = ctx.portals.get(name)
+        return p is not None and not p["done"]
 
     def _run_portal(self, ctx, p: dict) -> None:
         """Execute a bound portal once (Describe-portal triggers it so

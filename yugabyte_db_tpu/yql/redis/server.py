@@ -37,6 +37,7 @@ from yugabyte_db_tpu.models.datatypes import DataType
 from yugabyte_db_tpu.models.encoding import prefix_successor
 from yugabyte_db_tpu.models.schema import ColumnKind, ColumnSchema
 from yugabyte_db_tpu.rpc.messenger import Messenger
+from yugabyte_db_tpu.utils import trace
 from yugabyte_db_tpu.storage.scan_spec import ScanSpec
 from yugabyte_db_tpu.utils.metrics import (count_swallowed,
                                            observe_serve_batch)
@@ -1090,8 +1091,10 @@ class RedisServer:
     def listen(self, host: str = "127.0.0.1", port: int = 0):
         def handler(conn, method, args):
             if method == "redis_batch":
-                return self.service.handle_batch(args, conn)
-            return self.service.handle(args, conn)
+                with trace.statement("redis", n=len(args)):
+                    return self.service.handle_batch(args, conn)
+            with trace.statement("redis"):
+                return self.service.handle(args, conn)
         handler.takes_conn = True
 
         from yugabyte_db_tpu.yql.redis.resp import RedisConnectionContext
