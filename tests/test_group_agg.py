@@ -159,3 +159,183 @@ def test_int32_group_column_and_count_col():
     b = tpu.scan(spec)
     assert a.rows == b.rows
     assert len(b.rows) == 11
+
+
+# -- the dense form (PR 25): exactness bounds, collisions, no scatters ---------
+
+def _one_window_program(K, R, grouped, NB=512):
+    """One full window of synthetic flat planes, every row matching and
+    (grouped) in one bucket: group column 1 = 7 everywhere, base column 2
+    = 2^63 - 1, narrow column 3 = 127, so both factors 16256 + c3 sit at
+    the static bound 2^14 - 1. Returns (outputs, N)."""
+    import numpy as np
+
+    from yugabyte_db_tpu.ops import group_agg, row_gather, scan
+    from yugabyte_db_tpu.utils import planes as P
+
+    N = K * R
+    i32 = np.iinfo(np.int32)
+    hi, lo = P.i64_to_ordered_planes(np.array([2**63 - 1], np.int64))
+
+    def plane(v, p=1):
+        return np.broadcast_to(np.asarray(v, np.int32), (K, R, p)).copy()
+
+    def col(cmp):
+        return {"set": np.ones((K, R), bool),
+                "isnull": np.zeros((K, R), bool), "cmp": cmp}
+
+    run = {
+        "valid": np.ones((K, R), bool),
+        "group_start": np.ones((K, R), bool),
+        "tomb": np.zeros((K, R), bool),
+        "live": np.ones((K, R), bool),
+        "ht_hi": np.full((K, R), i32.min, np.int32),
+        "ht_lo": np.full((K, R), i32.min, np.int32),
+        "exp_hi": np.full((K, R), i32.max, np.int32),
+        "exp_lo": np.full((K, R), i32.max, np.int32),
+        "cols": {1: col(plane(7)),
+                 2: col(plane([int(hi[0]), int(lo[0])], 2)),
+                 3: col(plane(127))},
+    }
+    factor = ("+", ("k", 16256), ("c", 3))
+    sig = group_agg.GroupAggSig(
+        B=K, R=R, K=K, NB=NB,
+        cols=(scan.ColSig(1, "i32"), scan.ColSig(2, "i64"),
+              scan.ColSig(3, "i32")),
+        preds=(), apply_preds=True, flat=True,
+        group_cols=((1, 1),) if grouped else (),
+        aggs=(group_agg.GAgg("sum_prod", 2, planes=2,
+                             factors=(factor, factor), need_cols=(2, 3)),
+              group_agg.GAgg("sum_prod", 2, planes=2, need_cols=(2,)),
+              group_agg.GAgg("count", None)))
+    ip, fp = row_gather.pack_params(
+        0, 0, 0, N, (i32.max, i32.max, i32.min, i32.min), [], [])
+    out = group_agg.compiled_grouped(sig)(run, ip, fp)
+    return {k: np.asarray(v) for k, v in out.items()}, N
+
+
+@pytest.mark.parametrize("grouped,K", [(True, 8), (True, 64),
+                                       (False, 8), (False, 64)])
+def test_full_window_in_one_bucket_at_the_static_bounds_is_exact(grouped, K):
+    """The worst case of one window's reduction: K * R rows (16,384 and
+    131,072) all in one bucket, every digit vector at its maximum,
+    against Python's integers."""
+    out, N = _one_window_program(K, 2048, grouped)
+    live = [int(b) for b in out["count"].nonzero()[0]]
+    assert len(live) == 1 and int(out["count"][live[0]]) == N
+    b = live[0]
+    assert int(out["negs"]) == 0 and int(out["collisions"]) == 0
+    assert int(out["scanned"]) == N and int(out["rep"][b]) == 0
+
+    def value(digits):
+        return sum(int(d) << (16 * k) for k, d in enumerate(digits))
+
+    assert value(out["a0"][b]) == N * (2**63 - 1) * 16383 * 16383
+    assert value(out["a1"][b]) == N * (2**63 - 1)
+    assert int(out["n0"][b]) == int(out["n1"][b]) == int(out["a2"][b]) == N
+    if grouped:
+        assert out["key"][b].tolist() == [7, 0]   # the value, not null
+
+
+@pytest.mark.parametrize("grouped", [True, False],
+                         ids=["grouped", "ungrouped"])
+def test_a_window_past_the_exactness_bound_is_refused(grouped):
+    """K * R * 127 < 2^30 (7-bit pieces in int8, int32 sums with room
+    for the accumulator): asserted on the signature where the program is
+    built. 2^24 rows a window are past it, 2^23 are not."""
+    import dataclasses
+
+    from yugabyte_db_tpu.ops import group_agg, scan
+
+    sig = group_agg.GroupAggSig(
+        B=8192, R=2048, K=8192, NB=512, cols=(scan.ColSig(1, "i32"),),
+        preds=(), apply_preds=True, flat=True,
+        group_cols=((1, 1),) if grouped else (),
+        aggs=(group_agg.GAgg("count", None),))
+    with pytest.raises(ValueError, match="rows_per_block=2048"):
+        group_agg.compiled_grouped(sig)
+    group_agg.compiled_grouped(dataclasses.replace(sig, K=4096))
+
+
+def test_a_run_of_a_million_rows_is_one_window():
+    from yugabyte_db_tpu.ops import group_agg
+
+    assert group_agg.window_blocks(384, 2048) == 384    # the benchmark's
+    assert group_agg.window_blocks(64, 256) == 64
+    assert group_agg.window_blocks(1024, 2048) == 512   # two windows
+    assert group_agg.window_blocks(7 * 64, 4096) == 224
+    assert group_agg.window_blocks(3, 1 << 21) == 1
+
+
+def _fallbacks():
+    from yugabyte_db_tpu.utils import metrics
+
+    return metrics.grouped_agg_fallbacks()
+
+
+def test_forced_collision_is_reported_counted_and_answered_exactly(
+        monkeypatch):
+    """Three groups in two buckets: the program counts the rows whose
+    key is not their bucket's, the host row scan answers, and
+    yb_grouped_agg_fallbacks{reason="collision"} grows by one."""
+    from yugabyte_db_tpu.ops import group_agg
+
+    monkeypatch.setattr(group_agg, "NUM_BUCKETS", 2)
+    cpu, tpu, ht = _load(num=600)
+    spec = ScanSpec(read_ht=ht + 1, group_by=["flag"],
+                    aggregates=[AggSpec("count", None),
+                                AggSpec("sum", "price")])
+    _kind, (sig, ip, fp) = tpu._grouped_prep(tpu.runs[0], spec, [])
+    assert sig.NB == 2
+    out = group_agg.compiled_grouped(sig)(tpu.runs[0].dev.arrays, ip, fp)
+    assert int(out["collisions"]) > 0
+    before = _fallbacks()
+    a = cpu.scan(spec)
+    b = tpu.scan(spec)
+    assert a.rows == b.rows and len(b.rows) == 3
+    after = _fallbacks()
+    assert after["collision"] == before["collision"] + 1
+    assert after["negs"] == before["negs"]
+    assert after["decode"] == before["decode"]
+
+
+def test_negative_base_fallback_is_counted_and_clean_runs_are_not():
+    cpu, tpu, ht = _load(num=400, negatives=True)
+    spec = ScanSpec(read_ht=ht + 1, group_by=["flag"],
+                    aggregates=[AggSpec("sum", "price")])
+    before = _fallbacks()
+    assert cpu.scan(spec).rows == tpu.scan(spec).rows
+    mid = _fallbacks()
+    assert mid["negs"] == before["negs"] + 1
+    spec = ScanSpec(read_ht=ht + 1, group_by=["flag"],
+                    aggregates=[AggSpec("sum", "qty")])
+    assert cpu.scan(spec).rows == tpu.scan(spec).rows
+    assert _fallbacks() == mid
+    from yugabyte_db_tpu.utils.metrics import process_registry
+
+    text = process_registry().prometheus_text()
+    for reason in ("negs", "collision", "decode"):
+        assert f'yb_grouped_agg_fallbacks{{reason="{reason}"}}' in text
+
+
+@pytest.mark.parametrize("group_by", [[], ["flag", "status"]],
+                         ids=["ungrouped", "grouped"])
+def test_no_scatter_in_the_lowered_program(group_by):
+    """The guard that keeps a serialized TPU scatter from coming back:
+    neither the ungrouped (Q6) nor the grouped flat (Q1) signature lowers
+    to one, inside the window loop or outside it; the grouped one holds
+    the two int8 products instead."""
+    from yugabyte_db_tpu.ops import group_agg
+
+    _cpu, tpu, ht = _load(num=300)
+    spec = ScanSpec(read_ht=ht + 1, group_by=group_by,
+                    aggregates=list(Q1_AGGS),
+                    predicates=[Predicate("d", "<", 900)])
+    _kind, (sig, ip, fp) = tpu._grouped_prep(tpu.runs[0], spec,
+                                             spec.predicates)
+    assert sig.flat and bool(sig.group_cols) == bool(group_by)
+    text = group_agg.compiled_grouped(sig).lower(
+        tpu.runs[0].dev.arrays, ip, fp).as_text()
+    assert "while" in text
+    assert "scatter" not in text
+    assert text.count("dot_general") == (2 if group_by else 0)

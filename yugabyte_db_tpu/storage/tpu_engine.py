@@ -2569,8 +2569,10 @@ class TpuStorageEngine(StorageEngine):
         int_lits, f32_lits = self._pred_host_literals(exact_preds)
         row_lo = crun.lower_row(spec.lower)
         row_hi = crun.upper_row(spec.upper)
+        R = crun.R
+        K = group_agg.window_blocks(trun.dev.B, R)
         sig = group_agg.GroupAggSig(
-            B=trun.dev.B, R=crun.R, K=WINDOW_BLOCKS,
+            B=trun.dev.B, R=R, K=K,
             NB=group_agg.NUM_BUCKETS, cols=self._col_sigs(),
             preds=pred_sigs, apply_preds=True,
             flat=crun.max_group_versions <= 1,
@@ -2580,8 +2582,6 @@ class TpuStorageEngine(StorageEngine):
             agg = Aggregator(spec.aggregates, spec.group_by or [])
             empty = ScanResult(agg.column_names(), agg.results(), None, 0)
             return ("empty", ("issued", [], lambda _f: empty))
-        K = WINDOW_BLOCKS
-        R = crun.R
         w_first = row_lo // (K * R)
         w_last = (row_hi - 1) // (K * R)
         ip, fp = row_gather.pack_params(
@@ -2666,24 +2666,29 @@ class TpuStorageEngine(StorageEngine):
 
 
     def _finish_grouped(self, crun, spec, sig, res, fallback):
+        def give_up(reason):
+            # The program's answer is thrown away and the scan served
+            # again as a host row scan: never silently.
+            metrics.count_grouped_agg_fallback(reason)
+            return fallback()
+
         NB = sig.NB
         count = np.asarray(res["count"])[:NB]
         live = np.nonzero(count > 0)[0]
         if int(res["negs"]) > 0:
-            return fallback()  # negative base values: digits invalid
-        km = np.asarray(res["keymin"])[:NB]
-        kM = np.asarray(res["keymax"])[:NB]
-        if live.size and sig.group_cols and \
-                not (km[live] == kM[live]).all():
-            return fallback()  # bucket collision: rehash on host
+            return give_up("negs")  # negative base values: digits invalid
+        if int(res["collisions"]) > 0:
+            return give_up("collision")  # two groups, one bucket
+        keys = np.asarray(res["key"])[:NB]
 
         group_names = list(spec.group_by or [])
         rows = []
         reps = np.asarray(res["rep"])[:NB]
         for b in live:
-            gvals = self._decode_group(crun, spec, sig, km[b], int(reps[b]))
+            gvals = self._decode_group(crun, spec, sig, keys[b],
+                                       int(reps[b]))
             if gvals is None:
-                return fallback()
+                return give_up("decode")
             aggs = []
             for i, (a, ga) in enumerate(zip(spec.aggregates, sig.aggs)):
                 if ga.kind == "count":
@@ -2706,7 +2711,7 @@ class TpuStorageEngine(StorageEngine):
         return ScanResult(names, rows, None, int(res["scanned"]))
 
     def _decode_group(self, crun, spec, sig, key_planes, rep):
-        """Bucket key planes (verified min==max) -> python group values.
+        """Bucket key planes (no collision counted) -> python group values.
         Strings decode from the representative row's merged state."""
         from yugabyte_db_tpu.storage.merge import merge_versions
 

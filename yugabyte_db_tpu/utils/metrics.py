@@ -717,3 +717,31 @@ def count_device_dispatch(entry: str, read_bytes: int) -> None:
     ent = _span_entity(("dispatch", entry), entry=entry)
     ent.counter("yb_device_dispatches").increment()
     ent.counter("yb_device_program_read_bytes").increment(read_bytes)
+
+
+GROUPED_AGG_FALLBACK_REASONS = ("negs", "collision", "decode")
+
+
+def _grouped_fallback_counter(reason: str) -> Counter:
+    return _span_entity(("grouped_fallback", reason),
+                        reason=reason).counter("yb_grouped_agg_fallbacks")
+
+
+def count_grouped_agg_fallback(reason: str) -> None:
+    """``yb_grouped_agg_fallbacks{reason=negs|collision|decode}``: a
+    grouped-aggregate program's answer was thrown away and the scan
+    served again as a host row scan (storage/tpu_engine.py
+    ``_finish_grouped``): a negative base or factor, two groups in one
+    bucket, a group value the host cannot decode."""
+    _grouped_fallback_counter(reason).increment()
+
+
+def grouped_agg_fallbacks() -> dict[str, int]:
+    """Current ``yb_grouped_agg_fallbacks`` by reason."""
+    return {r: _grouped_fallback_counter(r).get()
+            for r in GROUPED_AGG_FALLBACK_REASONS}
+
+
+# (every reason reads 0 on /metrics from the start: a series that is
+# missing cannot be told from one that never grew)
+grouped_agg_fallbacks()
