@@ -5,8 +5,10 @@ collision/negative fallbacks) to Aggregator semantics — the TPC-H Q1/Q6
 machinery.
 """
 
+import functools
 import random
 
+import numpy as np
 import pytest
 
 from yugabyte_db_tpu.models.datatypes import DataType
@@ -168,8 +170,6 @@ def _one_window_program(K, R, grouped, NB=512):
     (grouped) in one bucket: group column 1 = 7 everywhere, base column 2
     = 2^63 - 1, narrow column 3 = 127, so both factors 16256 + c3 sit at
     the static bound 2^14 - 1. Returns (outputs, N)."""
-    import numpy as np
-
     from yugabyte_db_tpu.ops import group_agg, row_gather, scan
     from yugabyte_db_tpu.utils import planes as P
 
@@ -210,8 +210,9 @@ def _one_window_program(K, R, grouped, NB=512):
               group_agg.GAgg("count", None)))
     ip, fp = row_gather.pack_params(
         0, 0, 0, N, (i32.max, i32.max, i32.min, i32.min), [], [])
-    out = group_agg.compiled_grouped(sig)(run, ip, fp)
-    return {k: np.asarray(v) for k, v in out.items()}, N
+    vec = group_agg.compiled_grouped(sig)(
+        run, group_agg.pack_params(sig, ip, fp))
+    return group_agg.unpack(sig, np.asarray(vec)), N
 
 
 @pytest.mark.parametrize("grouped,K", [(True, 8), (True, 64),
@@ -285,9 +286,10 @@ def test_forced_collision_is_reported_counted_and_answered_exactly(
     spec = ScanSpec(read_ht=ht + 1, group_by=["flag"],
                     aggregates=[AggSpec("count", None),
                                 AggSpec("sum", "price")])
-    _kind, (sig, ip, fp) = tpu._grouped_prep(tpu.runs[0], spec, [])
+    _kind, (sig, params) = tpu._grouped_prep(tpu.runs[0], spec, [])
     assert sig.NB == 2
-    out = group_agg.compiled_grouped(sig)(tpu.runs[0].dev.arrays, ip, fp)
+    out = group_agg.unpack(sig, np.asarray(group_agg.compiled_grouped(sig)(
+        tpu.runs[0].dev.arrays, params)))
     assert int(out["collisions"]) > 0
     before = _fallbacks()
     a = cpu.scan(spec)
@@ -331,11 +333,128 @@ def test_no_scatter_in_the_lowered_program(group_by):
     spec = ScanSpec(read_ht=ht + 1, group_by=group_by,
                     aggregates=list(Q1_AGGS),
                     predicates=[Predicate("d", "<", 900)])
-    _kind, (sig, ip, fp) = tpu._grouped_prep(tpu.runs[0], spec,
+    _kind, (sig, params) = tpu._grouped_prep(tpu.runs[0], spec,
                                              spec.predicates)
     assert sig.flat and bool(sig.group_cols) == bool(group_by)
     text = group_agg.compiled_grouped(sig).lower(
-        tpu.runs[0].dev.arrays, ip, fp).as_text()
+        tpu.runs[0].dev.arrays, params).as_text()
     assert "while" in text
     assert "scatter" not in text
     assert text.count("dot_general") == (2 if group_by else 0)
+
+
+# -- the jit boundary (PR 28): one vector in, one vector out --------------------
+
+Q6_AGGS = [AggSpec("sum", None, label="revenue",
+                   expr=BinOp("*", Col("price"), Col("disc")))]
+PACKED_SHAPES = {
+    # aggregates, predicates of lane i (literals differ lane by lane)
+    "q1": (Q1_AGGS, lambda i: [Predicate("d", "<", 900 - 40 * i)]),
+    "q6": (Q6_AGGS, lambda i: [Predicate("qty", "<", 25 + i),
+                               Predicate("d", ">=", 100 + 30 * i)]),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _packed_case(shape, grouped, flat):
+    """(engine run arrays, sig, eight lanes' packed params, each lane's
+    dict from the program as it was before the packing: a jit of
+    ``grouped_aggregate`` itself, two parameter vectors in, a dict out)."""
+    import jax
+
+    from yugabyte_db_tpu.ops import group_agg
+
+    _cpu, tpu, ht = _load(num=300, versions=1 if flat else 3)
+    aggs, preds = PACKED_SHAPES[shape]
+    sigs, lanes = set(), []
+    for i in range(8):
+        spec = ScanSpec(read_ht=ht + 1 - i, aggregates=list(aggs),
+                        group_by=["flag", "status"] if grouped else [],
+                        predicates=preds(i))
+        _kind, (sig, params) = tpu._grouped_prep(tpu.runs[0], spec,
+                                                 spec.predicates)
+        sigs.add(sig)
+        lanes.append(params)
+    (sig,) = sigs
+    assert sig.flat == flat and bool(sig.group_cols) == grouped
+    arrays = tpu.runs[0].dev.arrays
+    n = group_agg.int_params(sig)
+    plain = jax.jit(functools.partial(group_agg.grouped_aggregate, sig))
+    want = [jax.device_get(plain(arrays, p[:n], p[n:].view(np.float32)))
+            for p in lanes]
+    return arrays, sig, lanes, want
+
+
+def _assert_same_bits(got: dict, want: dict):
+    assert set(got) == set(want)
+    for name, w in want.items():
+        g = got[name]
+        assert g.dtype == w.dtype == np.int32 and g.shape == w.shape, name
+        assert g.tobytes() == np.asarray(w).tobytes(), name
+
+
+@pytest.mark.parametrize("lanes", [0, 1, 3, 8],
+                         ids=["single", "vmap1", "vmap3", "vmap8"])
+@pytest.mark.parametrize("flat", [True, False],
+                         ids=["flat", "multi_version"])
+@pytest.mark.parametrize("grouped", [True, False],
+                         ids=["NA_eq_NB", "NA_ne_NB"])
+@pytest.mark.parametrize("shape", ["q1", "q6"])
+def test_unpacked_vector_is_the_dict_bit_for_bit(shape, grouped, flat,
+                                                 lanes):
+    """``unpack(sig, program(arrays, params))`` against the dict the same
+    traced function gives unpacked: Q1's and Q6's aggregates, with group
+    columns (NA == NB) and without (NA != NB: bucket 0, padded), flat and
+    multi-version runs, alone and as lanes of the vmapped program (padded
+    to a power of two, as the engine pads)."""
+    from yugabyte_db_tpu.ops import group_agg
+    from yugabyte_db_tpu.storage.tpu_engine import TpuStorageEngine
+
+    arrays, sig, params, want = _packed_case(shape, grouped, flat)
+    if not lanes:
+        vec = np.asarray(group_agg.compiled_grouped(sig)(arrays, params[0]))
+        assert vec.dtype == np.int32 and vec.ndim == 1
+        _assert_same_bits(group_agg.unpack(sig, vec), want[0])
+        return
+    m = 1 << (lanes - 1).bit_length()
+    stacked = np.zeros((m, params[0].size), np.int32)
+    stacked[:lanes] = params[:lanes]
+    res = np.asarray(TpuStorageEngine._batched_grouped_fn(sig)(arrays,
+                                                               stacked))
+    assert res.shape == (m, sum(
+        int(np.prod(s)) for _o, s in group_agg.out_layout(sig).values()))
+    for i in range(lanes):
+        _assert_same_bits(group_agg.unpack(sig, res[i]), want[i])
+    # (a lane that differs from lane 0 somewhere: the lanes are not one)
+    if lanes > 1:
+        assert res[0].tobytes() != res[lanes - 1].tobytes()
+
+
+def test_out_layout_is_a_pure_function_of_the_signature():
+    import dataclasses
+
+    from yugabyte_db_tpu.ops import group_agg
+
+    _arrays, sig, params, want = _packed_case("q1", True, True)
+    layout = group_agg.out_layout(sig)
+    assert layout == group_agg.out_layout(dataclasses.replace(sig))
+    # what the program's size or the parameters are is not in it
+    assert layout == group_agg.out_layout(
+        dataclasses.replace(sig, B=sig.B * 4, R=128, K=2, preds=()))
+    # back to back, in grouped_aggregate's documented order, every
+    # output there and nothing else
+    assert list(layout)[:6] == ["count", "rep", "key", "collisions",
+                                "scanned", "negs"]
+    assert set(layout) == set(want[0])
+    off = 0
+    for name, (o, shape) in layout.items():
+        assert o == off and shape == want[0][name].shape, name
+        off += int(np.prod(shape))
+    # the parameter vector: row_gather's nine, the literals' ints, then
+    # the float literals' bits
+    assert group_agg.int_params(sig) == 9 + 1 and params[0].size == 11
+    assert group_agg.out_layout(dataclasses.replace(sig, group_cols=()))[
+        "key"] == (2 * sig.NB, (sig.NB, 1))
+    with pytest.raises(ValueError, match="int parameters"):
+        group_agg.pack_params(sig, np.zeros(3, np.int32),
+                              np.zeros(1, np.float32))

@@ -20,6 +20,7 @@ from yugabyte_db_tpu.utils import jitting, metrics, trace
 from yugabyte_db_tpu.utils.fault_injection import arm_fault_once
 
 PHASES = ("issue", "wait_fetch", "finish")
+ISSUE_PARTS = ("plan", "dispatch", "copy_out")
 
 
 # -- the primitive ------------------------------------------------------------
@@ -248,12 +249,22 @@ def test_each_engine_phase_is_observed_once_a_batch(route):
         results = tpu.scan_batch(specs)
     assert len(results) == len(specs)
     assert _phase_counts(route) == [n + 1 for n in before]
-    spans = [s for s in t.dump()["spans"] if s["name"].startswith("engine.")
-             and s["name"] != "engine.compile"
-             and s["name"] != "engine.upload"]
+    engine = [s for s in t.dump()["spans"] if s["name"].startswith("engine.")
+              and s["name"] != "engine.compile"
+              and s["name"] != "engine.upload"]
+    parts = [s for s in engine if s["name"].startswith("engine.issue.")]
+    spans = [s for s in engine if s not in parts]
     assert sorted(s["name"] for s in spans) == sorted(
         "engine." + p for p in PHASES)
     assert {s["route"] for s in spans} == {route}
+    # The issue phase's three parts, once a batch that reached the
+    # device path, inside engine.issue and adding up to less than it.
+    assert sorted(s["name"] for s in parts) == (
+        [] if route == "breaker_host" else sorted(
+            "engine.issue." + p for p in ISSUE_PARTS))
+    assert {s["parent"] for s in parts} <= {"engine.issue"}
+    issue = next(s for s in spans if s["name"] == "engine.issue")
+    assert sum(s["duration_us"] for s in parts) <= issue["duration_us"]
     if route == "breaker_host":
         assert tpu.breaker.stats()["last_error"] is not None
 
@@ -273,6 +284,47 @@ def test_a_fault_at_the_fetch_moves_the_batch_to_the_breaker_route(
     before = _phase_counts("breaker_host")
     assert batch.finish()[0].rows
     assert _phase_counts("breaker_host")[1:] == [n + 1 for n in before[1:]]
+
+
+def _moved(entry):
+    text = metrics.process_registry().prometheus_text()
+    return tuple(_series(text, name, entry=entry, **labels) for name, labels
+                 in (("yb_device_dispatches", {}),
+                     ("yb_device_transfers", {"dir": "h2d"}),
+                     ("yb_device_transfers", {"dir": "d2h"})))
+
+
+@pytest.mark.parametrize("n_specs,entry", [(1, "grouped_aggregate"),
+                                           (3, "batched_grouped")])
+def test_a_grouped_batch_moves_one_array_each_way(n_specs, entry):
+    """A grouped scan costs the runtime three operations: its parameters
+    go up as one array, one program runs, its answer comes down as one
+    (``yb_device_transfers`` over ``yb_device_dispatches`` = 1 and 1),
+    alone or as the lanes of one vmapped dispatch; and the issue phase
+    opens its three parts once a batch."""
+    import jax
+
+    schema, cpu, tpu, ht = _load(300)
+    specs = [ScanSpec(read_ht=ht + 1, group_by=["d"],
+                      predicates=[Predicate("a", ">=", 10 * i)],
+                      aggregates=[AggSpec("count", None),
+                                  AggSpec("sum", "a")])
+             for i in range(n_specs)]
+    tpu.scan_batch(specs)    # compiled and resident
+    before = _moved(entry)
+    parts = [metrics.engine_issue_part_histogram(p).count
+             for p in ISSUE_PARTS]
+    batch = tpu.scan_batch_async(specs)
+    outs = {id(o): o for _pi, o, _fin in batch.issued_outs}
+    assert len(outs) == 1                       # the lanes share it
+    (out,) = jax.tree.leaves(list(outs.values()))
+    assert isinstance(out, jax.Array) and out.dtype == "int32"
+    assert out.ndim == (1 if n_specs == 1 else 2)
+    got = batch.finish()
+    assert [m - b for m, b in zip(_moved(entry), before)] == [1, 1, 1]
+    assert [metrics.engine_issue_part_histogram(p).count
+            for p in ISSUE_PARTS] == [n + 1 for n in parts]
+    assert [r.rows for r in got] == [cpu.scan(s).rows for s in specs]
 
 
 def test_first_scan_records_upload_and_compile():
@@ -456,8 +508,8 @@ def test_q1_and_q6_get_different_names_that_keep_the_old_patterns():
     ht = tpch.load_engine(eng, schema, 300)
     names = []
     for spec in (tpch.q1_spec(ht + 1), tpch.q6_spec(ht + 1)):
-        _kind, (sig, _ip, _fp) = eng._grouped_prep(eng.runs[0], spec,
-                                                   spec.predicates)
+        _kind, (sig, _params) = eng._grouped_prep(eng.runs[0], spec,
+                                                  spec.predicates)
         from yugabyte_db_tpu.ops import group_agg
 
         names.append(group_agg.compiled_grouped(sig).__name__)

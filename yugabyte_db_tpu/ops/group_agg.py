@@ -52,9 +52,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from dataclasses import dataclass
 
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from yugabyte_db_tpu.ops.scan import I32_MAX, _eval_pred, resolve_window
@@ -253,6 +255,11 @@ def _int8_dot(a, b, axis_a, axis_b):
                            preferred_element_type=jnp.int32)
 
 
+def _key_planes(sig: GroupAggSig) -> int:
+    """Width of ``key``: each group column's planes and its null flag."""
+    return max(1, sum(p + 1 for _c, p in sig.group_cols))
+
+
 def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
     """Traced program: one dispatch over [w_first, w_last] windows.
 
@@ -260,7 +267,8 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
                      e_hi, e_lo, scan_from, *int predicate literals]
     (the row_gather params layout — reuses pack_params).
 
-    Returns a dict of arrays keyed per output (fetched in one transfer):
+    Returns a dict of arrays keyed per output (``out_layout`` has the
+    shapes; ``compiled_grouped`` packs them into one vector):
       count[NB] i32, rep[NB] i32 (min matching global row, I32_MAX if
       none), key[NB, KP] i32 (the key planes of the bucket's rows),
       collisions i32 (matching rows whose key differs from their
@@ -280,7 +288,7 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
     pred_literals = _unpack_literals(sig, iparams, fparams)
 
     unfiltered = dataclasses.replace(sig, apply_preds=False)
-    KP = max(1, sum(p + 1 for _c, p in sig.group_cols))  # planes+null/col
+    KP = _key_planes(sig)
     NA = NB if sig.group_cols else 1   # buckets the loop accumulates
 
     def init_acc():
@@ -429,9 +437,77 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
         for name, v in acc.items()}
 
 
+# -- the jit boundary: one array in, one array out -----------------------------
+# A call into the runtime costs the host a fixed time for every array it
+# moves (the upload of a numpy argument, a copy_to_host_async, a buffer
+# in device_get), whatever the array's size: the program takes its
+# parameters as ONE int32 vector and gives its outputs as ONE.
+
+def int_params(sig: GroupAggSig) -> int:
+    """Length of ``iparams`` (row_gather's layout): the packed parameter
+    vector holds the bits of ``fparams`` behind it."""
+    from yugabyte_db_tpu.ops.row_gather import PARAM_FIXED
+
+    return PARAM_FIXED + sum(
+        0 if ps.kind == "f32" else 1 if ps.kind in ("i32", "code") else 2
+        for ps in sig.preds)
+
+
+def pack_params(sig: GroupAggSig, iparams, fparams) -> np.ndarray:
+    """Host side: i32[P] and f32[F] -> the program's one i32[P + F]."""
+    n = int_params(sig)
+    if iparams.size != n:
+        raise ValueError(f"{iparams.size} int parameters for a signature "
+                         f"of {n}")
+    return np.concatenate([iparams, fparams.view(np.int32)])
+
+
+def out_layout(sig: GroupAggSig) -> dict:
+    """{output: (offset, shape)} of the packed result vector, from the
+    signature alone, in ``grouped_aggregate``'s documented order."""
+    NB = sig.NB
+    shapes = {"count": (NB,), "rep": (NB,), "key": (NB, _key_planes(sig)),
+              "collisions": (), "scanned": (), "negs": ()}
+    for i, ag in enumerate(sig.aggs):
+        if ag.kind == "count":
+            shapes[f"a{i}"] = (NB,)
+        else:
+            shapes[f"a{i}"] = (NB, DIGITS)
+            shapes[f"n{i}"] = (NB,)
+    layout, off = {}, 0
+    for name, shape in shapes.items():
+        layout[name] = (off, shape)
+        off += math.prod(shape)
+    return layout
+
+
+def unpack(sig: GroupAggSig, vec) -> dict:
+    """A fetched result vector (numpy int32) -> ``grouped_aggregate``'s
+    dict, as views of it."""
+    return {name: vec[off:off + math.prod(shape)].reshape(shape)
+            for name, (off, shape) in out_layout(sig).items()}
+
+
+def _packed(sig: GroupAggSig, run, params):
+    n = int_params(sig)
+    out = grouped_aggregate(
+        sig, run, params[:n],
+        lax.bitcast_convert_type(params[n:], jnp.float32))
+    layout = out_layout(sig)
+    have = {name: (v.shape, v.dtype) for name, v in out.items()}
+    want = {name: (shape, jnp.int32) for name, (_o, shape) in layout.items()}
+    if have != want:
+        raise AssertionError(f"out_layout is {want}, the program gives "
+                             f"{have}")
+    return jnp.concatenate([out[name].reshape(-1) for name in layout])
+
+
 @functools.lru_cache(maxsize=64)
 @compile_contract("grouped_aggregate", max_compiles=64)
 def compiled_grouped(sig: GroupAggSig):
+    """The program of a signature: ``(run arrays, params i32[P + F]) ->
+    i32[L]``; ``pack_params`` makes the one, ``unpack`` reads the
+    other."""
     check_window_bound(sig)
-    return jitting.jit(functools.partial(grouped_aggregate, sig),
+    return jitting.jit(functools.partial(_packed, sig),
                        "grouped_aggregate", sig.tag())

@@ -223,19 +223,31 @@ def _record_phase(phase: str, route: str, wall_ns: int, ns: int) -> None:
                       route=route)
 
 
-def _count_dispatch(entry: str, dev, sig) -> None:
+def _issue_part(part: str) -> trace.span:
+    """One of the three parts of ``engine.issue`` on the device path
+    (plan, dispatch, copy_out): ``yb_engine_issue_part_us{part}``."""
+    return trace.span("engine.issue." + part,
+                      metrics.engine_issue_part_histogram(part))
+
+
+def _count_dispatch(entry: str, dev, sig, args, outs) -> None:
     """One aggregate program of ``entry`` dispatched over ``dev``'s
     planes: ``yb_device_dispatches`` and, for the roofline, the resident
     bytes of the planes ``sig`` names (ops.device_run.program_read_bytes:
     its group, aggregate and predicate columns' value planes, every
     column's presence planes, the MVCC planes; not the rest of the
     run). A vmapped batch counts its planes once. The sum is kept on
-    ``dev`` (one upload of one run) by signature."""
+    ``dev`` (one upload of one run) by signature. ``args`` (what the
+    call passed beside ``dev.arrays``) and ``outs`` are the arrays the
+    dispatch has the runtime move, parameters up and outputs down:
+    their leaves are counted in ``yb_device_transfers{dir, entry}``."""
     cache = dev.__dict__.setdefault("_read_bytes", {})
     nbytes = cache.get(sig)
     if nbytes is None:
         nbytes = cache[sig] = _sig_read_bytes(dev.arrays, sig)
-    metrics.count_device_dispatch(entry, nbytes)
+    metrics.count_device_dispatch(entry, nbytes,
+                                  h2d=len(jax.tree.leaves(args)),
+                                  d2h=len(jax.tree.leaves(outs)))
 
 
 def _sig_read_bytes(arrays: dict, sig) -> int:
@@ -1570,94 +1582,100 @@ class TpuStorageEngine(StorageEngine):
     def _scan_batch_async_device(self, specs: list[ScanSpec],
                                  deadline=None) -> "_AsyncBatch":
         self._device_fault_point()
-        agg_sink: list = []
-        grouped_sink: list = []
-        plans = [self._plan_scan(s, agg_sink=agg_sink,
-                                 grouped_sink=grouped_sink)
-                 for s in specs]
-
-        results: list = [None] * len(plans)
-        issued_outs = []
-        host_plans = []
-        page_items: list[tuple[int, tuple]] = []
-        gathers: list[tuple[int, "_GatherScan"]] = []
-        pre_work = []
-        deferred: list = []
-        gdeferred: list = []
-        for pi, plan in enumerate(plans):
-            if plan[0] == "host":
-                host_plans.append((pi, plan[1]))
-            elif plan[0] == "page":
-                page_items.append((pi, plan[1]))
-            elif plan[0] == "issued":
-                issued_outs.append((pi, plan[1], plan[2]))
-                if len(plan) > 3:  # host work to overlap with the fetch
-                    pre_work.append(plan[3])
-            elif plan[0] == "agg_deferred":
-                deferred.append(pi)
-            elif plan[0] == "grouped_deferred":
-                gdeferred.append(pi)
-            else:
-                gathers.append((pi, plan[1]))
-        # Residency pins for the issue→finish window: every run a device
-        # plan references stays resident until finish() releases it, so
-        # eviction can't drop planes an in-flight dispatch still holds.
-        # Unbounded full scans pin at low priority — they stream through
-        # the cache's low-pri pool instead of flushing the protected
-        # working set (the overlay's masked primary is pinned separately
-        # by the engine's overlay cache).
-        want_pins: dict[int, tuple[TpuRun, str]] = {}
-
-        def want_pin(trun, priority):
-            if isinstance(trun, _MaskedRun):
-                return
-            prev = want_pins.get(id(trun))
-            if prev is None or priority == "high":
-                want_pins[id(trun)] = (trun, priority)
-
-        for _pi, st in gathers:
-            want_pin(st.trun,
-                     "low" if st.mode == "chunks" else "high")
-        for trun, spec, _exact in agg_sink:
-            want_pin(trun, self._scan_priority(spec))
-        for item in grouped_sink:
-            want_pin(item[0], self._scan_priority(item[1]))
         # Until the _AsyncBatch below takes ownership (its finish path
         # unpins), any failure while pinning or planning must unwind the
         # pins already taken, or those entries stay unevictable for the
         # process lifetime.
         pins = []
         try:
-            for trun, priority in want_pins.values():
-                trun.pin(priority)
-                pins.append(trun)
-            if deferred:
-                # Single-source device aggregates dispatch together: one
-                # vmapped program per (run, signature) group.
-                items = [(pi, trun, spec, exact)
-                         for pi, (trun, spec, exact)
-                         in zip(deferred, agg_sink)]
-                issued_outs.extend(
-                    self._plan_device_aggregate_batch(items))
-            if gdeferred:
-                items = [(pi, trun, spec, exact, payload)
-                         for pi, (trun, spec, exact, payload)
-                         in zip(gdeferred, grouped_sink)]
-                issued_outs.extend(self._plan_grouped_batch(items))
-            # Page items defer wholesale to finish() (device work
-            # first); host_page.serve_pages runs them through the
-            # native page server.
-            pages = page_items
+            with _issue_part("plan"):
+                agg_sink: list = []
+                grouped_sink: list = []
+                plans = [self._plan_scan(s, agg_sink=agg_sink,
+                                         grouped_sink=grouped_sink)
+                         for s in specs]
 
-            states = dict(gathers)
-            pending = {pi: st.pending for pi, st in gathers
-                       if st.pending}
-            dispatches = (self._issue_round(states, pending)
-                          if pending else [])
-            for leaf in jax.tree.leaves([[d for _c, d in dispatches],
-                                         [o for _pi, o, _f
-                                          in issued_outs]]):
-                leaf.copy_to_host_async()
+                results: list = [None] * len(plans)
+                issued_outs = []
+                host_plans = []
+                page_items: list[tuple[int, tuple]] = []
+                gathers: list[tuple[int, "_GatherScan"]] = []
+                pre_work = []
+                deferred: list = []
+                gdeferred: list = []
+                for pi, plan in enumerate(plans):
+                    if plan[0] == "host":
+                        host_plans.append((pi, plan[1]))
+                    elif plan[0] == "page":
+                        page_items.append((pi, plan[1]))
+                    elif plan[0] == "issued":
+                        issued_outs.append((pi, plan[1], plan[2]))
+                        if len(plan) > 3:  # host work to overlap the fetch
+                            pre_work.append(plan[3])
+                    elif plan[0] == "agg_deferred":
+                        deferred.append(pi)
+                    elif plan[0] == "grouped_deferred":
+                        gdeferred.append(pi)
+                    else:
+                        gathers.append((pi, plan[1]))
+                # Residency pins for the issue→finish window: every run a
+                # device plan references stays resident until finish()
+                # releases it, so eviction can't drop planes an in-flight
+                # dispatch still holds. Unbounded full scans pin at low
+                # priority — they stream through the cache's low-pri pool
+                # instead of flushing the protected working set (the
+                # overlay's masked primary is pinned separately by the
+                # engine's overlay cache).
+                want_pins: dict[int, tuple[TpuRun, str]] = {}
+
+                def want_pin(trun, priority):
+                    if isinstance(trun, _MaskedRun):
+                        return
+                    prev = want_pins.get(id(trun))
+                    if prev is None or priority == "high":
+                        want_pins[id(trun)] = (trun, priority)
+
+                for _pi, st in gathers:
+                    want_pin(st.trun,
+                             "low" if st.mode == "chunks" else "high")
+                for trun, spec, _exact in agg_sink:
+                    want_pin(trun, self._scan_priority(spec))
+                for item in grouped_sink:
+                    want_pin(item[0], self._scan_priority(item[1]))
+                for trun, priority in want_pins.values():
+                    trun.pin(priority)
+                    pins.append(trun)
+            with _issue_part("dispatch"):
+                if deferred:
+                    # Single-source device aggregates dispatch together:
+                    # one vmapped program per (run, signature) group.
+                    items = [(pi, trun, spec, exact)
+                             for pi, (trun, spec, exact)
+                             in zip(deferred, agg_sink)]
+                    issued_outs.extend(
+                        self._plan_device_aggregate_batch(items))
+                if gdeferred:
+                    items = [(pi, trun, spec, exact, payload)
+                             for pi, (trun, spec, exact, payload)
+                             in zip(gdeferred, grouped_sink)]
+                    issued_outs.extend(self._plan_grouped_batch(items))
+                # Page items defer wholesale to finish() (device work
+                # first); host_page.serve_pages runs them through the
+                # native page server.
+                pages = page_items
+
+                states = dict(gathers)
+                pending = {pi: st.pending for pi, st in gathers
+                           if st.pending}
+                dispatches = (self._issue_round(states, pending)
+                              if pending else [])
+            with _issue_part("copy_out"):
+                # (the lanes of a vmapped dispatch share one array)
+                leaves = jax.tree.leaves([[d for _c, d in dispatches],
+                                          [o for _pi, o, _f
+                                           in issued_outs]])
+                for leaf in {id(leaf): leaf for leaf in leaves}.values():
+                    leaf.copy_to_host_async()
             return _AsyncBatch(self, results, host_plans, issued_outs,
                                gathers, states, pending, dispatches,
                                pages, pre_work, pins, specs=specs,
@@ -2502,8 +2520,9 @@ class TpuStorageEngine(StorageEngine):
         """Device GROUP BY / expression aggregates (ops.group_agg) — the
         TPC-H Q1/Q6 path. Host-side planning only: returns None when the
         spec isn't device-lowerable (caller falls back), ("empty", plan)
-        for empty ranges, or ("params", (sig, ip, fp)) ready for a
-        single or vmapped-batch dispatch."""
+        for empty ranges, or ("params", (sig, params)) ready for a
+        single or vmapped-batch dispatch (``params``: the program's one
+        int32 vector, group_agg.pack_params)."""
         from yugabyte_db_tpu.ops import group_agg, row_gather
         from yugabyte_db_tpu.storage import expr as X
 
@@ -2587,7 +2606,7 @@ class TpuStorageEngine(StorageEngine):
         ip, fp = row_gather.pack_params(
             w_first, w_last, row_lo, row_hi, self._read_plane_ints(spec),
             int_lits, f32_lits)
-        return ("params", (sig, ip, fp))
+        return ("params", (sig, group_agg.pack_params(sig, ip, fp)))
 
     def _grouped_finish(self, trun: TpuRun, spec: ScanSpec, exact_preds,
                         sig):
@@ -2595,18 +2614,18 @@ class TpuStorageEngine(StorageEngine):
             return self._row_scan(spec, [trun], False,
                                   (exact_preds, [], []), aggregate=True)
 
-        return lambda f: self._finish_grouped(trun.crun, spec, sig, f,
-                                              fallback)
+        return lambda vec: self._finish_grouped(trun.crun, spec, sig, vec,
+                                                fallback)
 
     def _dispatch_grouped(self, trun: TpuRun, spec: ScanSpec,
                           exact_preds, prep):
         from yugabyte_db_tpu.ops import group_agg
 
-        sig, ip, fp = prep
+        sig, params = prep
         fn = group_agg.compiled_grouped(sig)
         dev = trun.dev
-        out = fn(dev.arrays, ip, fp)
-        _count_dispatch("grouped_aggregate", dev, sig)
+        out = fn(dev.arrays, params)
+        _count_dispatch("grouped_aggregate", dev, sig, params, out)
         return ("issued", out,
                 self._grouped_finish(trun, spec, exact_preds, sig))
 
@@ -2620,58 +2639,55 @@ class TpuStorageEngine(StorageEngine):
         from yugabyte_db_tpu.ops import group_agg
 
         base = group_agg.compiled_grouped(sig)
-        return jitting.jit(jax.vmap(base, in_axes=(None, 0, 0)),
+        return jitting.jit(jax.vmap(base, in_axes=(None, 0)),
                            "batched_grouped", sig.tag())
 
     def _plan_grouped_batch(self, items):
         """Batched grouped aggregates (the concurrent TPC-H Q1 shape):
         group prepped specs by (run, signature), stack their packed
         param vectors (padded to the next power of two), one vmapped
-        dispatch per group; per-lane finishes slice the stacked
-        outputs. items = [(pi, trun, spec, exact, (sig, ip, fp))];
-        returns [(pi, outs, finish)]."""
+        dispatch per group (``[m, P] -> [m, L]``); per-lane finishes
+        take their row of the fetched result. items = [(pi, trun, spec,
+        exact, (sig, params))]; returns [(pi, outs, finish)]."""
         groups: dict = {}
         out = []
-        for pi, trun, spec, exact, (sig, ip, fp) in items:
+        for pi, trun, spec, exact, (sig, params) in items:
             groups.setdefault((id(trun), sig), []).append(
-                (pi, trun, spec, exact, sig, ip, fp))
-        for grp in groups.values():
+                (pi, trun, spec, exact, params))
+        for (_trun_id, sig), grp in groups.items():
             if len(grp) == 1:
-                pi, trun, spec, exact, sig, ip, fp = grp[0]
+                pi, trun, spec, exact, params = grp[0]
                 _tag, outs, fin = self._dispatch_grouped(
-                    trun, spec, exact, (sig, ip, fp))
+                    trun, spec, exact, (sig, params))
                 out.append((pi, outs, fin))
                 continue
-            _pi0, trun, _s0, _e0, sig, ip0, fp0 = grp[0]
+            trun = grp[0][1]
             n = len(grp)
             m = 1 << (n - 1).bit_length()
-            ip0 = np.asarray(ip0)
-            fp0 = np.asarray(fp0)
-            ip_b = np.zeros((m,) + ip0.shape, ip0.dtype)
-            fp_b = np.zeros((m,) + fp0.shape, fp0.dtype)
-            for i, (_pi, _t, _s, _e, _sig, ip, fp) in enumerate(grp):
-                ip_b[i] = np.asarray(ip)
-                fp_b[i] = np.asarray(fp)
+            params_b = np.zeros((m, grp[0][-1].size), np.int32)
+            for i, (*_item, params) in enumerate(grp):
+                params_b[i] = params
             fn = self._batched_grouped_fn(sig)
             dev = trun.dev
-            res = fn(dev.arrays, ip_b, fp_b)
-            _count_dispatch("batched_grouped", dev, sig)
-            for i, (pi, trun_i, spec, exact, sig_i, _ip, _fp) in \
-                    enumerate(grp):
-                fin1 = self._grouped_finish(trun_i, spec, exact, sig_i)
+            res = fn(dev.arrays, params_b)
+            _count_dispatch("batched_grouped", dev, sig, params_b, res)
+            for i, (pi, trun_i, spec, exact, _params) in enumerate(grp):
+                fin1 = self._grouped_finish(trun_i, spec, exact, sig)
                 out.append((pi, res,
-                            lambda f, i=i, fin1=fin1:
-                            fin1({k: v[i] for k, v in f.items()})))
+                            lambda f, i=i, fin1=fin1: fin1(f[i])))
         return out
 
 
-    def _finish_grouped(self, crun, spec, sig, res, fallback):
+    def _finish_grouped(self, crun, spec, sig, vec, fallback):
+        from yugabyte_db_tpu.ops import group_agg
+
         def give_up(reason):
             # The program's answer is thrown away and the scan served
             # again as a host row scan: never silently.
             metrics.count_grouped_agg_fallback(reason)
             return fallback()
 
+        res = group_agg.unpack(sig, vec)
         NB = sig.NB
         count = np.asarray(res["count"])[:NB]
         live = np.nonzero(count > 0)[0]
@@ -3331,10 +3347,9 @@ class TpuStorageEngine(StorageEngine):
                     lits_b[k][i] = l
             fn = self._batched_agg_fn(route, sig)
             dev = trun.dev
-            ivec, fvec = fn(dev.arrays, row_lo_b, row_hi_b,
-                            planes_b[0], planes_b[1], planes_b[2],
-                            planes_b[3], tuple(lits_b))
-            _count_dispatch("batched_agg", dev, sig)
+            args = (row_lo_b, row_hi_b, *planes_b, tuple(lits_b))
+            ivec, fvec = fn(dev.arrays, *args)
+            _count_dispatch("batched_agg", dev, sig, args, (ivec, fvec))
             for i, (pi, _t, spec, (_sig, _r, _rlo, _rhi, _pl, _lits,
                                    dev_aggs, lowering)) in enumerate(grp):
                 fin1 = self._agg_finish(spec, dev_aggs, lowering,
@@ -3355,19 +3370,15 @@ class TpuStorageEngine(StorageEngine):
         pred_lits = tuple(jnp.asarray(l) for l in lits)
         fn = self._agg_route_fn(route, sig)
         dev = trun.dev
+        windows = ()
         if route == "full":
             W = dev.B // sig.K
-            w_first, w_last = agg_fold.window_bounds(row_lo, row_hi,
-                                                     sig.R, sig.K, W)
-            ivec, fvec = fn(dev.arrays, jnp.int32(row_lo),
-                            jnp.int32(row_hi),
-                            jnp.int32(w_first), jnp.int32(w_last),
-                            r_hi_, r_lo_, e_hi_, e_lo_, pred_lits)
-        else:
-            ivec, fvec = fn(dev.arrays, jnp.int32(row_lo),
-                            jnp.int32(row_hi), r_hi_, r_lo_, e_hi_, e_lo_,
-                            pred_lits)
-        _count_dispatch(route + "_aggregate", dev, sig)
+            windows = tuple(jnp.int32(w) for w in agg_fold.window_bounds(
+                row_lo, row_hi, sig.R, sig.K, W))
+        args = (jnp.int32(row_lo), jnp.int32(row_hi), *windows,
+                r_hi_, r_lo_, e_hi_, e_lo_, pred_lits)
+        ivec, fvec = fn(dev.arrays, *args)
+        _count_dispatch(route + "_aggregate", dev, sig, args, (ivec, fvec))
         return [ivec, fvec], self._agg_finish(spec, dev_aggs, lowering,
                                               raw=raw)
 

@@ -688,6 +688,14 @@ def engine_phase_histogram(phase: str, route: str) -> Histogram:
                         route=route).histogram("yb_engine_phase_us")
 
 
+def engine_issue_part_histogram(part: str) -> Histogram:
+    """``yb_engine_issue_part_us{part=plan|dispatch|copy_out}``: the
+    issue phase of a device scan batch in three parts, one observation
+    each per batch (storage/tpu_engine.py ``_scan_batch_async_device``)."""
+    return _span_entity(("issue_part", part), part=part).histogram(
+        "yb_engine_issue_part_us")
+
+
 def jit_compile_histogram(entry: str) -> Histogram:
     """``yb_jit_compile_seconds{entry}``: seconds a dispatch spent
     tracing and compiling, beside ``yb_jit_compiles{entry}``."""
@@ -708,15 +716,23 @@ def count_device_upload_bytes(n: int) -> None:
     _span_entity(("upload",)).counter("yb_device_upload_bytes").increment(n)
 
 
-def count_device_dispatch(entry: str, read_bytes: int) -> None:
+def count_device_dispatch(entry: str, read_bytes: int, h2d: int,
+                          d2h: int) -> None:
     """One device program dispatched for ``entry``:
-    ``yb_device_dispatches{entry}`` += 1 and
+    ``yb_device_dispatches{entry}`` += 1,
     ``yb_device_program_read_bytes{entry}`` += the resident bytes of the
     planes its signature names (``ops.device_run.program_read_bytes``),
-    the bytes a roofline sets against the program's device time."""
+    the bytes a roofline sets against the program's device time, and
+    ``yb_device_transfers{dir=h2d|d2h, entry}`` += the arrays the
+    dispatch hands the runtime to move: parameters up, outputs down
+    (each costs the host a call, whatever its size)."""
     ent = _span_entity(("dispatch", entry), entry=entry)
     ent.counter("yb_device_dispatches").increment()
     ent.counter("yb_device_program_read_bytes").increment(read_bytes)
+    for direction, n in (("h2d", h2d), ("d2h", d2h)):
+        _span_entity(("transfer", entry, direction), dir=direction,
+                     entry=entry).counter(
+                         "yb_device_transfers").increment(n)
 
 
 GROUPED_AGG_FALLBACK_REASONS = ("negs", "collision", "decode")
