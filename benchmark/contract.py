@@ -35,9 +35,12 @@ def cell_metrics(bench: dict, workload: str, trace: bool) -> dict[str, str]:
 
 def build(*, correct: bool, attempted: int, failed: int, metrics: dict,
           units: dict[str, str], device: dict,
-          breakdown: dict | None = None) -> dict:
+          breakdown: dict | None = None,
+          compared: dict | None = None) -> dict:
     """``metrics`` is {name: number}; a name the cell does not declare, or
-    one whose reader found nothing (None), is left out."""
+    one whose reader found nothing (None), is left out. ``compared`` is
+    {name: [number, limit]}, what ``correct`` was decided from; it comes
+    last in the line."""
     line = {
         "correct": bool(correct), "attempted": int(attempted),
         "failed": int(failed),
@@ -47,6 +50,8 @@ def build(*, correct: bool, attempted: int, failed: int, metrics: dict,
     }
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if compared is not None:
+        line["compared"] = compared
     return line
 
 
@@ -65,9 +70,18 @@ def validate(line: dict, bench: dict, workload: str, trace: bool) -> None:
     missing = want - line.keys()
     if missing:
         raise Malformed(f"missing keys {sorted(missing)}")
-    extra = line.keys() - want - {"breakdown"}
+    extra = line.keys() - want - {"breakdown", "compared"}
     if extra:
         raise Malformed(f"unknown keys {sorted(extra)}")
+    if "compared" in line:
+        if list(line)[-1] != "compared" \
+                or not isinstance(line["compared"], dict):
+            raise Malformed("compared is not an object at the line's end")
+        for name, pair in line["compared"].items():
+            if not (isinstance(pair, list) and len(pair) == 2):
+                raise Malformed(f"compared.{name} is not [number, limit]")
+            _number(pair[0], f"compared.{name}")
+            _number(pair[1], f"compared.{name}'s limit")
     if not isinstance(line["correct"], bool):
         raise Malformed("correct is not a boolean")
     for k in ("attempted", "failed"):

@@ -30,6 +30,7 @@ import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
+import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -123,6 +124,23 @@ def merge_client(results: list[dict]) -> dict:
         "answers": [a for r in results for a in r.get("answers", [])],
         "errors": [e for r in results for e in r.get("errors", [])],
     }
+
+
+def thirds(client: dict, seconds: float) -> dict:
+    """By class of operation and third of the window (by the time an
+    operation was answered): [median latency in ms, operations answered,
+    mean number in flight]. What one run's level is made of: thirds that
+    differ among themselves as runs do are drift inside a run, flat
+    thirds at a level of the run's own are a mode of the process."""
+    third = seconds / 3
+    out = {}
+    for k, lats in client["latency_ms"].items():
+        parts: list[list[float]] = [[], [], []]
+        for ms, t in zip(lats, client["done_s"][k]):
+            parts[min(2, int(t // third))].append(ms)
+        out[k] = [[statistics.median(p) if p else None, len(p),
+                   sum(p) / 1e3 / third] for p in parts]
+    return out
 
 
 # -- the metrics --------------------------------------------------------------
@@ -417,6 +435,8 @@ def measure(args, bench, cell, cfg, traffic, children, scratch,
         k: [sum(i * step <= t < (i + 1) * step for t in ts)
             for i in range(int(max(ts, default=0) // step) + 1)]
         for k, ts in client["done_s"].items() if ts}))
+    log("thirds of the window by class [p50 ms, answered, mean in flight]: "
+        + json.dumps(thirds(client, args.seconds)))
     log(f"flushes in the window: device {flush_dev:g} host {flush_host:g}; "
         f"whole run device {metrics.flush_path_count('device')} host "
         f"{metrics.flush_path_count('host')}")
@@ -431,10 +451,15 @@ def measure(args, bench, cell, cfg, traffic, children, scratch,
         log(f"  client error: {e}")
     for line in dep.replica_state():
         log("  " + line)
-    log("compared (value, limit): " + json.dumps(compared))
     for p in problems:
         log(f"  PROBLEM: {p}")
     correct = wrong == 0 and not problems
+    # Everything `correct` rests on, each number beside its limit: the
+    # reference's comparison, then what else makes a run not correct.
+    compared.update(operations_failed=[client["failed"], 0],
+                    compiles_in_window=[compiles_in_window, 0],
+                    problems=[len(problems), 0])
+    log("compared (value, limit): " + json.dumps(compared))
 
     ctx = {"client": client, "setup_s": setup_s, "window_s": window_s,
            "registry": (before, after), "trace": trace,
@@ -466,8 +491,11 @@ def measure(args, bench, cell, cfg, traffic, children, scratch,
         failed=min(client["failed"] + wrong, client["attempted"]),
         metrics=values,
         units=contract.cell_metrics(bench, cell["name"], bool(args.trace)),
-        device=device, breakdown=breakdown)
+        device=device, breakdown=breakdown,
+        compared={k: v for k, v in compared.items() if v[1] is not None})
     contract.validate(line, bench, cell["name"], bool(args.trace))
+    for name, (value, limit) in compared.items():
+        print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -47,3 +47,5 @@ def test_sound_run_is_correct(capsys):
 def test_altered_aggregate_is_not_correct(capsys, altered_aggregates):
     line = last_line(capsys, "tpch_power_q1q6", 12)
     assert line["correct"] is False and line["failed"] > 0
+    wrong, limit = line["compared"]["answers_wrong"]
+    assert wrong > limit == 0

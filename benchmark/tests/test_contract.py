@@ -21,7 +21,8 @@ def good(trace: bool) -> dict:
         correct=True, attempted=40, failed=0,
         metrics={n: 1.5 for n in units}, units=units, device=device,
         breakdown={"device_ops": [["fusion.1", 0.2]],
-                   "idle_gaps": [["a -> b", 40.0]]} if trace else None)
+                   "idle_gaps": [["a -> b", 40.0]]} if trace else None,
+        compared={"answers_wrong": [0, 0]})
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -31,6 +32,11 @@ def test_good_line_passes(trace):
 
 def _drop_metric(line):
     line["metrics"].pop(next(iter(line["metrics"])))
+
+
+def _compared_first(line):
+    rest = {k: line.pop(k) for k in list(line) if k != "compared"}
+    line.update(rest)
 
 
 def _set(path, value):
@@ -63,6 +69,9 @@ BREAKS = {
     "failed exceeds attempted": (False, _set(("failed",), 41)),
     "breakdown in an untraced run":
         (False, _set(("breakdown",), {"device_ops": []})),
+    "compared is not the line's last key": (False, _compared_first),
+    "a number compared has no limit":
+        (False, _set(("compared", "answers_wrong"), [0, None])),
     "a breakdown list of 11":
         (True, _set(("breakdown", "device_ops"), [["op", 0.1]] * 11)),
 }
