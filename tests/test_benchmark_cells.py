@@ -1,0 +1,53 @@
+"""Every cell of ``BENCHMARK.json`` rehearsed on the CPU, plain and traced.
+
+What ``benchmark/selfcheck.py`` does before a chip call, one case a run:
+``run.py --rehearse-cpu`` in a process of its own, its last line through
+``contract.validate``. The driver runs the cells on the chip only after
+these tests, and a run that exits 1 there (a load generator that died, a
+warm-up that failed, a listed metric missing, an exception) costs the
+whole PR; most of those show here in half a minute. A rehearsal proves
+the harness and the served path's control flow, and nothing about the
+chip: no number of its line is a device number.
+
+``span_histogram_mean`` and ``named_program_time`` read 0 from a program
+that lacks their series, so ``validate`` alone lets a renamed span, label
+or device program through. Every per-layer metric of both cells reads
+above 0 on the CPU backend, so a 0 in a traced line is such a rename.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from benchmark import contract
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = contract.load_benchmark(ROOT)
+# selfcheck.py's seeds and window lengths, by --trace
+SEED = {0: 2_147_483_659, 1: 2_147_483_660}
+SECONDS = {0: 6, 1: 8}
+
+
+@pytest.mark.parametrize("trace", [0, 1], ids=["plain", "traced"])
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_cell_rehearses(cell, trace):
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", cell, "--seed", str(SEED[trace]),
+         "--seconds", str(SECONDS[trace]), "--trace", str(trace),
+         "--rehearse-cpu"],
+        cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300)
+    err = proc.stderr[-800:]
+    assert proc.returncode == 0, f"exit {proc.returncode}: {err}"
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    contract.validate(line, BENCH, cell, bool(trace))
+    assert line["correct"] is True, err
+    assert line["failed"] == 0, err
+    if trace:
+        dark = sorted(n for n, m in line["metrics"].items()
+                      if not m["value"] > 0)
+        assert not dark, f"per-layer metrics that read nothing: {dark}: {err}"
