@@ -766,7 +766,6 @@ def compile_unreached(smoke: Smoke, served: dict) -> dict:
     route the served path did take. Returns {entry: how}."""
     import yugabyte_db_tpu.storage.tpu_engine as te
     from yugabyte_db_tpu.ops import compact as dcompact
-    from yugabyte_db_tpu.ops import pallas_agg  # declares its contract
     from yugabyte_db_tpu.parallel import ShardedTablets
     from yugabyte_db_tpu.storage import (AggSpec, Predicate, ScanSpec,
                                          make_engine)
@@ -849,32 +848,6 @@ def compile_unreached(smoke: Smoke, served: dict) -> dict:
 
     forced_routes(flat_t, flat_c, flat_ht)
     forced_routes(mv_t, mv_c, mv_ht)
-
-    # The Pallas fold (nothing selects it: --tpu_engine_use_pallas has no
-    # reader) through Mosaic; a CPU rehearsal has no Mosaic and says so.
-    if smoke.args.rehearse_cpu:
-        how["pallas_flat_aggregate"] = "NOT compiled: a CPU rehearsal"
-    else:
-        from yugabyte_db_tpu.ops.scan import AggSig, PredSig
-        from yugabyte_db_tpu.utils import planes as PL
-
-        cid = {c.name: c.col_id for c in schema.columns}
-        price, ship, qty = (cid["l_extendedprice"], cid["l_shipdate"],
-                            cid["l_quantity"])
-        aggs = (AggSig("count", None, None), AggSig("sum", price, "i64"),
-                AggSig("min", ship, "i32"))
-        col_order = ((price, True), (ship, False), (qty, False))
-        trun = flat_t.runs[0]
-        iparams = np.array(
-            [0, trun.crun.total_rows(), *PL.scalar_ht_planes(flat_ht),
-             *PL.scalar_ht_planes(flat_ht - 1), 30], np.int32)
-        fn = pallas_agg.compiled_flat_aggregate(
-            trun.dev.B, R, aggs, (PredSig(qty, "i32", "<"),), col_order)
-        _n, _scanned, vals = pallas_agg.combine_partials(
-            np.asarray(fn(trun.pallas_tensors(col_order), iparams)), aggs)
-        check(tuple(vals) == flat_c.scan(at(agg, flat_ht)).rows[0],
-              f"pallas fold {vals} differs from the CPU oracle")
-        how["pallas_flat_aggregate"] = "Mosaic-compiled vs CPU oracle"
 
     # Batched (vmapped) flat + grouped programs: same signature, distinct
     # literals/read points in one scan_batch.
@@ -992,8 +965,7 @@ def compile_unreached(smoke: Smoke, served: dict) -> dict:
     for e in (flat_t, flat_c, mv_t, mv_c):
         e.close()
     after = metrics.jit_compiles()
-    still = [e for e in jitting.declared_contracts() if not after.get(e)
-             and not how.get(e, "").startswith("NOT compiled")]
+    still = [e for e in jitting.declared_contracts() if not after.get(e)]
     check(not still, f"entries that never met the compiler: {still}")
     return {e: how.get(e, "reached while compiling another entry")
             for e in missing}

@@ -115,8 +115,7 @@ def test_smoke_rehearsal_passes_and_says_what_it_is():
     assert report["rehearsal"] and report["reduced"]
     assert report["flushes"]["device"] > 0
     assert all(report["compiles_total"].get(e)
-               for e in report["compiles_served_path"]
-               if e != "pallas_flat_aggregate")  # Mosaic needs the chip
+               for e in report["compiles_served_path"])
 
 
 @pytest.mark.slow

@@ -431,7 +431,6 @@ def _build_entries():
     import numpy as np
     from jax.sharding import Mesh
 
-    from yugabyte_db_tpu.ops import pallas_agg
     from yugabyte_db_tpu.parallel import sharded
     from yugabyte_db_tpu.ops import (agg_fold, compact, flat_fold, flush,
                                      group_agg, lookback_fold, row_gather,
@@ -458,8 +457,6 @@ def _build_entries():
         "dist_page": sharded._compiled_dist_page(gather, mesh, ((), ()),
                                                  1, 4),
         "stack_update": sharded._compiled_stack_update(2, 8, 64, ()),
-        "pallas_flat_aggregate": pallas_agg.compiled_flat_aggregate(
-            8, 64, (), (), (), interpret=True),
         "flat_aggregate": flat_fold.compiled_flat_aggregate(sig),
         "lookback_aggregate":
             lookback_fold.compiled_lookback_aggregate(multi),

@@ -522,7 +522,7 @@ class TrafficSweep:
     def _external_bytes(self) -> int:
         cache = hbm_cache()
         with cache._lock:
-            return sum(e.total_bytes
+            return sum(e.nbytes
                        for pool in cache._pools.values()
                        for e in pool.values() if e.external)
 

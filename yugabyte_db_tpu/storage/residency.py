@@ -78,7 +78,7 @@ def _pin_witness():
 
 class _Entry:
     __slots__ = ("key", "label", "tracker", "owner_ref", "payload",
-                 "nbytes", "aux", "aux_bytes", "pins", "pool", "external",
+                 "nbytes", "pins", "pool", "external",
                  "encoding", "device", "dev_bytes")
 
     def __init__(self, key: int, label: str, tracker,
@@ -89,8 +89,6 @@ class _Entry:
         self.owner_ref = None
         self.payload = None
         self.nbytes = 0
-        self.aux: dict = {}
-        self.aux_bytes = 0
         self.pins = 0
         self.pool = "high"
         self.external = False
@@ -104,10 +102,6 @@ class _Entry:
         # External mesh stacks only: per-device byte map (one shard's
         # bytes on the chip holding it).  None for single-device entries.
         self.dev_bytes: dict | None = None
-
-    @property
-    def total_bytes(self) -> int:
-        return self.nbytes + self.aux_bytes
 
 
 # _dead is deliberately NOT declared: the weakref death callback
@@ -324,32 +318,6 @@ class HbmCache:
             if b and self._dev_resident.get(e.device, 0) > b:
                 self._evict_until(b, e.device)
 
-    # -- derived-tensor side cars (pallas gather tensors) --------------------
-
-    def aux_get(self, key: int, aux_key):
-        with self._lock:
-            self._drain_dead()
-            e = self._entries.get(key)
-            if e is None or e.payload is None:
-                return None
-            return e.aux.get(aux_key)
-
-    def aux_put(self, key: int, aux_key, value, nbytes: int) -> None:
-        """Attach a derived device tensor set to a resident entry; it is
-        charged with — and dropped with — the entry.  A no-op if the
-        entry was evicted meanwhile (the caller still holds ``value``)."""
-        with self._lock:
-            self._drain_dead()
-            e = self._entries.get(key)
-            if e is None or e.payload is None or aux_key in e.aux:
-                return
-            e.aux[aux_key] = value
-            e.aux_bytes += int(nbytes)
-            self._charge(e, int(nbytes))
-            b = self.budget()
-            if b and self._dev_resident.get(e.device, 0) > b:
-                self._evict_until(b, e.device)
-
     # -- internals ------------------------------------------------------------
 
     def _drain_dead(self) -> None:
@@ -381,8 +349,6 @@ class HbmCache:
         e.encoding = ("encoded" if getattr(payload, "encoded", False)
                       else "plain")
         e.nbytes = int(nbytes)
-        e.aux = {}
-        e.aux_bytes = 0
         e.pool = "low" if priority == "low" else "high"
         self._pools[e.pool][e.key] = e
         if pin:
@@ -436,7 +402,7 @@ class HbmCache:
         can't demote another chip's."""
         cap = int(b * HIGH_PRI_POOL_RATIO)
         high = self._pools["high"]
-        hb = sum(en.total_bytes for en in high.values()
+        hb = sum(en.nbytes for en in high.values()
                  if not en.external and en.device == device)
         for k in list(high.keys()):
             if hb <= cap:
@@ -445,7 +411,7 @@ class HbmCache:
             if en.external or en.device != device:
                 continue
             self._move_pool(en, "low")
-            hb -= en.total_bytes
+            hb -= en.nbytes
 
     def _evict_until(self, target: int, device: str | None = None) -> None:
         """Evict LRU-first until ``device``'s bucket (or, with
@@ -468,7 +434,7 @@ class HbmCache:
         return False
 
     def _release_entry(self, e: _Entry, evicted: bool) -> None:
-        total = e.total_bytes
+        total = e.nbytes
         w = _pin_witness()
         if w is not None:
             # Entry teardown retires every pin on the key at once
@@ -476,7 +442,6 @@ class HbmCache:
             w.pins_cleared(e.key)
         self._pools[e.pool].pop(e.key, None)
         e.payload = None
-        e.aux = {}
         self._resident -= total
         if e.dev_bytes is not None:
             for d, n in e.dev_bytes.items():
@@ -487,7 +452,6 @@ class HbmCache:
         if e.tracker is not None:
             e.tracker.release(total)
         e.nbytes = 0
-        e.aux_bytes = 0
         e.pins = 0
         e.encoding = "plain"
         if evicted:
@@ -512,7 +476,7 @@ class HbmCache:
     def pinned_bytes(self) -> int:
         with self._lock:
             self._drain_dead()
-            return sum(e.total_bytes
+            return sum(e.nbytes
                        for pool in self._pools.values()
                        for e in pool.values() if e.pins > 0)
 
@@ -535,7 +499,7 @@ class HbmCache:
             self._drain_dead()
             pools = {
                 name: {"entries": len(pool),
-                       "bytes": sum(e.total_bytes for e in pool.values())}
+                       "bytes": sum(e.nbytes for e in pool.values())}
                 for name, pool in self._pools.items()}
             by_enc: dict[str, dict] = {}
             for pool in self._pools.values():
@@ -543,7 +507,7 @@ class HbmCache:
                     d = by_enc.setdefault(e.encoding,
                                           {"entries": 0, "bytes": 0})
                     d["entries"] += 1
-                    d["bytes"] += e.total_bytes
+                    d["bytes"] += e.nbytes
             b = self.budget()
             by_dev: dict[str, dict] = {
                 dev: {"resident_bytes": n, "budget_bytes": b,
@@ -552,7 +516,7 @@ class HbmCache:
             for pool in self._pools.values():
                 for e in pool.values():
                     devs = (e.dev_bytes if e.dev_bytes is not None
-                            else {e.device: e.total_bytes})
+                            else {e.device: e.nbytes})
                     for dev, n in devs.items():
                         d = by_dev.setdefault(
                             dev, {"resident_bytes": 0, "budget_bytes": b,
@@ -580,7 +544,7 @@ class HbmCache:
 def device_nbytes(tree) -> int:
     """Device bytes of a nested dict/list/tuple of arrays (duck-typed:
     anything with .size and .dtype.itemsize) — the footprint charged for
-    cache payloads and aux tensors."""
+    cache payloads."""
     total = 0
     stack = [tree]
     while stack:

@@ -196,20 +196,6 @@ class TpuRun:
         hbm_cache().acquire(self._res_key, lambda: (dev, dev.nbytes),
                             nbytes_hint=self._nbytes_hint())
 
-    def pallas_tensors(self, col_order: tuple):
-        """Device tensors in the pallas kernel's ref order (bool planes
-        cast to int32, cmp planes sliced), cached on — and evicted
-        with — the run's residency entry."""
-        cache = hbm_cache()
-        aux_key = ("pallas", col_order)
-        t = cache.aux_get(self._res_key, aux_key)
-        if t is None:
-            from yugabyte_db_tpu.ops import pallas_agg
-
-            t = pallas_agg.gather_tensors(self.dev.arrays, col_order)
-            cache.aux_put(self._res_key, aux_key, t, device_nbytes(t))
-        return t
-
 
 def _set_phase(sp, phase: str, route: str) -> None:
     """Point a span at ``yb_engine_phase_us{phase, route}``."""

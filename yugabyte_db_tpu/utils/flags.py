@@ -82,10 +82,6 @@ FLAGS.define("max_clock_skew_us", 500_000,
              ("stable",))
 FLAGS.define("follower_unavailable_considered_failed_sec", 5.0,
              "tserver liveness timeout", ("stable",))
-FLAGS.define("tpu_engine_use_pallas", False,
-             "route eligible flat-run aggregate scans through the "
-             "hand-written Pallas fold kernel (ops.pallas_agg) instead "
-             "of the XLA scan program", ("evolving", "runtime"))
 FLAGS.define("tpu_hbm_budget_bytes", 0,
              "PER-DEVICE capacity budget for device-resident (HBM) "
              "columnar run planes; 0 = unbounded. When set, run planes "
