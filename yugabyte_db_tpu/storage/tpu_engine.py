@@ -2984,8 +2984,8 @@ class TpuStorageEngine(StorageEngine):
 
         Steady-state cost is O(delta): one bisect per touched key plus
         one re-scatter only when new primary rows need clearing — this
-        is what turns the 899ms per-wave overlay rebuild into a
-        sub-50ms update (BENCH_r05 postwrite_scan)."""
+        is what keeps a wave of writes from costing a full overlay
+        rebuild."""
         delta = getattr(mem, "versions_since", lambda _n: None)(since)
         if delta is None:
             return _OVERLAY_REBUILD

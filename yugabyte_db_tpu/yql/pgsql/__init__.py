@@ -8,7 +8,7 @@ Redesigned single-runtime: a SQL parser (parser.py) and executor
 grouped/expression aggregates pushed down to the storage engines (the
 TPU engine runs them as one device dispatch per tablet); pggate.py is
 the embedding API (PgApi/PgSession/PgStatement), wire.py the FE/BE v3
-protocol server, and tpch.py the TPC-H Q1/Q6 workload bench.py measures.
+protocol server, and tpch.py the TPC-H Q1/Q6 workload benchmark/ measures.
 """
 
 from yugabyte_db_tpu.yql.pgsql.executor import PgProcessor, PgResult
