@@ -23,12 +23,11 @@ import sys
 import pytest
 
 from benchmark import contract
+from benchmark.selfcheck import SECONDS  # the window's length, by --trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = contract.load_benchmark(ROOT)
-# selfcheck.py's seeds and window lengths, by --trace
-SEED = {0: 2_147_483_659, 1: 2_147_483_660}
-SECONDS = {0: 6, 1: 8}
+SEED = {0: 2_147_483_659, 1: 2_147_483_660}  # selfcheck.py's, by --trace
 
 
 @pytest.mark.parametrize("trace", [0, 1], ids=["plain", "traced"])
@@ -37,7 +36,7 @@ def test_cell_rehearses(cell, trace):
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
          "--workload", cell, "--seed", str(SEED[trace]),
-         "--seconds", str(SECONDS[trace]), "--trace", str(trace),
+         "--seconds", SECONDS[trace], "--trace", str(trace),
          "--rehearse-cpu"],
         cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, timeout=300)

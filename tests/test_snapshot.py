@@ -195,7 +195,8 @@ def test_master_coordinated_cluster_snapshot():
             for i in range(40, 55):
                 s.insert(table, {"k": f"a{i:03d}", "v": i})
             s.flush()
-            assert _rows(client, table) != baseline
+            diverged = _rows(client, table)
+            assert diverged != baseline
 
             # kill the whole cluster; registry must survive the restart
             mc.shutdown()
@@ -208,6 +209,10 @@ def test_master_coordinated_cluster_snapshot():
             reg = admin.cluster_snapshot("list")["snapshots"]
             assert reg["cs1"]["state"] == "COMPLETE"
 
+            # The master asks each replica of a tablet once for its
+            # leader; a scan returns once every tablet has elected one
+            # after the restart.
+            assert _rows(client, table) == diverged
             admin.cluster_snapshot("restore", snapshot_id="cs1")
             assert _rows(client, table) == baseline
 

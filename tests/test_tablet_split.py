@@ -188,3 +188,35 @@ def test_auto_split_pass_triggers_on_size_threshold(cluster, table):
     res = YBSession(client).scan(
         client.open_table("split_t"), ScanSpec(projection=["k"]))
     assert len(res.rows) == 202
+
+
+def test_admin_split_is_sent_once_however_long_the_master_takes():
+    """``AdminClient.master_rpc`` gives a send 2 s and then sends again.
+    A split the master needs longer for (three daemons on a loaded box)
+    was so requested twice, and the second request is refused: "already
+    running" while the first runs, ``not_found`` once it has committed
+    and the parent has left the catalog. The split's one send waits as
+    long as the master may take."""
+    from yugabyte_db_tpu.consensus.transport import TransportError
+    from yugabyte_db_tpu.tools.admin_client import AdminClient
+
+    class SlowMaster:
+        """A master that needs 3 s for a split and commits it whether or
+        not the requester is still waiting."""
+
+        def __init__(self):
+            self.sends = []
+
+        def send(self, dst, method, payload, timeout):
+            self.sends.append((method, timeout))
+            if len(self.sends) > 1:
+                return {"code": "not_found"}
+            if timeout < 3.0:
+                raise TransportError("timed out")
+            return {"code": "ok", "children": ["c0", "c1"], "split_hash": 7}
+
+    master = SlowMaster()
+    resp = AdminClient(master, ["m-0"]).split_tablet("t", "t-0000",
+                                                     timeout_s=30.0)
+    assert resp["children"] == ["c0", "c1"]
+    assert master.sends == [("master.split_tablet", 35.0)]

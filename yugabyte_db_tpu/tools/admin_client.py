@@ -75,16 +75,21 @@ class AdminClient:
 
     # -- master RPCs ---------------------------------------------------------
     def master_rpc(self, method: str, payload: dict | None = None,
-                   timeout_s: float = 10.0) -> dict:
+                   timeout_s: float = 10.0,
+                   send_timeout_s: float = 2.0) -> dict:
         """Try masters until one answers as leader (yb-admin's leader
-        master discovery loop)."""
+        master discovery loop). One send waits ``send_timeout_s``: 2 s
+        moves on from a dead master quickly. A request the master works
+        on for longer must pass its own budget, because this loop sends
+        again after a send that timed out, while the first request may
+        still be running or have committed."""
         deadline = time.monotonic() + timeout_s
         last = None
         while time.monotonic() < deadline:
             for m in list(self.master_uuids):
                 try:
                     resp = self.transport.send(m, method, payload or {},
-                                               timeout=2.0)
+                                               timeout=send_timeout_s)
                 except TransportError as e:
                     last = str(e)
                     continue
@@ -132,11 +137,15 @@ class AdminClient:
         """Manually split one tablet at its median resident key
         (yb-admin split_tablet): the master drives the whole seal →
         fork → seed → commit protocol and answers with the child
-        tablet ids."""
+        tablet ids. The one send waits as long as the master may take:
+        sent again after 2 s, a split still running is refused as
+        "already running" and one that has committed as ``not_found``
+        (the parent has left the catalog)."""
         resp = self.master_rpc("master.split_tablet",
                                {"table": table, "tablet_id": tablet_id,
                                 "timeout": timeout_s},
-                               timeout_s=timeout_s + 5.0)
+                               timeout_s=timeout_s + 5.0,
+                               send_timeout_s=timeout_s + 5.0)
         if resp.get("code") != "ok":
             raise AdminError(
                 f"split_tablet {tablet_id}: "
