@@ -861,8 +861,14 @@ def compile_unreached(smoke: Smoke, served: dict) -> dict:
           == [flat_c.scan(s).rows for s in q1s], "batched_grouped differs")
     check(flat_t.scan(q1s[0]).rows == flat_c.scan(q1s[0]).rows,
           "grouped_aggregate differs")
+    # (a multi-version run: the XLA prologue gathers each group's newest
+    # planes, the grouped kernel is handed the same row vectors)
+    q1_mv = tpch.q1_spec(mv_ht, 10471)
+    check(mv_t.scan(q1_mv).rows == mv_c.scan(q1_mv).rows,
+          "grouped_aggregate over a multi-version run differs")
     how["batched_grouped"] = how["grouped_aggregate"] = \
-        "Q1 spec(s) vs CPU oracle"
+        "Q1 spec(s) vs CPU oracle: the grouped kernel, alone, under " \
+        "vmap and behind a multi-version run"
 
     # Row paths: a multi-version LIMIT page (gather), then a second run
     # makes the scan multi-source (scan_window) and the aggregate an

@@ -6,6 +6,7 @@ machinery.
 """
 
 import functools
+import math
 import random
 
 import numpy as np
@@ -165,6 +166,27 @@ def test_int32_group_column_and_count_col():
 
 # -- the dense form (PR 25): exactness bounds, collisions, no scatters ---------
 
+def _flat_run(B, R, cols):
+    """Synthetic flat planes of B x R rows, every row live, valid and
+    visible at any read point; ``cols`` = {col_id: (cmp planes [B, R, P],
+    NULL mask [B * R] or None)}."""
+    i32 = np.iinfo(np.int32)
+    return {
+        "valid": np.ones((B, R), bool),
+        "group_start": np.ones((B, R), bool),
+        "tomb": np.zeros((B, R), bool),
+        "live": np.ones((B, R), bool),
+        "ht_hi": np.full((B, R), i32.min, np.int32),
+        "ht_lo": np.full((B, R), i32.min, np.int32),
+        "exp_hi": np.full((B, R), i32.max, np.int32),
+        "exp_lo": np.full((B, R), i32.max, np.int32),
+        "cols": {cid: {"set": np.ones((B, R), bool),
+                       "isnull": (np.zeros((B, R), bool) if null is None
+                                  else null.reshape(B, R)), "cmp": cmp}
+                 for cid, (cmp, null) in cols.items()},
+    }
+
+
 def _one_window_program(K, R, grouped, NB=512):
     """One full window of synthetic flat planes, every row matching and
     (grouped) in one bucket: group column 1 = 7 everywhere, base column 2
@@ -180,23 +202,9 @@ def _one_window_program(K, R, grouped, NB=512):
     def plane(v, p=1):
         return np.broadcast_to(np.asarray(v, np.int32), (K, R, p)).copy()
 
-    def col(cmp):
-        return {"set": np.ones((K, R), bool),
-                "isnull": np.zeros((K, R), bool), "cmp": cmp}
-
-    run = {
-        "valid": np.ones((K, R), bool),
-        "group_start": np.ones((K, R), bool),
-        "tomb": np.zeros((K, R), bool),
-        "live": np.ones((K, R), bool),
-        "ht_hi": np.full((K, R), i32.min, np.int32),
-        "ht_lo": np.full((K, R), i32.min, np.int32),
-        "exp_hi": np.full((K, R), i32.max, np.int32),
-        "exp_lo": np.full((K, R), i32.max, np.int32),
-        "cols": {1: col(plane(7)),
-                 2: col(plane([int(hi[0]), int(lo[0])], 2)),
-                 3: col(plane(127))},
-    }
+    run = _flat_run(K, R, {1: (plane(7), None),
+                           2: (plane([int(hi[0]), int(lo[0])], 2), None),
+                           3: (plane(127), None)})
     factor = ("+", ("k", 16256), ("c", 3))
     sig = group_agg.GroupAggSig(
         B=K, R=R, K=K, NB=NB,
@@ -268,6 +276,182 @@ def test_a_run_of_a_million_rows_is_one_window():
     assert group_agg.window_blocks(3, 1 << 21) == 1
 
 
+# -- the kernel's own edges (PR 32): synthetic planes, Python's integers --------
+
+def _kernel_case(K, R, NB, group_of, row_lo, row_hi, lanes=None,
+                 windows=1):
+    """``windows`` flat windows of K * R rows through ``compiled_grouped``
+    (or its vmap over ``lanes`` = [(row_lo, row_hi)...]): group column 1
+    (int32) = ``group_of(rows)``, group column 4 (int64) = 1000 x column 1,
+    base column 2 (int64) = 10^12 + row, narrow column 3 = row % 100, NULL
+    every 7th row. Returns (sig, [unpacked outputs a lane], planes)."""
+    import jax
+
+    from yugabyte_db_tpu.ops import group_agg, row_gather, scan
+    from yugabyte_db_tpu.utils import planes as P
+
+    B = K * windows
+    N = B * R
+    i32 = np.iinfo(np.int32)
+    rows = np.arange(N, dtype=np.int64)
+    g1 = np.asarray(group_of(rows), np.int64)
+    base = 10**12 + rows
+    narrow = rows % 100
+
+    def i64_planes(v):
+        hi, lo = P.i64_to_ordered_planes(v.astype(np.int64))
+        return np.stack([hi, lo], -1).reshape(B, R, 2).astype(np.int32)
+
+    run = _flat_run(B, R, {
+        1: (g1.astype(np.int32).reshape(B, R, 1), None),
+        2: (i64_planes(base), None),
+        3: (narrow.astype(np.int32).reshape(B, R, 1), rows % 7 == 0),
+        4: (i64_planes(1000 * g1), None)})
+    sig = group_agg.GroupAggSig(
+        B=B, R=R, K=K, NB=NB,
+        cols=(scan.ColSig(1, "i32"), scan.ColSig(2, "i64"),
+              scan.ColSig(3, "i32"), scan.ColSig(4, "i64")),
+        preds=(), apply_preds=True, flat=True,
+        group_cols=((1, 1), (4, 2)),
+        aggs=(group_agg.GAgg("sum_prod", 2, planes=2,
+                             factors=(("+", ("k", 1), ("c", 3)),),
+                             need_cols=(2, 3)),
+              group_agg.GAgg("sum_prod", 3, planes=1, need_cols=(3,)),
+              group_agg.GAgg("count", 3, need_cols=(3,))))
+
+    def packed(lo, hi):
+        ip, fp = row_gather.pack_params(
+            lo // (K * R), (hi - 1) // (K * R), lo, hi,
+            (i32.max, i32.max, i32.min, i32.min), [], [])
+        return group_agg.pack_params(sig, ip, fp)
+
+    fn = group_agg.compiled_grouped(sig)
+    if lanes is None:
+        vecs = [np.asarray(fn(run, packed(row_lo, row_hi)))]
+    else:
+        vecs = np.asarray(jax.jit(jax.vmap(fn, in_axes=(None, 0)))(
+            run, np.stack([packed(lo, hi) for lo, hi in lanes])))
+    return sig, [group_agg.unpack(sig, v) for v in vecs], \
+        {"g1": g1, "base": base, "narrow": narrow, "null3": rows % 7 == 0}
+
+
+def _assert_groups_exact(sig, out, planes, row_lo, row_hi):
+    """Every live bucket of ``out`` against Python's integers over the
+    rows of its key in [row_lo, row_hi)."""
+    rows = np.arange(planes["g1"].size)
+    inside = (rows >= row_lo) & (rows < row_hi)
+    assert int(out["scanned"]) == int(inside.sum())
+    assert int(out["negs"]) == 0 and int(out["collisions"]) == 0
+
+    def value(digits):
+        return sum(int(d) << (16 * k) for k, d in enumerate(digits))
+
+    live = [int(b) for b in out["count"].nonzero()[0]]
+    groups = sorted(set(planes["g1"][inside].tolist()))
+    assert len(live) == len(groups)
+    seen = set()
+    for b in live:
+        g1 = int(out["key"][b][0])
+        assert out["key"][b][1] == 0 and out["key"][b][4] == 0  # not null
+        from yugabyte_db_tpu.utils import planes as P
+        assert int(P.ordered_planes_to_i64(
+            out["key"][b][2:3], out["key"][b][3:4])[0]) == 1000 * g1
+        seen.add(g1)
+        mine = inside & (planes["g1"] == g1)
+        full = mine & ~planes["null3"]
+        assert int(out["count"][b]) == int(mine.sum())
+        assert int(out["rep"][b]) == int(rows[mine].min())
+        assert int(out["n0"][b]) == int(out["n1"][b]) == int(
+            out["a2"][b]) == int(full.sum())
+        assert value(out["a0"][b]) == sum(
+            int(v) * (1 + int(f)) for v, f in
+            zip(planes["base"][full], planes["narrow"][full]))
+        assert value(out["a1"][b]) == int(planes["narrow"][full].sum())
+    assert seen == set(groups)
+
+
+KERNEL_EDGES = {
+    # K, R, group of a row, row_lo, row_hi
+    "ragged_tiles_cut_by_the_bounds":
+        (20, 1024, lambda r: r % 3, 100, 20000),
+    "a_bucket_first_seen_in_tile_2":
+        (20, 1024, lambda r: np.where(r < 17000, r % 2, 2), 0, 20480),
+    "no_row_in_the_first_tile":
+        (20, 1024, lambda r: r % 4, 9000, 19999),
+    "one_tile_smaller_than_a_register_row":
+        (3, 64, lambda r: r % 5, 1, 190),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_EDGES))
+def test_kernel_edges_are_exact(case):
+    """The tile's edges: a window that is no multiple of the tile (the
+    kernel pads it with rows that match nothing), bounds that cut inside
+    a tile, a bucket whose first row, key and ``rep`` come from a later
+    tile than the first, tiles with no matching row at all, and C = 49
+    columns, no multiple of an int8 tile's 32."""
+    from yugabyte_db_tpu.ops import group_agg
+
+    K, R, group_of, lo, hi = KERNEL_EDGES[case]
+    sig, (out,), planes = _kernel_case(K, R, 512, group_of, lo, hi)
+    _KP5, C, CP, _NBP, _KW = group_agg._kernel_dims(sig)
+    assert C == 50 and CP == 128
+    T = group_agg._tile_rows(sig, K * R)
+    if case != "one_tile_smaller_than_a_register_row":
+        assert T == 8192 and (K * R) % T and K * R > 2 * T
+    _assert_groups_exact(sig, out, planes, lo, hi)
+
+
+def test_kernel_carries_counts_and_keys_from_window_to_window():
+    """Two windows of three tiles each: the second window's kernel is
+    handed the buckets' counts and keys of the first (a bucket that goes
+    on keeps its key and its ``rep``, one first seen in window 2 gets
+    both there), the sums add up in the accumulators outside."""
+    lo, hi = 5, 2 * 20480 - 7
+    sig, (out,), planes = _kernel_case(
+        20, 1024, 512, lambda r: np.where(r < 30000, r % 2, 2 + r % 2),
+        lo, hi, windows=2)
+    assert sig.B == 2 * sig.K
+    _assert_groups_exact(sig, out, planes, lo, hi)
+
+
+def test_kernel_lanes_of_a_vmap_are_each_exact():
+    """``batched_grouped``'s shape: the kernel under ``vmap`` (a grid
+    axis a lane), each lane with bounds of its own."""
+    lanes = [(0, 20480), (8193, 16383), (17000, 17001), (0, 1)]
+    sig, outs, planes = _kernel_case(20, 1024, 512, lambda r: r % 3, 0, 0,
+                                     lanes=lanes)
+    for out, (lo, hi) in zip(outs, lanes):
+        _assert_groups_exact(sig, out, planes, lo, hi)
+
+
+def test_kernel_counts_a_collision_between_rows_of_different_tiles():
+    """Two buckets, and two keys of one bucket whose rows lie in
+    different tiles: the bucket keeps the key of the tile that came
+    first, every matching row of the other key is counted."""
+    import jax.numpy as jnp
+
+    from yugabyte_db_tpu.ops import group_agg
+    from yugabyte_db_tpu.utils import planes as P
+
+    def bucket(g):
+        hi, lo = P.i64_to_ordered_planes(np.array([1000 * g], np.int64))
+        key = [jnp.array([g], jnp.int32), jnp.array([0], jnp.int32),
+               jnp.asarray(hi, jnp.int32), jnp.asarray(lo, jnp.int32),
+               jnp.array([0], jnp.int32)]
+        return int(group_agg._bucket_hash(key)[0]) % 2
+
+    a, b = next((a, b) for a in range(8) for b in range(a + 1, 9)
+                if bucket(a) == bucket(b))
+    sig, (out,), _planes = _kernel_case(
+        20, 1024, 2, lambda r: np.where(r < 8192, a, b), 10, 20000)
+    assert group_agg._tile_rows(sig, 20480) == 8192
+    assert int(out["collisions"]) == 20000 - 8192
+    assert int(out["count"][bucket(a)]) == 20000 - 10
+    assert int(out["key"][bucket(a)][0]) == a
+    assert int(out["rep"][bucket(a)]) == 10
+
+
 def _fallbacks():
     from yugabyte_db_tpu.utils import metrics
 
@@ -320,13 +504,31 @@ def test_negative_base_fallback_is_counted_and_clean_runs_are_not():
         assert f'yb_grouped_agg_fallbacks{{reason="{reason}"}}' in text
 
 
+def _equations(jaxpr, inside_kernel=False):
+    """(primitive name, equation, inside a pallas kernel?) of a jaxpr and
+    of every jaxpr its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, eqn, inside_kernel
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(
+                        sub, inside_kernel
+                        or eqn.primitive.name == "pallas_call")
+
+
 @pytest.mark.parametrize("group_by", [[], ["flag", "status"]],
                          ids=["ungrouped", "grouped"])
 def test_no_scatter_in_the_lowered_program(group_by):
     """The guard that keeps a serialized TPU scatter from coming back:
-    neither the ungrouped (Q6) nor the grouped flat (Q1) signature lowers
-    to one, inside the window loop or outside it; the grouped one holds
-    the two int8 products instead."""
+    neither the ungrouped (Q6) nor the grouped flat (Q1) signature traces
+    to one, inside the window loop or outside it. The grouped one is ONE
+    kernel a window that holds the int8 product; what XLA is left with
+    stacks the kernel's dozen row vectors and never the C columns of
+    pieces (PR 25's ``[C, N]`` operand, 1.08 ms a call on the v5e)."""
+    import jax
+
     from yugabyte_db_tpu.ops import group_agg
 
     _cpu, tpu, ht = _load(num=300)
@@ -336,11 +538,30 @@ def test_no_scatter_in_the_lowered_program(group_by):
     _kind, (sig, params) = tpu._grouped_prep(tpu.runs[0], spec,
                                              spec.predicates)
     assert sig.flat and bool(sig.group_cols) == bool(group_by)
-    text = group_agg.compiled_grouped(sig).lower(
-        tpu.runs[0].dev.arrays, params).as_text()
-    assert "while" in text
-    assert "scatter" not in text
-    assert text.count("dot_general") == (2 if group_by else 0)
+    arrays = tpu.runs[0].dev.arrays
+    eqns = list(_equations(jax.make_jaxpr(functools.partial(
+        group_agg._packed, sig))(arrays, params).jaxpr))
+    names = [name for name, _e, _k in eqns]
+    assert "while" in names
+    assert not [n for n in names if "scatter" in n]
+    outside = [(name, e) for name, e, in_kernel in eqns if not in_kernel]
+    assert [n for n, _e in outside].count("dot_general") == 0
+    assert names.count("pallas_call") == (1 if group_by else 0)
+    if not group_by:
+        assert "dot_general" not in names
+        # (the text XLA is given holds none either)
+        assert "scatter" not in group_agg.compiled_grouped(sig).lower(
+            arrays, params).as_text()
+        return
+    assert sum(1 for name, _e, in_kernel in eqns
+               if in_kernel and name == "dot_general") == 1
+    _KP5, C, _CP, _NBP, _KW = group_agg._kernel_dims(sig)
+    N = sig.K * sig.R
+    rows = len(group_agg._kernel_rows(sig)[1]) + 2
+    assert rows < 16 < C
+    widest = max(math.prod(e.outvars[0].aval.shape)
+                 for name, e in outside if name == "concatenate")
+    assert widest == rows * N      # the kernel's operand, not [C, N]
 
 
 # -- the jit boundary (PR 28): one vector in, one vector out --------------------

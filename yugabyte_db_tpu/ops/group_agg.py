@@ -3,36 +3,50 @@
 The TPC-H Q1 shape — few, low-cardinality groups over millions of rows —
 runs as ONE device dispatch: a fori_loop over windows (one window for a
 run of up to a million rows, ``window_blocks``) resolves MVCC visibility
-(ops.scan.resolve_window), applies the predicates, hashes each row's
-group-key planes into a fixed bucket table, and reduces the window into
-the buckets without a scatter (XLA serializes a TPU scatter: 9 ns a row;
-the twelve of Q1's old program were 71 of its 82 ms):
+(ops.scan.resolve_window, which also decodes the run's encoded planes),
+applies the predicates, and reduces the window into a fixed bucket table
+without a scatter (XLA serializes a TPU scatter: 9 ns a row; the twelve
+of Q1's old program were 71 of its 82 ms). The signature decides how,
+nothing else:
 
-- sums and counts: the bucket one-hot ``[NB, N]`` (bucket == iota) times
-  ONE stacked matrix ``[C, N]`` of everything a bucket sums — the 0/1
-  masks of ``count`` / ``n<i>`` / count aggregates, every sum's masked
-  base-2^16 digits cut into 7-bit pieces, the key planes' pieces — as
-  one ``dot_general`` on the MXU: int8 operands (0..127 and 0/1 are
-  exact), int32 accumulation, exact while K * R * 127 < 2^30
-  (``check_window_bound``, asserted where the program is built). The
-  piece sums recombine into the ``[NB, DIGITS]`` int32 accumulators and
-  a per-window carry normalization keeps them inside int32 at any scale
-  (the same discipline as ops.agg_fold's limb sums);
-- ``rep`` (a bucket's first matching row, through which the host decodes
-  string groups): a masked minimum over the same one-hot on the VPU;
-- collisions: a bucket keeps ONE key, taken when its first rows arrive
-  (their key pieces sum to count x piece when they agree). Every
-  matching row's bucket key comes back through a second small one-hot
-  product (one non-zero term a row) and rows whose own key differs are
-  counted in ``collisions``; the host falls back to its row scan when
-  that is non-zero (retry-with-salt left for later; collisions are
-  vanishingly rare with NB >= 16x groups). Varlen group columns are
-  exact only when their values fit the 8-byte device prefix — the engine
-  checks the run's recorded max length before choosing this path;
+- with group columns, ONE Pallas kernel a window, gridded over row tiles
+  (``_window_kernel``; ``_tile_rows`` rows a step). XLA hands it a dozen
+  int32 vectors a row (rowid, the match and not-null masks as bits of a
+  word, the planes of the base, factor and group columns); what is made
+  per row — digits, carries, 7-bit pieces, key pieces, the bucket
+  (``_bucket_hash`` of the key planes), the bucket one-hot — lives in
+  VMEM for the length of a tile and never exists in HBM (as XLA ops it
+  was an ``[109, N]`` int8 operand, 860 launches and 70 MB of
+  temporaries a call of Q1):
+  - sums and counts: the bucket one-hot ``[NB, T]`` (bucket == iota)
+    times ONE matrix ``[C, T]`` of everything a bucket sums — the 0/1
+    masks of ``count`` / ``n<i>`` / count aggregates, every sum's masked
+    base-2^16 digits cut into 7-bit pieces, the key planes' pieces — on
+    the MXU: int8 operands (0..127 and 0/1 are exact), int32
+    accumulation over the grid, exact while K * R * 127 < 2^30
+    (``check_window_bound``, asserted where the program is built). The
+    piece sums recombine into the ``[NB, DIGITS]`` int32 accumulators
+    and a per-window carry normalization keeps them inside int32 at any
+    scale (the same discipline as ops.agg_fold's limb sums);
+  - ``rep`` (a bucket's first matching row, through which the host
+    decodes string groups) and the bucket's key: taken in the tile that
+    first holds a row of the bucket — rows come in rowid order — ``rep``
+    as a masked minimum over the one-hot, the key from the tile's sums
+    (its rows' key pieces sum to count x piece when they agree);
+  - collisions: a bucket keeps ONE key. Every matching row looks its
+    bucket's key planes up (a lane gather of a 128-bucket table) and
+    rows whose own key differs are counted in ``collisions``; the host
+    falls back to its row scan when that is non-zero
+    (retry-with-salt left for later; collisions are vanishingly rare
+    with NB >= 16x groups). Varlen group columns are exact only when
+    their values fit the 8-byte device prefix — the engine checks the
+    run's recorded max length before choosing this path;
+  on a backend that is no TPU the same ``pallas_call`` is interpreted, so
+  the tests and the CPU rehearsals execute the kernel's own body;
 - no group column (Q6, every ungrouped expression sum): no hash, no
-  one-hot, no buckets — ``jnp.sum`` / ``jnp.min`` of the same columns
-  over the rows, into bucket 0 of the same outputs. The signature
-  decides, nothing else.
+  one-hot, no buckets, no kernel — ``jnp.sum`` / ``jnp.min`` of the same
+  columns (``_columns``) over the window's rows, into bucket 0 of the
+  same outputs.
 
 Integer sums (including product expressions like
 sum(price * (100 - disc) * (100 + tax)) over scaled-integer money
@@ -41,7 +55,9 @@ splits into digits and each small factor (statically bounded < 2^14,
 non-negative) multiplies the digit vector with an elementwise carry
 chain. A negative base or factor invalidates the digits: such rows are
 counted in ``negs`` and the host falls back. Every fallback is counted
-(``yb_grouped_agg_fallbacks{reason}``, storage/tpu_engine.py).
+(``yb_grouped_agg_fallbacks{reason}``, storage/tpu_engine.py). The same
+functions build the columns of a window's ``[N]`` vectors (ungrouped)
+and of a tile's ``[S, 128]`` values in the kernel.
 
 Reference analog: the grouped aggregate evaluation the reference runs
 row-at-a-time inside the scan (PgsqlReadOperation::EvalAggregate,
@@ -100,17 +116,16 @@ class GroupAggSig:
                            preds=self.preds, flat=self.flat)
 
 
-def _eval_factor(expr, cmp_w, idx, flat):
-    """Trace a small-factor expression to a per-row int32 vector."""
+def _eval_factor(expr, plane):
+    """Trace a small-factor expression to a per-row int32 vector;
+    ``plane(col_id, i)`` gives a column's i-th plane of the rows."""
     op = expr[0]
     if op == "k":
         return jnp.int32(expr[1])
     if op == "c":
-        col = cmp_w[expr[1]]
-        v = col[:, 0] if flat else col[idx[expr[1]], 0]
-        return v
-    left = _eval_factor(expr[1], cmp_w, idx, flat)
-    right = _eval_factor(expr[2], cmp_w, idx, flat)
+        return plane(expr[1], 0)
+    left = _eval_factor(expr[1], plane)
+    right = _eval_factor(expr[2], plane)
     if op == "+":
         return left + right
     if op == "-":
@@ -131,16 +146,16 @@ def _digits_mul(digits: list, f):
     return out[:DIGITS]
 
 
-def _base_digits(sig_planes, cmp, idx, flat):
+def _base_digits(col_id, sig_planes, plane):
     """Wide base column -> (digit list, value-negative flag per row)."""
     if sig_planes == 1:
-        v = cmp[:, 0] if flat else cmp[idx, 0]
+        v = plane(col_id, 0)
         neg = v < 0
         d0 = v & jnp.int32(0xFFFF)
         d1 = (v >> jnp.int32(16)) & jnp.int32(0x7FFF)
         return [d0, d1], neg
-    hi = cmp[:, 0] if flat else cmp[idx, 0]
-    lo = cmp[:, 1] if flat else cmp[idx, 1]
+    hi = plane(col_id, 0)
+    lo = plane(col_id, 1)
     # ordered planes: u64 = v ^ 2^63 with both words bias-flipped
     hi_u = (hi.view(jnp.uint32) ^ jnp.uint32(0x80000000)).view(jnp.int32)
     lo_u = (lo.view(jnp.uint32) ^ jnp.uint32(0x80000000)).view(jnp.int32)
@@ -233,9 +248,9 @@ def check_window_bound(sig: GroupAggSig) -> None:
             "2^30 to be exact in int32; shrink one")
 
 
-def _bucket_hash(planes, n):
+def _bucket_hash(planes):
     """FNV-ish hash of the key planes, folded to a non-negative int32."""
-    h = jnp.full((n,), 0x01000193, jnp.int32)
+    h = jnp.full(planes[0].shape, 0x01000193, jnp.int32)
     for p in planes:
         h = (h ^ p) * jnp.int32(-2128831035)
     # Avalanche: mod-2^32 multiplies only push bits UP, so values
@@ -260,6 +275,339 @@ def _key_planes(sig: GroupAggSig) -> int:
     return max(1, sum(p + 1 for _c, p in sig.group_cols))
 
 
+def _columns(sig: GroupAggSig, m, notnull, plane):
+    """Everything a bucket sums of its rows but their key, as columns of
+    0..127: the 0/1 masks and the masked digit vectors' pieces
+    (``_column_layout`` says which column is whose). ``m`` is the rows'
+    match mask, ``notnull[col_id]`` a column's not-null mask and
+    ``plane(col_id, i)`` its i-th plane: a window's ``[N]`` vectors in
+    the ungrouped program, a tile's ``[S, 128]`` in the kernel. Returns
+    (cols, bad): ``bad`` marks the rows whose digits are invalid."""
+    cols = [m.astype(jnp.int32)]
+    bad = jnp.zeros(m.shape, jnp.bool_)
+    for ag in sig.aggs:
+        if ag.kind == "count":
+            cols.append((m if ag.col_id is None
+                         else m & notnull[ag.col_id]).astype(jnp.int32))
+            continue
+        mask = m
+        for cid in ag.need_cols:
+            mask = mask & notnull[cid]
+        cols.append(mask.astype(jnp.int32))
+        digits, neg = _base_digits(ag.col_id, ag.planes, plane)
+        bad = bad | (mask & neg)
+        for fx in ag.factors:
+            f = _eval_factor(fx, plane)
+            # Factors are statically bounded |f| < 2^14 but may still
+            # be negative at runtime (dtype ranges are conservative);
+            # a negative factor invalidates the digit math — counted
+            # here, and the host falls back when any were seen.
+            bad = bad | (mask & (f < 0))
+            digits = _digits_mul(digits, f)
+        for d in digits:
+            cols += _digit_pieces(jnp.where(mask, d, 0))
+    return cols, bad
+
+
+def _column_layout(sig: GroupAggSig):
+    """``_columns``' list from the signature alone: ({output: its mask
+    column}, {output: its digits' pieces' columns}, the column count)."""
+    mask_at = {"count": 0}
+    digits_at = {}
+    n = 1
+    for i, ag in enumerate(sig.aggs):
+        if ag.kind == "count":
+            mask_at[f"a{i}"] = n
+            n += 1
+            continue
+        mask_at[f"n{i}"] = n
+        digits = min(DIGITS, (2 if ag.planes == 1 else 4) + len(ag.factors))
+        digits_at[f"a{i}"] = slice(n + 1, n + 1 + 3 * digits)
+        n += 1 + 3 * digits
+    return mask_at, digits_at, n
+
+
+def _group_planes(sig: GroupAggSig, notnull, plane):
+    """The rows' group key: each group column's planes (0 where it is
+    null) and its null flag."""
+    planes = []
+    for cid, np_ in sig.group_cols:
+        nn = notnull[cid]
+        for pi in range(np_):
+            planes.append(jnp.where(nn, plane(cid, pi), jnp.int32(0)))
+        planes.append((~nn).astype(jnp.int32))
+    return planes
+
+
+# -- the grouped window: one kernel over row tiles ----------------------------
+# What is made per row (digits, carries, pieces, masks, bucket, one-hot)
+# lives in VMEM for the length of a tile and goes straight into the MXU;
+# per-bucket sums, ``rep``, the key and two counts leave the kernel.
+
+def _factor_cols(expr):
+    if expr[0] == "k":
+        return []
+    if expr[0] == "c":
+        return [expr[1]]
+    return _factor_cols(expr[1]) + _factor_cols(expr[2])
+
+
+def _kernel_rows(sig: GroupAggSig):
+    """What the XLA prologue hands the kernel of every row, from the
+    signature: (the columns whose not-null masks follow the match mask
+    as bits of the mask words, the (col_id, plane) vectors)."""
+    notnull, planes = {}, {}
+    for cid, np_ in sig.group_cols:
+        notnull[cid] = None
+        planes.update({(cid, i): None for i in range(np_)})
+    for ag in sig.aggs:
+        if ag.kind == "count":
+            if ag.col_id is not None:
+                notnull[ag.col_id] = None
+            continue
+        notnull.update({cid: None for cid in ag.need_cols})
+        planes.update({(ag.col_id, i): None for i in range(ag.planes)})
+        planes.update({(cid, 0): None
+                       for fx in ag.factors for cid in _factor_cols(fx)})
+    return tuple(notnull), tuple(planes)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _kernel_dims(sig: GroupAggSig):
+    """(KP5, C, CP, NBP, KW): the key's pieces lead the kernel's columns,
+    ``_columns``' follow; the MXU's operands and the key tables want the
+    columns, the buckets and the key's pieces padded to whole lanes (CP,
+    NBP, KW)."""
+    KP5 = 5 * _key_planes(sig)
+    C = KP5 + _column_layout(sig)[2]
+    return (KP5, C, _round_up(C, 128), _round_up(sig.NB, 128),
+            _round_up(KP5, 128))
+
+
+def _tile_rows(sig: GroupAggSig, n: int) -> int:
+    """Rows a grid step takes: a power of two from 1,024 (a row vector is
+    then whole (8, 128) registers) to 8,192 that keeps a tile's columns
+    (int32 ``[CP, T]``) within 4 MiB of VMEM, and no more than covers
+    the window."""
+    CP = _kernel_dims(sig)[2]
+    t = 1024
+    while t < min(n, 8192) and 2 * t * 4 * CP <= 4 << 20:
+        t *= 2
+    return t
+
+
+# Lane-rows (of 128 rows) one product of the kernel contracts: the MXU
+# accumulates over K = 2,048 by itself, and the tile's sums are read and
+# written once a product (Q1's program on the v5e: 1.60 ms a call at 2,
+# 1.44 at 4, 1.32 at 8, 1.29 at 16, 1.27 at 32).
+_PRODUCT_LANE_ROWS = 16
+
+
+def _fold8(v):
+    """[S, 128] -> [8, 128] partial sums (whole registers added)."""
+    return v.reshape(-1, 8, 128).sum(axis=0)
+
+
+def _window_kernel(sig: GroupAggSig, S: int, x_ref, cnt0_ref, key0_ref,
+                   sums_ref, keyp_ref, rep_ref, stat_ref,
+                   p_ref, b_ref, tile_ref, seen_ref, ktab_ref):
+    """One tile of S x 128 rows (grid axis 0, ``arbitrary``: the outputs
+    stay in VMEM and accumulate over the window).
+
+    x_ref[V, S, 128]: rowid, the mask words, the planes of
+    ``_kernel_rows``; cnt0_ref[NBP, 1] / key0_ref[NBP, KW]: the buckets'
+    counts and key pieces of the windows before. Outputs: sums[NBP, CP]
+    (the window's; column order: key pieces, then ``_columns``'),
+    keyp[NBP, KW] (every seen bucket's key pieces), rep[NBP, 1] (first
+    matching row of the buckets first seen in this window), stat[2, 8,
+    128] (partial counts: negs, collisions). Scratch: p[S * CP, 128] the
+    tile's columns (row s * CP + c: column c of the rows of lane-row s),
+    b[S, 128] the rows' buckets, tile[NBP, CP] the tile's sums, seen[NBP,
+    1] the buckets' rows so far, ktab[KP, NBP] the kept keys' planes with
+    the buckets along the lanes, as the rows look their bucket's up."""
+    from jax.experimental import pallas as pl
+
+    KP, NB = _key_planes(sig), sig.NB
+    KP5, _C, CP, NBP, KW = _kernel_dims(sig)
+    notnull_cols, planes = _kernel_rows(sig)
+    words = -(-(1 + len(notnull_cols)) // 32)    # x_ref[1:1 + words]
+    G = min(_PRODUCT_LANE_ROWS, S)
+    i = pl.program_id(0)
+
+    def keep_keys(keyp):
+        """Key pieces [NBP, KW] -> keyp_ref, and their planes -> ktab."""
+        keyp_ref[...] = keyp
+        pieces = keyp.T                                      # [KW, NBP]
+        for k in range(KP):
+            ktab_ref[k:k + 1, :] = functools.reduce(jnp.bitwise_or, [
+                pieces[5 * k + j:5 * k + j + 1, :] << jnp.int32(7 * j)
+                for j in range(5)])
+
+    @pl.when(i == 0)
+    def _():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+        stat_ref[...] = jnp.zeros_like(stat_ref)
+        rep_ref[...] = jnp.full_like(rep_ref, I32_MAX)
+        p_ref[...] = jnp.zeros_like(p_ref)      # (the padding columns)
+        seen_ref[...] = cnt0_ref[...]
+        keep_keys(key0_ref[...])
+
+    def bit(k):
+        return ((x_ref[1 + k // 32] >> jnp.int32(k % 32)) & jnp.int32(1)) != 0
+
+    m = bit(0)
+
+    # A tile no row of which matches (past the run's rows, outside the
+    # scan's bounds) sums nothing.
+    @pl.when(jnp.sum(m.astype(jnp.int32)) > 0)
+    def _():
+        notnull = {cid: bit(1 + j) for j, cid in enumerate(notnull_cols)}
+
+        def plane(cid, pi):
+            return x_ref[1 + words + planes.index((cid, pi))]
+
+        cols, bad = _columns(sig, m, notnull, plane)
+        stat_ref[0] += _fold8(bad.astype(jnp.int32))
+        key = _group_planes(sig, notnull, plane)
+        bucket = jnp.where(m, _bucket_hash(key) % NB, NBP)   # NBP: none
+        b_ref[...] = bucket
+        for c, col in enumerate(
+                [q for p in key for q in _plane_pieces(p)] + cols):
+            p_ref[pl.ds(c, S, stride=CP), :] = col
+
+        # ONE product of the bucket one-hot with the tile's columns for
+        # every per-bucket sum, G lane-rows of the tile at a time:
+        # onehot[NBP, G * 128] x columns[CP, G * 128]^T, int8 operands.
+        buckets = lax.broadcasted_iota(jnp.int32, (NBP, 128), 0)
+        tile_ref[...] = jnp.zeros_like(tile_ref)
+
+        def product(g, carry):
+            onehot, columns = [], []
+            for s in (g * G + u for u in range(G)):
+                onehot.append((buckets == b_ref[pl.ds(s, 1), :]).astype(
+                    jnp.int32).astype(jnp.int8))
+                columns.append(p_ref[pl.ds(pl.multiple_of(s * CP, CP), CP),
+                                     :].astype(jnp.int8))
+            tile_ref[...] += _int8_dot(jnp.concatenate(onehot, axis=1),
+                                       jnp.concatenate(columns, axis=1), 1, 1)
+            return carry
+
+        lax.fori_loop(0, S // G, product, 0)
+        tile = tile_ref[...]
+        cnt = tile[:, KP5:KP5 + 1]
+        first_seen = (seen_ref[...] == 0) & (cnt > 0)
+
+        # The key and the first row of a bucket seen for the first time:
+        # its rows' key pieces sum to count x piece when they agree (and
+        # to anything when they do not: then some row differs from
+        # whatever is kept, and is counted). Rows come in rowid order, so
+        # a bucket's first row is in the first tile that has any.
+        @pl.when(jnp.sum(first_seen.astype(jnp.int32)) > 0)
+        def _():
+            a, n = tile[:, :KW], jnp.maximum(cnt, 1)
+            # a // n, exact: a < 2^24 (a tile's rows x 127) is exact in
+            # float32, the quotient is off by one at most
+            q = (a.astype(jnp.float32) / n.astype(jnp.float32)).astype(
+                jnp.int32)
+            q = q + ((q + 1) * n <= a).astype(jnp.int32) \
+                - (q * n > a).astype(jnp.int32)
+            keep_keys(jnp.where(first_seen, q, keyp_ref[...]))
+
+            def first_row(s, rep):
+                return jnp.minimum(rep, jnp.where(
+                    buckets == b_ref[pl.ds(s, 1), :],
+                    x_ref[0, pl.ds(s, 1), :], I32_MAX))
+
+            rep = lax.fori_loop(0, S, first_row,
+                                jnp.full((NBP, 128), I32_MAX, jnp.int32))
+            rep_ref[...] = jnp.where(
+                first_seen, jnp.min(rep, axis=1, keepdims=True), rep_ref[...])
+
+        seen_ref[...] += cnt
+        sums_ref[...] += tile
+
+        # Each matching row against its bucket's key: the key's planes,
+        # looked up by the row's bucket along the lanes of ``ktab`` (128
+        # buckets a lookup).
+        differs = jnp.zeros(m.shape, jnp.bool_)
+        for k in range(KP):
+            want = jnp.zeros(m.shape, jnp.int32)
+            for seg in range(NBP // 128):
+                table = jnp.broadcast_to(
+                    ktab_ref[k:k + 1, seg * 128:(seg + 1) * 128], m.shape)
+                want = jnp.where(
+                    (bucket >> jnp.int32(7)) == seg,
+                    jnp.take_along_axis(table, bucket & jnp.int32(127),
+                                        axis=1), want)
+            differs = differs | (want != key[k])
+        stat_ref[1] += _fold8((m & differs).astype(jnp.int32))
+
+
+def _grouped_window(sig: GroupAggSig, m, notnull, plane, rowid, count, key):
+    """The grouped reduction of one window, as one ``pallas_call`` over
+    row tiles (interpreted where the backend is no TPU): ``m``,
+    ``notnull`` and ``plane`` as ``_columns`` takes them (``[N]``
+    vectors), ``rowid[N]``, and the accumulator's ``count[NB]`` and
+    ``key[NB, KP]`` of the windows before. Returns (sums[NB, C] in
+    ``_columns``' order, rep[NB], key[NB, KP], collisions, negs)."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    NB, KP = sig.NB, _key_planes(sig)
+    KP5, C, CP, NBP, KW = _kernel_dims(sig)
+    notnull_cols, planes = _kernel_rows(sig)
+    bits = [m] + [notnull[cid] for cid in notnull_cols]
+    rows = [rowid] + [
+        functools.reduce(jnp.bitwise_or, [
+            b.astype(jnp.int32) << jnp.int32(k)
+            for k, b in enumerate(bits[w:w + 32])])
+        for w in range(0, len(bits), 32)] + [plane(*cp) for cp in planes]
+    n = rowid.shape[0]
+    T = _tile_rows(sig, n)
+    S = T // 128
+    x = jnp.stack(rows)
+    if n % T:
+        x = jnp.pad(x, ((0, 0), (0, -n % T)))   # (mask word 0: no match)
+    x = x.reshape(len(rows), -1, 128)
+    key0 = jnp.pad(jnp.stack(_plane_pieces(key), axis=-1).reshape(NB, KP5),
+                   ((0, NBP - NB), (0, KW - KP5)))
+    cnt0 = jnp.pad(count, (0, NBP - NB))[:, None]
+
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+
+    vmem = 4 * (T * (CP + 2 * len(rows)) + NBP * (3 * CP + 4 * KW))
+    sums, keyp, rep, stat = pl.pallas_call(
+        functools.partial(_window_kernel, sig, S),
+        grid=(x.shape[1] // S,),
+        in_specs=[pl.BlockSpec((len(rows), S, 128), lambda i: (0, i, 0)),
+                  whole(NBP, 1), whole(NBP, KW)],
+        out_specs=[whole(NBP, CP), whole(NBP, KW), whole(NBP, 1),
+                   whole(2, 8, 128)],
+        out_shape=[jax.ShapeDtypeStruct((NBP, CP), jnp.int32),
+                   jax.ShapeDtypeStruct((NBP, KW), jnp.int32),
+                   jax.ShapeDtypeStruct((NBP, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((2, 8, 128), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((S * CP, 128), jnp.int32),
+                        pltpu.VMEM((S, 128), jnp.int32),
+                        pltpu.VMEM((NBP, CP), jnp.int32),
+                        pltpu.VMEM((NBP, 1), jnp.int32),
+                        pltpu.VMEM((KP, NBP), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * vmem + (8 << 20)),
+        interpret=jax.default_backend() != "tpu",
+        name="grouped_window",
+    )(x, cnt0, key0)
+    return (sums[:NB, KP5:C], rep[:NB, 0],
+            _plane_of_pieces(keyp[:NB, :KP5].reshape(NB, KP, 5)),
+            jnp.sum(stat[1]), jnp.sum(stat[0]))
+
+
 def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
     """Traced program: one dispatch over [w_first, w_last] windows.
 
@@ -281,7 +629,6 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
     from yugabyte_db_tpu.ops.row_gather import _unpack_literals
 
     K, R, NB = sig.K, sig.R, sig.NB
-    N = K * R
     w_first, w_last = iparams[0], iparams[1]
     row_lo, row_hi = iparams[2], iparams[3]
     read = (iparams[4], iparams[5], iparams[6], iparams[7])
@@ -290,6 +637,7 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
     unfiltered = dataclasses.replace(sig, apply_preds=False)
     KP = _key_planes(sig)
     NA = NB if sig.group_cols else 1   # buckets the loop accumulates
+    mask_at, digits_at, _C = _column_layout(sig)
 
     def init_acc():
         acc = {
@@ -331,90 +679,25 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
                     slice(None) if sig.flat else col_idx[ps.col_id], lit)
         rowid = base + r["start_idx"]
 
-        # group key planes (+ null flags)
-        planes = []
-        for cid, np_ in sig.group_cols:
-            idx = col_idx[cid]
-            nn = col_notnull[cid]
-            for pi in range(np_):
-                p = (cmp_w[cid][:, pi] if sig.flat
-                     else cmp_w[cid][idx, pi])
-                planes.append(jnp.where(nn, p, jnp.int32(0)))
-            planes.append((~nn).astype(jnp.int32))
+        def plane(cid, pi):
+            return (cmp_w[cid][:, pi] if sig.flat
+                    else cmp_w[cid][col_idx[cid], pi])
 
-        # Everything a bucket sums, as columns of 0..127: the 0/1 masks,
-        # the masked digit vectors' pieces, the key planes' pieces.
-        cols = [m.astype(jnp.int32)]
-        mask_at = {"count": 0}      # output -> its column
-        digits_at = {}              # output -> its pieces' columns
-        bad = jnp.zeros((N,), jnp.bool_)
-        for i, ag in enumerate(sig.aggs):
-            if ag.kind == "count":
-                mask_at[f"a{i}"] = len(cols)
-                cols.append((m if ag.col_id is None
-                             else m & col_notnull[ag.col_id]
-                             ).astype(jnp.int32))
-                continue
-            mask = m
-            for cid in ag.need_cols:
-                mask = mask & col_notnull[cid]
-            mask_at[f"n{i}"] = len(cols)
-            cols.append(mask.astype(jnp.int32))
-            digits, neg = _base_digits(
-                ag.planes, cmp_w[ag.col_id],
-                None if sig.flat else col_idx[ag.col_id], sig.flat)
-            bad = bad | (mask & neg)
-            for fx in ag.factors:
-                f = _eval_factor(fx, cmp_w,
-                                 None if sig.flat else col_idx, sig.flat)
-                # Factors are statically bounded |f| < 2^14 but may still
-                # be negative at runtime (dtype ranges are conservative);
-                # a negative factor invalidates the digit math — counted
-                # here, and the host falls back when any were seen.
-                bad = bad | (mask & (f < 0))
-                digits = _digits_mul(digits, f)
-            digits_at[f"a{i}"] = slice(len(cols),
-                                       len(cols) + 3 * len(digits))
-            for d in digits:
-                cols += _digit_pieces(jnp.where(mask, d, 0))
-        k0 = len(cols)
-        for p in planes:
-            cols += _plane_pieces(p)
-
+        if not sig.group_cols:
+            # (the grouped program builds its columns in the kernel)
+            cols, bad = _columns(sig, m, col_notnull, plane)
         new = {
             "scanned": acc["scanned"] + jnp.sum(
                 (r["pre_pred"] & gvalid).astype(jnp.int32)),
-            "negs": acc["negs"] + jnp.sum(bad.astype(jnp.int32)),
+            "negs": acc["negs"] if sig.group_cols else acc["negs"] + jnp.sum(
+                bad.astype(jnp.int32)),
             "key": acc["key"], "collisions": acc["collisions"],
         }
         if sig.group_cols:
-            # ONE product of the bucket one-hot with the stacked columns
-            # for every per-bucket sum of the window; the bucket's first
-            # row as a masked minimum over the same one-hot.
-            bucket = jnp.where(m, _bucket_hash(planes, N) % NB, NB)
-            onehot = lax.broadcasted_iota(
-                jnp.int32, (NB, N), 0) == bucket[None, :]
-            mat = jnp.stack(cols)                               # [C, N]
-            sums = _int8_dot(onehot, mat, 1, 1)                  # [NB, C]
-            rep = jnp.min(jnp.where(onehot, rowid[None, :], I32_MAX),
-                          axis=1)
-            # The key of a bucket seen for the first time: its rows' key
-            # pieces sum to count x piece when they agree (and to
-            # anything when they do not: then some row differs from
-            # whatever is kept, and is counted).
-            cnt = jnp.maximum(sums[:, 0], 1)[:, None]
-            first = _plane_of_pieces(
-                (sums[:, k0:] // cnt).reshape(NB, KP, 5))
-            key = jnp.where((acc["count"] > 0)[:, None], acc["key"], first)
-            # Each row's bucket key, back through the one-hot (one
-            # non-zero term a row), against the row's own key pieces.
-            want = _int8_dot(
-                jnp.stack(_plane_pieces(key), axis=-1).reshape(NB, -1),
-                onehot, 0, 0)                                   # [5KP, N]
-            differs = jnp.any(want != mat[k0:], axis=0)
-            new["key"] = key
-            new["collisions"] = acc["collisions"] + jnp.sum(
-                (m & differs).astype(jnp.int32))
+            sums, rep, new["key"], collisions, negs = _grouped_window(
+                sig, m, col_notnull, plane, rowid, acc["count"], acc["key"])
+            new["collisions"] = acc["collisions"] + collisions
+            new["negs"] = acc["negs"] + negs
         else:
             # No group column, no buckets: plain reductions over the rows.
             sums = jnp.stack([jnp.sum(c) for c in cols])[None]   # [1, C]
