@@ -404,6 +404,10 @@ class Smoke:
 
         unions = [sum(t.crun.num_versions for t in p.tablet.engine.runs)
                   for _u, p in self.leader_peers()]
+        # (the mask is also the device's where every run is resident)
+        resident = [all(t.peek_device() is not None
+                        for t in p.tablet.engine.runs)
+                    for _u, p in self.leader_peers()]
         c0 = metrics.jit_compiles("resident_gc_mask")
         t0 = time.perf_counter()
         self.admin.compact_table(TABLE)
@@ -411,9 +415,10 @@ class Smoke:
         device_route = metrics.jit_compiles("resident_gc_mask") > c0
         big = [u for u in unions if u > tpu_engine.HOST_GC_MASK_MAX]
         log(f"compaction unions {unions}; HOST_GC_MASK_MAX "
-            f"{tpu_engine.HOST_GC_MASK_MAX}: {len(big)} above it, retention "
-            f"mask route {'device' if device_route else 'host'}")
-        check(bool(big) == device_route,
+            f"{tpu_engine.HOST_GC_MASK_MAX}: {len(big)} above it, every run "
+            f"resident {resident}, retention mask route "
+            f"{'device' if device_route else 'host'}")
+        check((bool(big) or any(resident)) == device_route,
               "compaction route does not match the union sizes")
         if not big:
             self.findings.append(

@@ -150,7 +150,10 @@ def test_divergent_suffix_truncated(group):
     with pytest.raises((TimeoutError, NotLeader)):
         leader.write([group.row("orphan", 9)], timeout=0.4)
     new_leader = wait_for(
-        lambda: next((p for p in others if p.raft.is_leader()), None),
+        # (ready: a leader takes writes once its own term's no-op has
+        # applied, some milliseconds after the election under load)
+        lambda: next((p for p in others if p.raft.is_leader()
+                      and p.raft.leader_ready()), None),
         msg="new leader")
     new_leader.write([group.row("winner", 2)])
     group.transport.heal(leader.node_uuid)

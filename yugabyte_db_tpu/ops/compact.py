@@ -38,11 +38,14 @@ def _seg_max(vals, gid, n):
                                indices_are_sorted=True)
 
 
-def gc_mask(num_cols: int, N: int, s, cutoff_planes):
+def gc_mask(num_cols: int, N: int, s, cutoff_planes, keep_tombstones=False):
     """Retention mask over the SORTED union (key asc, ht desc).
 
     ``s`` = {new_group, tomb, live: [N] bool; ht_hi, ht_lo, exp_hi,
     exp_lo: [N] i32; set_: [num_cols, N] bool}. Returns keep[N] bool.
+    ``keep_tombstones`` (a scalar, traced): the union is not all there
+    is of its keys, so each group's newest row tombstone at or under
+    the cutoff is kept (CpuStorageEngine._gc_versions).
     """
     ht_hi, ht_lo = s["ht_hi"], s["ht_lo"]
     gid = jnp.cumsum(s["new_group"].astype(jnp.int32)) - 1
@@ -87,7 +90,10 @@ def gc_mask(num_cols: int, N: int, s, cutoff_planes):
     kept_contrib = span_contrib[sid] > 0
 
     newer = ~visible  # ht > cutoff: always retained
-    return newer | (kept_contrib & ~le2(ht_hi, ht_lo, t_hi_r, t_lo_r))
+    top_tomb = (visible & s["tomb"] & (ht_hi == t_hi_r)
+                & (ht_lo == t_lo_r))
+    return newer | (kept_contrib & ~le2(ht_hi, ht_lo, t_hi_r, t_lo_r)) \
+        | (top_tomb & keep_tombstones)
 
 
 @functools.lru_cache(maxsize=32)
@@ -99,7 +105,8 @@ def compiled_gc_mask(num_cols: int, N: int):
 
 # -- host-vectorized twin ----------------------------------------------------
 
-def gc_mask_host(num_cols: int, s, cutoff_planes) -> "np.ndarray":
+def gc_mask_host(num_cols: int, s, cutoff_planes,
+                 keep_tombstones: bool = False) -> "np.ndarray":
     """Numpy twin of gc_mask (reduceat segment reductions) for unions
     small enough that a device round trip costs more than the mask:
     every dispatch pays a synchronous fetch cycle plus a ~4B/row index
@@ -154,7 +161,10 @@ def gc_mask_host(num_cols: int, s, cutoff_planes) -> "np.ndarray":
         span_sizes) > 0
 
     newer = ~visible
-    return newer | (kept_contrib & ~le2s(ht_hi, ht_lo, t_hi_r, t_lo_r))
+    keep = newer | (kept_contrib & ~le2s(ht_hi, ht_lo, t_hi_r, t_lo_r))
+    if keep_tombstones:
+        keep |= vt & (ht_hi == t_hi_r) & (ht_lo == t_lo_r)
+    return keep
 
 
 # -- resident-plane variant --------------------------------------------------
@@ -164,7 +174,8 @@ _PAD_ZLO = -(1 << 31)  # low plane of value 0 (bias-flipped)
 
 @compile_contract("resident_gc_mask", max_compiles=64)
 @jax.jit
-def resident_gc_mask(runs_planes, idx, new_group, cutoff_planes):
+def resident_gc_mask(runs_planes, idx, new_group, cutoff_planes,
+                     keep_tombstones=False):
     """gc_mask over the merge order WITHOUT shipping the union's planes:
     the runs' planes are already HBM-resident (ops.device_run), so the
     host uploads only the sorted row-index vector (idx[i] = flat index
@@ -206,4 +217,5 @@ def resident_gc_mask(runs_planes, idx, new_group, cutoff_planes):
         sets.append(jnp.where(pads, False, cat[safe]))
     s["set_"] = (jnp.stack(sets) if sets
                  else jnp.zeros((0, idx.shape[0]), jnp.bool_))
-    return gc_mask(num_cols, idx.shape[0], s, cutoff_planes)
+    return gc_mask(num_cols, idx.shape[0], s, cutoff_planes,
+                   keep_tombstones)

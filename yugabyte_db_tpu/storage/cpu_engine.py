@@ -253,9 +253,13 @@ class CpuStorageEngine(StorageEngine):
             yield current_key, sorted(bucket, key=lambda r: (-r.ht, -r.write_id))
 
     @staticmethod
-    def _gc_versions(key: bytes, versions: list[RowVersion],
-                     cutoff: int) -> list[RowVersion]:
+    def _gc_versions(key: bytes, versions: list[RowVersion], cutoff: int,
+                     keep_tombstones: bool = False) -> list[RowVersion]:
         """History GC: keep versions needed by any read at read_ht >= cutoff.
+        ``keep_tombstones``: the versions are not all there are of the
+        key (a compaction of some runs, the oldest not among them), so
+        the newest row tombstone at or under the cutoff stays to shadow
+        what an older run may hold.
 
         Reference analog: DocDBCompactionFilter retention
         (src/yb/docdb/docdb_compaction_filter.cc) driven by
@@ -270,6 +274,7 @@ class CpuStorageEngine(StorageEngine):
         kept = [
             v for v in versions
             if v.ht > cutoff or (v.ht in contributing and v.ht > state.tomb_ht)
+            or (keep_tombstones and v.tombstone and v.ht == state.tomb_ht)
         ]
         return kept  # tombstones <= cutoff drop: nothing older remains to shadow
 

@@ -150,6 +150,14 @@ class StorageEngine(abc.ABC):
     def stats(self) -> dict:
         """Observability counters (runs, rows, bytes, versions)."""
 
+    def run_files(self):
+        """Context manager: the paths of the run files as they stand,
+        none of which goes away inside the block (a snapshot links
+        them)."""
+        import contextlib
+
+        return contextlib.nullcontext(list(self.persist.files))
+
     def restore_entries(self, entries) -> None:
         """Replace ALL engine content (memtable + runs + persisted files)
         with the given (key, versions) entries — the snapshot-restore
@@ -163,21 +171,58 @@ class StorageEngine(abc.ABC):
         renamed (ids are stable, so data is untouched)."""
         self.schema = new_schema
 
+    def compaction_trigger(self) -> int:
+        from yugabyte_db_tpu.utils.flags import FLAGS
+
+        return self.options.get("compaction_trigger",
+                                FLAGS.get("compaction_trigger"))
+
     def maybe_compact(self, history_cutoff_ht: int = 0) -> bool:
         """Universal-compaction trigger: compact when run count reaches the
         threshold (reference: universal style with num_levels=1,
         docdb_rocksdb_util.cc:476-482)."""
-        from yugabyte_db_tpu.utils.flags import FLAGS
-
-        trigger = self.options.get("compaction_trigger",
-                                   FLAGS.get("compaction_trigger"))
-        if self.stats().get("num_runs", 0) >= trigger:
+        if self.stats().get("num_runs", 0) >= self.compaction_trigger():
             self.compact(history_cutoff_ht)
             return True
         return False
 
     def close(self) -> None:
         self.mem_tracker.detach()
+
+
+# Universal (size-tiered) compaction's picker, with upstream's defaults
+# (rocksdb universal_compaction_size_ratio / _min_merge_width, as
+# docdb_rocksdb_util.cc leaves them).
+COMPACTION_SIZE_RATIO_PCT = 20
+COMPACTION_MIN_MERGE_WIDTH = 4
+
+
+def pick_compaction(sizes: list[int], trigger: int) -> tuple[int, int] | None:
+    """Which age-adjacent runs to merge: ``sizes`` are the runs' sizes
+    (versions) from the NEWEST to the oldest; returns ``(first, count)``
+    into that list, or None. Nothing under ``trigger`` runs. From each
+    start, a stretch grows while the next (older) run is no larger than
+    the sum of the stretch so far plus ``COMPACTION_SIZE_RATIO_PCT`` percent;
+    the longest stretch of at least the minimum width wins, the newest
+    on a tie. So a small run is never merged with one hundreds of times
+    its size, and runs of like size are merged all at once (reference:
+    UniversalCompactionPicker::PickCompactionUniversalReadAmp,
+    src/yb/rocksdb/db/compaction_picker.cc)."""
+    n = len(sizes)
+    if n < max(2, trigger):
+        return None
+    width = max(2, min(COMPACTION_MIN_MERGE_WIDTH, trigger))
+    best = None
+    for first in range(n - width + 1):
+        total, count = sizes[first], 1
+        while first + count < n and \
+                sizes[first + count] * 100 <= \
+                total * (100 + COMPACTION_SIZE_RATIO_PCT):
+            total += sizes[first + count]
+            count += 1
+        if count >= width and (best is None or count > best[1]):
+            best = (first, count)
+    return best
 
 
 _ENGINES: dict[str, type] = {}

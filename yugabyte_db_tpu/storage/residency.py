@@ -79,7 +79,7 @@ def _pin_witness():
 class _Entry:
     __slots__ = ("key", "label", "tracker", "owner_ref", "payload",
                  "nbytes", "pins", "pool", "external",
-                 "encoding", "device", "dev_bytes")
+                 "encoding", "device", "dev_bytes", "retired")
 
     def __init__(self, key: int, label: str, tracker,
                  device: str = DEFAULT_DEVICE):
@@ -102,6 +102,9 @@ class _Entry:
         # External mesh stacks only: per-device byte map (one shard's
         # bytes on the chip holding it).  None for single-device entries.
         self.dev_bytes: dict | None = None
+        # The owner left its run set while a dispatch window still held
+        # a pin (:meth:`HbmCache.retire`): dropped at the last unpin.
+        self.retired = False
 
 
 # _dead is deliberately NOT declared: the weakref death callback
@@ -232,6 +235,19 @@ class HbmCache:
             if e is not None and e.payload is not None:
                 self._release_entry(e, evicted=False)
 
+    def retire(self, key: int) -> None:
+        """:meth:`invalidate` for an owner that readers may still hold
+        (a run that a compaction replaced): while a pin is out the
+        planes stay resident and accounted, as they do for an eviction,
+        and the last :meth:`unpin` drops the entry."""
+        with self._lock:
+            self._drain_dead()
+            e = self._entries.get(key)
+            if e is not None and e.pins > 0 and e.payload is not None:
+                e.retired = True
+            else:
+                self.invalidate(key)
+
     def release(self, key: int) -> None:
         """Drop the entry's resident payload but keep the registration:
         the next acquire() demand-rebuilds through the cache, still
@@ -313,6 +329,11 @@ class HbmCache:
                 w = _pin_witness()
                 if w is not None:
                     w.pin_released(key)
+            if e.retired and e.pins == 0:
+                del self._entries[key]
+                if e.payload is not None:
+                    self._release_entry(e, evicted=False)
+                return
             # Unpinning may unlock deferred evictions on this device.
             b = self.budget()
             if b and self._dev_resident.get(e.device, 0) > b:

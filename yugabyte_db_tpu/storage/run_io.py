@@ -12,7 +12,9 @@ File format: codec.encode of
 
 from __future__ import annotations
 
+import json
 import os
+import threading
 
 from yugabyte_db_tpu.utils import codec
 from yugabyte_db_tpu.storage.row_version import RowVersion
@@ -36,54 +38,111 @@ def save_run(path: str, entries: list[tuple[bytes, list[RowVersion]]]) -> None:
     os.replace(tmp, path)
 
 
+MANIFEST = "MANIFEST.json"
+
+
 class RunPersistence:
     """Tracks a directory of numbered run files for one engine instance.
-    ``None`` data_dir = in-memory engine (tests, caches)."""
+    ``None`` data_dir = in-memory engine (tests, caches).
+
+    ``files`` lists the live runs' paths in AGE order, oldest first, and
+    is what ``MANIFEST.json`` holds once the set has changed under this
+    class (a directory without one lists by name, which is by age as
+    long as nothing was merged in the middle). A run file that the
+    manifest does not name is the remains of a crash, before a publish
+    (a new file) or after it (a replaced one), and is removed at open:
+    whatever the last published manifest names is read, each version
+    once."""
 
     def __init__(self, data_dir: str | None):
         self.data_dir = data_dir
         self._seq = 0
+        self._seq_lock = threading.Lock()
         self.files: list[str] = []
         if data_dir:
             os.makedirs(data_dir, exist_ok=True)
             names = sorted(n for n in os.listdir(data_dir)
                            if n.startswith("run-") and n.endswith(".dat"))
-            self.files = [os.path.join(data_dir, n) for n in names]
             if names:
                 self._seq = max(int(n[4:-4]) for n in names) + 1
+            live = self._read_manifest()
+            if live is not None:
+                for n in set(names) - set(live):
+                    os.unlink(os.path.join(data_dir, n))
+                names = [n for n in live if n in names]
+            self.files = [os.path.join(data_dir, n) for n in names]
 
     @property
     def enabled(self) -> bool:
         return self.data_dir is not None
 
+    def _read_manifest(self) -> list[str] | None:
+        try:
+            with open(os.path.join(self.data_dir, MANIFEST)) as f:
+                return json.load(f)["runs"]
+        except FileNotFoundError:
+            return None
+
+    def _publish(self, files: list[str]) -> None:
+        """The one commit point of a change to the run set."""
+        path = os.path.join(self.data_dir, MANIFEST)
+        with open(path + ".tmp", "w") as f:
+            json.dump({"runs": [os.path.basename(p) for p in files]}, f)
+            f.flush()
+            os.fsync(f.fileno())
+        os.replace(path + ".tmp", path)
+        self.files = files
+
     def load_all(self):
         return [load_run(p) for p in self.files]
 
-    def save_new(self, entries) -> None:
-        if not self.enabled:
-            return
-        path = os.path.join(self.data_dir, f"run-{self._seq:010d}.dat")
-        self._seq += 1
+    def write_run(self, entries) -> str:
+        """A new run file, durable and not yet part of the set."""
+        with self._seq_lock:
+            path = os.path.join(self.data_dir, f"run-{self._seq:010d}.dat")
+            self._seq += 1
         save_run(path, entries)
-        self.files.append(path)
+        return path
 
-    def replace_all(self, entries) -> None:
-        """Atomically-ish swap every run file for one merged run (compaction).
-        New file is durable before old ones are unlinked, so a crash leaves
-        either the old set or a superset — load_all after a crash between
-        steps would see duplicated data, which the version-merge semantics
-        absorb (identical versions merge idempotently)."""
-        if not self.enabled:
-            return
-        old = list(self.files)
-        self.files = []
-        if entries:
-            self.save_new(entries)
-        for p in old:
+    def install(self, old: list[str], new: str | None) -> None:
+        """Publish ``new`` (a :meth:`write_run` path, or None when
+        nothing survived) in the place of ``old``, paths that stand side
+        by side in ``files``; as the newest run when ``old`` is empty.
+        The old files stay on disk until :meth:`remove`: write the new
+        run, publish, remove, in that order, and a reopen in between
+        reads one set or the other."""
+        at = self.files.index(old[0]) if old else len(self.files)
+        if self.files[at:at + len(old)] != old:
+            raise ValueError("the replaced runs do not stand side by side")
+        self._publish(self.files[:at] + ([new] if new else [])
+                      + self.files[at + len(old):])
+
+    @staticmethod
+    def remove(paths: list[str]) -> None:
+        for p in paths:
             try:
                 os.unlink(p)
             except FileNotFoundError:
                 pass
+
+    def save_new(self, entries) -> str | None:
+        """One more run, the newest; its path."""
+        if not self.enabled:
+            return None
+        path = self.write_run(entries)
+        self.install([], path)
+        return path
+
+    def replace_all(self, entries) -> str | None:
+        """Every run file for one merged run (a full compaction, a
+        restore); its path, or None when ``entries`` is empty."""
+        if not self.enabled:
+            return None
+        old = list(self.files)
+        new = self.write_run(entries) if entries else None
+        self.install(old, new)
+        self.remove(old)
+        return new
 
 
 def load_run(path: str) -> list[tuple[bytes, list[RowVersion]]]:

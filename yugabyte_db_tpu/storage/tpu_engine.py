@@ -33,6 +33,7 @@ IntentAwareIterator merging regular/provisional sources
 from __future__ import annotations
 
 import bisect
+import contextlib
 import functools
 import threading
 import time
@@ -120,8 +121,9 @@ class TpuRun:
     accounting can't drop planes a dispatch still references."""
 
     def __init__(self, crun: ColumnarRun, device_tracker=None,
-                 device=None):
+                 device=None, path: str | None = None):
         self.crun = crun
+        self.path = path        # its file under RunPersistence, if any
         self.host_index = None  # storage.host_page.HostPageIndex, lazy
         self._dev_nbytes_hint: int | None = None
         # The owning device: every demand (re-)upload for this run
@@ -184,8 +186,9 @@ class TpuRun:
 
     def retire(self) -> None:
         """Run leaving the run set for good (compaction, restore,
-        close): drop resident planes and the registration itself."""
-        hbm_cache().invalidate(self._res_key)
+        close): drop resident planes and the registration itself, once
+        no reader that took the run before holds a pin on it."""
+        hbm_cache().retire(self._res_key)
 
     def seed_device(self, dev: DeviceRun) -> None:
         """Admit an already-built DeviceRun (the device flush output) as
@@ -372,10 +375,25 @@ class TpuStorageEngine(StorageEngine):
                 "breaker_cooldown_s",
                 FLAGS.get("tpu_breaker_cooldown_s"))))
         self.persist = RunPersistence(self.options.get("data_dir"))
-        for entries in self.persist.load_all():
+        for path, entries in zip(self.persist.files,
+                                 self.persist.load_all()):
             crun = ColumnarRun.build(self.schema, entries, self.rows_per_block)
-            self.runs.append(TpuRun(crun, self.device_tracker))
+            self.runs.append(TpuRun(crun, self.device_tracker, path=path))
             self.flushed_frontier_ht = max(self.flushed_frontier_ht, crun.max_ht)
+        # The run list changes hands under this lock: a flush adds its
+        # run, a compaction puts one run in the place of those it
+        # merged, both on their own threads. Readers take no lock: the
+        # list is replaced, never changed in place.
+        self._runs_lock = threading.Lock()
+        # Odd while the runs are rebuilt where they stand (ALTER): a
+        # compaction neither starts then nor keeps what it merged across
+        # a change of it.
+        self._run_epoch = 0
+        # Who compacts what :meth:`pick_compaction` picked after a
+        # flush: the tablet peer's background worker sets this (a
+        # callable that takes the picked runs and only wakes it); an
+        # engine on its own compacts where it stands.
+        self.compaction_listener = None
         # Plane-encoding observability: yb_plane_bytes{encoding} /
         # yb_plane_encoded_ratio sample plane_stats() at scrape time
         # (weakly held — a dropped engine falls out of the series).
@@ -398,8 +416,13 @@ class TpuStorageEngine(StorageEngine):
         limit = self.options.get("memtable_flush_versions",
                                  FLAGS.get("memtable_flush_versions"))
         if self.memtable.num_versions >= limit:
-            self.flush()
-            self.maybe_compact()
+            self.flush(caller="apply")
+            picked = self.pick_compaction()
+            if picked is not None:
+                if self.compaction_listener is not None:
+                    self.compaction_listener(picked)
+                else:
+                    self.compact(runs=picked, by="apply")
         self._track_memstore()
 
     # -- plane-encoding introspection --------------------------------------
@@ -448,6 +471,15 @@ class TpuStorageEngine(StorageEngine):
         dropped columns keep their (now unreachable) planes. The memtable
         flushes first so no old-schema rows build runs after the switch."""
         self.flush()
+        with self._runs_lock:
+            self._run_epoch += 1
+        try:
+            self._alter_runs(new_schema)
+        finally:
+            with self._runs_lock:
+                self._run_epoch += 1
+
+    def _alter_runs(self, new_schema: Schema) -> None:
         super().alter_schema(new_schema)
         self.mat = RowMaterializer(new_schema)
         self._kinds = {c.col_id: dtype_kind(c.dtype)
@@ -456,7 +488,8 @@ class TpuStorageEngine(StorageEngine):
         self._name_to_id = {c.name: c.col_id
                             for c in new_schema.value_columns}
         self._key_col_names = {c.name for c in new_schema.key_columns}
-        self._plan_cache.clear()
+        with self._runs_lock:
+            self._run_set_changed()
         from yugabyte_db_tpu.storage.columnar import ColumnData
 
         for trun in self.runs:
@@ -486,14 +519,27 @@ class TpuStorageEngine(StorageEngine):
                 # the residency byte hint.
                 trun.invalidate_device()
                 trun._dev_nbytes_hint = None
-        self._drop_overlay_cache()
+        with self._runs_lock:
+            self._run_set_changed()
 
-    def flush(self) -> None:
+    def flush(self, caller: str = "maintenance") -> None:
+        """``caller`` is who is held meanwhile: ``apply`` for the thread
+        that applies committed Raft entries (the memtable reached its
+        limit under it; the time goes to ``yb_apply_stall_us``), else
+        ``maintenance``."""
         from yugabyte_db_tpu.utils.sync_point import sync_point
 
         sync_point("tpu_engine:flush:start")
         if self.memtable.is_empty:
             return
+        with trace.span("engine.flush",
+                        metrics.apply_stall_histogram()
+                        if caller == "apply" else None,
+                        thread=caller) as sp:
+            sp.labels["route"] = self._flush()
+        sync_point("tpu_engine:flush:done")
+
+    def _flush(self) -> str:
         if self.memtable.max_ht is not None:
             self.flushed_frontier_ht = max(self.flushed_frontier_ht,
                                            self.memtable.max_ht)
@@ -503,30 +549,37 @@ class TpuStorageEngine(StorageEngine):
         # ineligible or over the residency budget: the native one-C-pass
         # path, generic drain+build behind it.
         seeded = self._device_flush()
+        entries = None
         if seeded is not None:
             crun, trun = seeded
-            if self.persist.enabled:
-                self.persist.save_new(list(crun.iter_entries()))
         else:
             count_flush_path("host")
             crun = ColumnarRun.build_from_memtable(
                 self.schema, self.memtable, self.rows_per_block)
             if crun is None:
                 entries = self.memtable.drain_sorted()
-                self.persist.save_new(entries)
                 crun = ColumnarRun.build(self.schema, entries,
                                          self.rows_per_block)
-            elif self.persist.enabled:
-                self.persist.save_new(list(crun.iter_entries()))
             trun = TpuRun(crun, self.device_tracker)
-        self.runs.append(trun)
-        self.memtable = make_memtable()
-        self._plan_cache.clear()
-        self._drop_overlay_cache()
+        if self.persist.enabled:
+            trun.path = self.persist.write_run(
+                entries if entries is not None
+                else list(crun.iter_entries()))
+        with self._runs_lock:
+            if trun.path:
+                # Justified hold (here and in _compact, restore_entries):
+                # the manifest is the run list's durable twin and changes
+                # with it; what it costs is one small file's fsync, the
+                # run's own file is written before.
+                # yb-lint: disable=iholds/lock-across-blocking
+                self.persist.install([], trun.path)
+            self.runs = [*self.runs, trun]
+            self.memtable = make_memtable()
+            self._run_set_changed()
         self._track_memstore()
         if len(self.runs) > 1:
             self._warm_overlay_scatter()
-        sync_point("tpu_engine:flush:done")
+        return "host" if seeded is None else "device"
 
     def _device_flush(self):
         """The device flush path: stage the memtable's apply-order op
@@ -660,8 +713,14 @@ class TpuStorageEngine(StorageEngine):
         is_real = np.zeros(Bp, dtype=bool)
         is_real[:B] = True
         ehi, elo = P.scalar_ht_planes(MAX_HT)
-        out = dflush.replay_flush(staged_tree, perm_p, dst_p, gs_p,
-                                  is_real, ehi, elo, R=R)
+        args = (staged_tree, perm_p, dst_p, gs_p, is_real)
+        out = dflush.replay_flush(*args, ehi, elo, R=R)
+        # (the planes the program reads: the staged op log, the sort
+        # permutation, the slots, the group and block bits)
+        metrics.count_device_dispatch(
+            "replay_flush", device_nbytes(args),
+            h2d=len(jax.tree.leaves(args)) + 2,
+            d2h=len(jax.tree.leaves(out)))
         # The device planes round-trip back as the run's HOST planes
         # (one copy per plane; np.array so they're owned and writable —
         # never a read-only view of a device buffer).
@@ -844,67 +903,179 @@ class TpuStorageEngine(StorageEngine):
 
         threading.Thread(target=warm, daemon=True).start()
 
-    def compact(self, history_cutoff_ht: int = 0) -> None:
-        """Merge all runs into one, GCing history at the cutoff. The
-        k-way merge ORDER and the GC decisions run as one device dispatch
-        (ops.compact: lexsort by key planes + vectorized retention mask)
-        whenever every key fits the exact 32-byte device prefix; the host
-        then materializes the merged run with a single linear pass. Falls
-        back to the host heap merge otherwise (BASELINE config 4;
-        reference hot loop: CompactionJob::Run,
-        src/yb/rocksdb/db/compaction_job.cc:622)."""
-        if len(self.runs) <= 1 and history_cutoff_ht == 0:
-            return
-        # Bulk object churn (hundreds of thousands of row objects moving
-        # between containers) makes the cyclic GC fire on allocation and
-        # rescan the whole heap repeatedly — measured 27x slowdown on
-        # plain object-array fills. Nothing here creates cycles; pause
-        # collection for the duration (the reference's arena-allocated
-        # compaction has no analogous cost).
+    def pick_compaction(self) -> "list[TpuRun] | None":
+        """The runs a compaction should merge now, oldest first, by
+        size (storage.engine.pick_compaction), or None."""
+        from yugabyte_db_tpu.storage.engine import pick_compaction
+
+        runs = self.runs
+        pick = pick_compaction(
+            [t.crun.num_versions for t in reversed(runs)],
+            self.compaction_trigger())
+        if pick is None:
+            return None
+        first, count = pick
+        return runs[len(runs) - first - count:len(runs) - first]
+
+    def maybe_compact(self, history_cutoff_ht: int = 0,
+                      by: str = "caller") -> bool:
+        picked = self.pick_compaction()
+        if picked is None:
+            return False
+        return self.compact(history_cutoff_ht, runs=picked, by=by)
+
+    def compact(self, history_cutoff_ht: int = 0,
+                runs: "list[TpuRun] | None" = None,
+                by: str = "caller") -> bool:
+        """Merge ``runs`` (age-adjacent, oldest first; every run when
+        None: a manual compaction is a full one) into one run that takes
+        their place, GCing history at the cutoff. The k-way merge ORDER
+        is computed on the host and the GC decisions as one retention
+        mask (ops.compact), on the device where the planes already are
+        or the union is big, whenever every key fits the exact 32-byte
+        device prefix; the host then materializes the merged run with a
+        single linear pass. Falls back to the host heap merge otherwise
+        (BASELINE config 4; reference hot loop: CompactionJob::Run,
+        src/yb/rocksdb/db/compaction_job.cc:622).
+
+        Where the tablet's oldest run does not take part, an older run
+        may hold what a tombstone shadows: row tombstones at or under
+        the cutoff are then kept (what they shadow among ``runs`` still
+        goes, as does every overwritten version).
+
+        The merged run is built from the runs as they are, with no lock
+        held: readers and flushes go on. The swap of the run list, the
+        manifest's, the caches and the retiring of the inputs happen
+        under the lock a flush adds its run under. False when ``runs``
+        no longer stand side by side in the run list (they were not
+        taken from it now) and the result was thrown away. ``by`` says
+        whose thread is held meanwhile (``yb_compactions{by}``):
+        ``apply`` for an engine on its own that compacts where the
+        write was applied, ``worker`` for the tablet peer's background
+        thread, else ``caller``."""
+        full = runs is None
+        inputs = list(self.runs) if full else list(runs)
+        if not inputs or (full and len(inputs) <= 1
+                          and history_cutoff_ht == 0):
+            return True
+        if not full:
+            return self._compact(inputs, history_cutoff_ht, "subset", by)
+        # Bulk object churn (hundreds of thousands of row objects
+        # moving between containers) makes the cyclic GC fire on
+        # allocation and rescan the whole heap repeatedly — measured
+        # 27x slowdown on plain object-array fills. Nothing here
+        # creates cycles; a full compaction (an operator's, in the
+        # foreground) pauses collection for its duration. A subset
+        # compaction moves a few thousand versions from a background
+        # thread and leaves the process's collector alone.
         import gc
 
         gc_was = gc.isenabled()
         gc.disable()
         try:
-            self._compact_locked(history_cutoff_ht)
+            return self._compact(inputs, history_cutoff_ht, "full", by)
         finally:
             if gc_was:
                 gc.enable()
 
-    def _compact_locked(self, history_cutoff_ht: int) -> None:
-        result = None
-        if self.runs and all(t.crun.max_key_len <= 32 for t in self.runs) \
-                and sum(t.crun.num_versions for t in self.runs) > 0:
-            result = self._device_compact_entries(history_cutoff_ht)
-        if result is None:
-            from yugabyte_db_tpu.storage.cpu_engine import CpuStorageEngine
-            from yugabyte_db_tpu.storage.merge import merge_entry_streams
+    def _position(self, inputs: "list[TpuRun]") -> int:
+        """Where ``inputs`` stand side by side in the run list, or -1."""
+        cur = self.runs
+        at = next((i for i, t in enumerate(cur) if t is inputs[0]), -1)
+        if at < 0 or len(cur) < at + len(inputs) \
+                or any(a is not b for a, b in zip(cur[at:], inputs)):
+            return -1
+        return at
 
-            merged = []
-            for key, versions in merge_entry_streams(
-                    [t.crun.iter_entries() for t in self.runs]):
-                kept = CpuStorageEngine._gc_versions(key, versions,
-                                                     history_cutoff_ht)
-                if kept:
-                    merged.append((key, kept))
-            crun = (ColumnarRun.build(self.schema, merged,
-                                      self.rows_per_block)
-                    if merged else None)
-            self.persist.replace_all(merged)
-        else:
-            make_entries, crun = result
+    def _compact(self, inputs: "list[TpuRun]", history_cutoff_ht: int,
+                 kind: str, by: str) -> bool:
+        from yugabyte_db_tpu.utils.sync_point import sync_point
+
+        wall, t0 = time.time_ns(), time.perf_counter_ns()
+        epoch = self._run_epoch
+        at = self._position(inputs)
+        if at < 0 or epoch & 1:
+            return False
+        try:
+            # (at 0 the oldest run takes part: nothing older is left
+            # for a tombstone to shadow)
+            merged, crun, route = self._merge_runs(
+                inputs, history_cutoff_ht, keep_tombstones=at > 0)
+        except Exception:
+            if self._run_epoch != epoch:
+                return False    # (ALTER rebuilt the planes it read)
+            raise
+        # Crash-safe in this order: write the new run, publish the
+        # manifest that names it in its inputs' place, remove their files.
+        path = (self.persist.write_run(merged)
+                if merged and self.persist.enabled else None)
+        new = (TpuRun(crun, self.device_tracker, path=path)
+               if crun is not None else None)
+        sync_point("tpu_engine:compact:built")
+        with self._runs_lock:
+            cur = self.runs
+            at = self._position(inputs) if self._run_epoch == epoch else -1
+            if at >= 0:
+                if self.persist.enabled:
+                    # yb-lint: disable=iholds/lock-across-blocking
+                    self.persist.install([t.path for t in inputs], path)
+                self.runs = (cur[:at] + ([new] if new is not None else [])
+                             + cur[at + len(inputs):])
+                self._run_set_changed()
+        if at < 0:
+            if new is not None:
+                new.retire()
+            self.persist.remove([path] if path else [])
+            return False
+        sync_point("tpu_engine:compact:swapped")
+        for t in inputs:
+            t.retire()
+        self.persist.remove([t.path for t in inputs if t.path])
+        metrics.count_compaction(route, kind, by)
+        trace.record_span(
+            "engine.compact", wall, (time.perf_counter_ns() - t0) // 1000,
+            metrics.compaction_histogram(route, kind), route=route,
+            kind=kind, by=by, runs_in=len(inputs),
+            versions_in=sum(t.crun.num_versions for t in inputs),
+            versions_out=0 if crun is None else crun.num_versions)
+        return True
+
+    def _merge_runs(self, inputs: "list[TpuRun]", history_cutoff_ht: int,
+                    keep_tombstones: bool):
+        """-> (entries for the run's file, merged ColumnarRun or None,
+        route), from the runs as they are; takes no lock."""
+        result = None
+        if all(t.crun.max_key_len <= 32 for t in inputs) \
+                and sum(t.crun.num_versions for t in inputs) > 0:
+            result = self._device_compact_entries(inputs, history_cutoff_ht,
+                                                  keep_tombstones)
+        if result is not None:
+            make_entries, crun, route = result
             # The (key, versions) entry list exists only for durability;
             # materialize it lazily — an in-memory engine (data_dir=None)
             # skips the 1-tuple-per-group Python walk entirely.
-            self.persist.replace_all(make_entries()
-                                     if self.persist.enabled else [])
-        old_runs = [t for t in self.runs]
-        self.runs = ([TpuRun(crun, self.device_tracker)]
-                     if crun is not None else [])
+            return (make_entries() if self.persist.enabled else [],
+                    crun, route)
+        from yugabyte_db_tpu.storage.cpu_engine import CpuStorageEngine
+        from yugabyte_db_tpu.storage.merge import merge_entry_streams
+
+        merged = []
+        for key, versions in merge_entry_streams(
+                [t.crun.iter_entries() for t in inputs]):
+            kept = CpuStorageEngine._gc_versions(
+                key, versions, history_cutoff_ht, keep_tombstones)
+            if kept:
+                merged.append((key, kept))
+        crun = (ColumnarRun.build(self.schema, merged, self.rows_per_block)
+                if merged else None)
+        return merged, crun, "host_merge"
+
+    def _run_set_changed(self) -> None:
+        """What is cached by run goes with the run set (flush, compact,
+        restore under ``_runs_lock``, so that two of them never drop
+        the overlay's pin twice)."""
         self._plan_cache.clear()
         self._drop_overlay_cache()
-        for t in old_runs:
-            t.retire()
 
     def _drop_overlay_cache(self) -> None:
         """Forget the cached delta-overlay state, releasing its pin on
@@ -920,13 +1091,14 @@ class TpuStorageEngine(StorageEngine):
             self._overlay_ext_key = None
 
     def close(self) -> None:
-        self._drop_overlay_cache()
+        self._run_set_changed()
         for t in self.runs:
             t.retire()
         self.device_tracker.detach()
         super().close()
 
-    def _device_gc_fits_budget(self) -> bool:
+    @staticmethod
+    def _device_gc_fits_budget(runs) -> bool:
         """Compaction's resident mask needs every run pinned at once;
         under a budget smaller than the union's plane bytes that would
         force pinned overflow, so the caller falls back to the
@@ -934,17 +1106,20 @@ class TpuStorageEngine(StorageEngine):
         b = hbm_cache().budget()
         if not b:
             return True
-        return sum(t._nbytes_hint() for t in self.runs) <= b
+        return sum(t._nbytes_hint() for t in runs) <= b
 
-    def _device_compact_entries(self, cutoff: int):
-        """Device merge+GC -> (entries, merged ColumnarRun), or None when
-        the union is empty. The merged run is assembled by GATHERING the
-        surviving rows' existing planes (numpy) instead of re-encoding
-        every version through ColumnarRun.build — the whole pipeline is
-        vectorized except one linear grouping pass."""
+    def _device_compact_entries(self, runs, cutoff: int,
+                                keep_tombstones: bool = False):
+        """Merge+GC of ``runs`` -> (entries, merged ColumnarRun, route),
+        or None when the union is empty; ``route`` says where the
+        retention mask was computed (``device`` or ``host``). The merged
+        run is assembled by GATHERING the surviving rows' existing
+        planes (numpy) instead of re-encoding every version through
+        ColumnarRun.build — the whole pipeline is vectorized except one
+        linear grouping pass."""
         from yugabyte_db_tpu.ops import compact as dcompact
 
-        crs = [t.crun for t in self.runs]
+        crs = [t.crun for t in runs]
         parts_kw, parts = [], {k: [] for k in
                                ("ht_hi", "ht_lo", "exp_hi", "exp_lo",
                                 "tomb", "live")}
@@ -1010,7 +1185,7 @@ class TpuStorageEngine(StorageEngine):
         # The retention decisions run on device (ops.compact docstring).
         run_items = []
         off = 0
-        for t, nrows in zip(self.runs, run_row_counts):
+        for t, nrows in zip(runs, run_row_counts):
             if nrows == 0:
                 continue
             sk = self._sortkey_bytes(kw[off:off + nrows],
@@ -1040,21 +1215,29 @@ class TpuStorageEngine(StorageEngine):
         keep_dev = None
         gc_pins: list[TpuRun] = []
         try:
-            if N > HOST_GC_MASK_MAX and self._device_gc_fits_budget():
+            # The mask is computed where the planes are: on the device
+            # when the union is big enough to be worth uploading, or
+            # when every run is resident already (a device flush leaves
+            # its run in HBM), so that nothing crosses the link but the
+            # index vector.
+            if (N > HOST_GC_MASK_MAX
+                    or all(t.peek_device() is not None for t in runs)) \
+                    and self._device_gc_fits_budget(runs):
                 # Device retention mask over RESIDENT planes: upload only
                 # the sorted flat-index vector (union position -> row in
                 # the concatenation of the runs' flattened device planes)
                 # and the group bits — the planes never re-cross the link.
                 # Every run is pinned for the dispatch window so eviction
                 # can't drop planes the mask program still references.
-                for t in self.runs:
+                route = "device"
+                for t in runs:
                     t.pin("low")
                     gc_pins.append(t)
                 R = self.rows_per_block
                 offsets = np.cumsum(
-                    [0] + [t.dev.B * R for t in self.runs])[:-1]
+                    [0] + [t.dev.B * R for t in runs])[:-1]
                 src_parts = []
-                for t, off in zip(self.runs, offsets):
+                for t, off in zip(runs, offsets):
                     cr = t.crun
                     for b in range(cr.B):
                         nv = cr.blocks[b].num_valid
@@ -1075,17 +1258,25 @@ class TpuStorageEngine(StorageEngine):
                      "live": t.dev.arrays["live"],
                      "sets": tuple(t.dev.arrays["cols"][cid]["set"]
                                    for cid in col_ids)}
-                    for t in self.runs)
-                cutoff_planes = (jnp.int32(c_hi), jnp.int32(c_lo),
-                                 jnp.int32(c_hi), jnp.int32(c_lo))
-                keep_dev = dcompact.resident_gc_mask(
-                    runs_planes, jnp.asarray(idx),
-                    jnp.asarray(new_group), cutoff_planes)
+                    for t in runs)
+                # (numpy scalars: a jnp one is a device program each)
+                cutoff_planes = (np.int32(c_hi), np.int32(c_lo),
+                                 np.int32(c_hi), np.int32(c_lo))
+                args = (idx, new_group, cutoff_planes,
+                        np.bool_(keep_tombstones))
+                keep_dev = dcompact.resident_gc_mask(runs_planes, *args)
                 keep_dev.copy_to_host_async()
+                # (the planes the program reads: the runs' MVCC and set
+                # planes as they lie in HBM, the index and group vectors)
+                metrics.count_device_dispatch(
+                    "resident_gc_mask",
+                    device_nbytes((runs_planes, args[:2])),
+                    h2d=len(jax.tree.leaves(args)), d2h=1)
             else:
-                # Small unions (or budgets too tight to pin the whole
-                # union): the host-vectorized twin beats the link's
-                # fixed per-dispatch fence + index upload.
+                # Small unions that are not resident (or budgets too
+                # tight to pin the whole union): the host-vectorized
+                # twin beats uploading the planes.
+                route = "host"
                 keep = dcompact.gc_mask_host(
                     len(col_ids),
                     {"new_group": new_group, "ht_hi": s_ht_hi,
@@ -1093,7 +1284,7 @@ class TpuStorageEngine(StorageEngine):
                      "exp_lo": exp_lo[perm], "tomb": tomb[perm],
                      "live": live[perm],
                      "set_": [cat_set[cid][perm] for cid in col_ids]},
-                    (c_hi, c_lo, c_hi, c_lo))
+                    (c_hi, c_lo, c_hi, c_lo), keep_tombstones)
 
             # While any device mask computes/streams back, do the host
             # work that doesn't need it: collect the row-level Python
@@ -1117,7 +1308,7 @@ class TpuStorageEngine(StorageEngine):
         kept_pos = np.nonzero(keep[:].astype(bool) & (perm < N))[0]
         kept_src = perm[kept_pos]
         if kept_src.size == 0:
-            return (lambda: []), None
+            return (lambda: []), None, route
         # Group boundaries among KEPT rows (still key-sorted).
         gid_sorted = np.cumsum(new_group.astype(np.int64)) - 1
         kept_gids = gid_sorted[kept_pos]
@@ -1147,7 +1338,7 @@ class TpuStorageEngine(StorageEngine):
                                 all_vers, all_kvs, kw, planes, col_ids,
                                 null_parts, cmp_parts, arith_parts,
                                 varlen_all)
-        return make_entries, crun
+        return make_entries, crun, route
 
     def _gather_run(self, kept_src, kept_new_group, all_keys, all_vers,
                     all_kvs, kw, planes, col_ids, null_parts, cmp_parts,
@@ -1258,20 +1449,30 @@ class TpuStorageEngine(StorageEngine):
                         run.varlen_max_len.get(cid, 0), max(lens))
         return run
 
+    @contextlib.contextmanager
+    def run_files(self):
+        with self._runs_lock:   # (a compaction unlinks after its swap)
+            yield list(self.persist.files)
+
     def restore_entries(self, entries) -> None:
-        self.memtable = make_memtable()
-        self.persist.replace_all(entries)
-        old_runs = list(self.runs)
-        if entries:
-            crun = ColumnarRun.build(self.schema, entries,
-                                     self.rows_per_block)
-            self.runs = [TpuRun(crun, self.device_tracker)]
+        crun = (ColumnarRun.build(self.schema, entries, self.rows_per_block)
+                if entries else None)
+        path = (self.persist.write_run(entries)
+                if entries and self.persist.enabled else None)
+        if crun is not None:
             self.flushed_frontier_ht = max(self.flushed_frontier_ht,
                                            crun.max_ht)
-        else:
-            self.runs = []
-        self._plan_cache.clear()
-        self._drop_overlay_cache()
+        with self._runs_lock:
+            old_runs = self.runs
+            old_files = list(self.persist.files)
+            if self.persist.enabled:
+                # yb-lint: disable=iholds/lock-across-blocking
+                self.persist.install(old_files, path)
+            self.memtable = make_memtable()
+            self.runs = ([TpuRun(crun, self.device_tracker, path=path)]
+                         if crun is not None else [])
+            self._run_set_changed()
+        self.persist.remove(old_files)
         for t in old_runs:
             t.retire()
 

@@ -236,12 +236,13 @@ class Tablet:
             tmp = sdir + ".tmp"
             _shutil.rmtree(tmp, ignore_errors=True)
             os.makedirs(tmp)
-            for path in self.engine.persist.files:
-                dst = os.path.join(tmp, os.path.basename(path))
-                try:
-                    os.link(path, dst)  # hard link: cheap, immutable file
-                except OSError:
-                    _shutil.copy2(path, dst)
+            with self.engine.run_files() as paths:
+                for path in paths:
+                    dst = os.path.join(tmp, os.path.basename(path))
+                    try:
+                        os.link(path, dst)  # hard link: cheap, immutable file
+                    except OSError:
+                        _shutil.copy2(path, dst)
             with open(os.path.join(tmp, "snapshot-meta.json"), "w") as f:
                 import json as _json
 

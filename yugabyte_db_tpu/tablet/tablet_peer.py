@@ -15,12 +15,13 @@ from __future__ import annotations
 
 import os
 import threading
+from collections import deque
 
 from yugabyte_db_tpu.consensus.metadata import ConsensusMetadata, RaftConfig
 from yugabyte_db_tpu.consensus.raft import (NotLeader, RaftConsensus,
                                             RaftOptions)
 from yugabyte_db_tpu.storage.row_version import RowVersion
-from yugabyte_db_tpu.utils.trace import TRACE
+from yugabyte_db_tpu.utils.trace import TRACE, span
 from yugabyte_db_tpu.storage.scan_spec import ScanResult, ScanSpec
 from yugabyte_db_tpu.tablet.tablet import (Tablet, TabletMetadata,
                                            _encode_rows)
@@ -91,6 +92,21 @@ class TabletPeer:
         # BEFORE entering the log, so every admitted write sits below
         # the seal entry and is captured by the fork snapshot.
         self._split_sealing = False
+        # Background compaction: the engine only ASKS, naming the runs
+        # it picked (on the thread that applied the write whose flush
+        # made the run count reach the trigger); one worker a peer,
+        # started at the first request, compacts under the maintenance
+        # lock, so that a manual compact, a flush, a split and a
+        # bootstrap snapshot exclude it as they exclude each other. A
+        # tablet that never reaches the trigger has no such thread.
+        self._compactor: threading.Thread | None = None
+        # (the newest asked; a deque so that the asking thread and the
+        # worker hand it over without a lock)
+        self._compact_requests: deque = deque(maxlen=1)
+        self._compact_wanted = threading.Event()
+        self._compactor_stop = threading.Event()
+        if hasattr(self.tablet.engine, "compaction_listener"):
+            self.tablet.engine.compaction_listener = self._request_compaction
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> None:
@@ -98,6 +114,10 @@ class TabletPeer:
 
     def shutdown(self) -> None:
         self.raft.shutdown()
+        self._compactor_stop.set()
+        self._compact_wanted.set()
+        if self._compactor is not None:
+            self._compactor.join(timeout=60.0)
         self.tablet.close()
 
     @property
@@ -258,13 +278,17 @@ class TabletPeer:
         kind = admitted[0]
         if kind == "dup":
             return admitted[1]
+        # Span raft.replicate: appended to majority-durable (the peers'
+        # rounds, their WAL syncs and this replica's own).
         if kind == "inflight":
             _k, op_id, ht = admitted
-            self.raft.wait_committed(op_id, timeout)
+            with span("raft.replicate"):
+                self.raft.wait_committed(op_id, timeout)
             return ht
         _k, op_id, ht, rid_key = admitted
         try:
-            self.raft.wait_committed(op_id, timeout)
+            with span("raft.replicate"):
+                self.raft.wait_committed(op_id, timeout)
         except NotLeader:
             if rid_key is not None:
                 self._inflight_rids.pop(rid_key, None)
@@ -537,8 +561,49 @@ class TabletPeer:
         return n
 
     def compact(self, history_cutoff_ht: int = 0) -> None:
+        """A manual compaction (``ts.compact``, ``yb_admin``): all runs."""
         with self._maintenance_lock:
             self.tablet.compact(history_cutoff_ht)
+
+    def _request_compaction(self, runs: list) -> None:
+        """The engine's ``compaction_listener``; runs on the one thread
+        that is applying, and only wakes the worker."""
+        self._compact_requests.append(runs)
+        self._compact_wanted.set()
+        if self._compactor is None and not self._compactor_stop.is_set():
+            self._compactor = threading.Thread(
+                target=self._compaction_loop, daemon=True,
+                name=f"compact-{self.tablet_id[-8:]}")
+            self._compactor.start()
+
+    def _compaction_loop(self) -> None:
+        from yugabyte_db_tpu.utils.metrics import count_swallowed
+
+        while True:
+            self._compact_wanted.wait()
+            self._compact_wanted.clear()
+            if self._compactor_stop.is_set():
+                return
+            try:
+                engine = self.tablet.engine
+                asked = (self._compact_requests.pop()
+                         if self._compact_requests else None)
+                # Compaction IS maintenance: what waits for this lock (a
+                # manual flush or compact, a split, a bootstrap
+                # snapshot) must not run beside it. The engine builds
+                # the merged run with none of its own locks held, so
+                # applies, flushes and reads go on.
+                with self._maintenance_lock:
+                    # (runs asked for while an earlier compaction took
+                    # some of them are refused; then the picker decides
+                    # from the list as it stands)
+                    if asked is not None:
+                        engine.compact(runs=asked, by="worker")
+                    while not self._compactor_stop.is_set() \
+                            and engine.maybe_compact(by="worker"):
+                        pass
+            except Exception as e:  # noqa: BLE001 — the next flush asks again
+                count_swallowed("tablet_peer.compaction", e)
 
     def stats(self) -> dict:
         s = self.tablet.stats()

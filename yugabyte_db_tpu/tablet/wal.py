@@ -164,11 +164,13 @@ class Log:
         if maybe_fault("fault.wal_sync_failed"):
             raise FaultInjected("injected WAL sync failure")
         from yugabyte_db_tpu.utils.metrics import observe_wal_sync_ms
+        from yugabyte_db_tpu.utils.trace import record_span
         from yugabyte_db_tpu.utils.watchdog import watchdog
 
         # Standing stall check (reference: kernel_stack_watchdog.h):
         # a wedged fsync surfaces as a flagged stall, not silence.
         with watchdog().watch("wal.sync", threshold_s=2.0):
+            wall = time.time_ns()
             start = time.monotonic()
             f = None
             with self._lock:
@@ -193,7 +195,11 @@ class Log:
                     # snapshotted it; _close_file_locked flushed AND fsynced
                     # it before closing, so the group is durable anyway.
                     pass
-            observe_wal_sync_ms((time.monotonic() - start) * 1e3)
+            took = time.monotonic() - start
+            observe_wal_sync_ms(took * 1e3)
+            # (under a follower's raft.append_entries or a single-peer
+            # write the sync shows in that request's /rpcz sample)
+            record_span("wal.sync", wall, int(took * 1e6))
 
     # -- read / replay -----------------------------------------------------
     def read_all(self, min_index: int = 0):

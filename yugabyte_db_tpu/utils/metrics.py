@@ -696,6 +696,32 @@ def engine_issue_part_histogram(part: str) -> Histogram:
         "yb_engine_issue_part_us")
 
 
+def apply_stall_histogram() -> Histogram:
+    """``yb_apply_stall_us``: how long a memtable flush held the thread
+    that applies committed Raft entries (span ``engine.flush`` with
+    ``thread=apply``, storage/tpu_engine.py ``_after_apply``)."""
+    return _span_entity(("apply_stall",)).histogram("yb_apply_stall_us")
+
+
+def compaction_histogram(route: str, kind: str) -> Histogram:
+    """``yb_compaction_us{route, kind}``: one compaction from the runs
+    as they were to the run list swapped (span ``engine.compact``).
+    ``route``: where the retention mask was computed (``device``,
+    ``host``) or ``host_merge`` for the heap merge of keys beyond the
+    device prefix; ``kind``: ``full`` or ``subset``."""
+    return _span_entity(("compaction", route, kind), route=route,
+                        kind=kind).histogram("yb_compaction_us")
+
+
+def count_compaction(route: str, kind: str, by: str) -> None:
+    """``yb_compactions{route, kind, by}``, beside the histogram; ``by``
+    is whose thread ran it: ``worker`` (the tablet peer's background
+    thread), ``apply`` (an engine with no peer, where the write was
+    applied) or ``caller`` (a manual compaction)."""
+    _span_entity(("compactions", route, kind, by), route=route, kind=kind,
+                 by=by).counter("yb_compactions").increment()
+
+
 def jit_compile_histogram(entry: str) -> Histogram:
     """``yb_jit_compile_seconds{entry}``: seconds a dispatch spent
     tracing and compiling, beside ``yb_jit_compiles{entry}``."""
