@@ -22,7 +22,10 @@ from yugabyte_db_tpu.storage.row_version import RowVersion
 
 
 def _load(num=3000, seed=7, with_nulls=True, negatives=False,
-          versions=1):
+          versions=1, host_flush=False):
+    """``host_flush``: the run is built on the host and uploaded encoded
+    ("bits" presence leaves, as the benchmark's tables are); a device
+    flush leaves plain planes."""
     schema = Schema([
         ColumnSchema("k", DataType.STRING, ColumnKind.HASH),
         ColumnSchema("flag", DataType.STRING),       # 1-char, Q1-like
@@ -61,7 +64,14 @@ def _load(num=3000, seed=7, with_nulls=True, negatives=False,
             cpu.apply([rv])
             tpu.apply([rv])
     cpu.flush()
-    tpu.flush()
+    from yugabyte_db_tpu.utils.flags import FLAGS
+
+    device_flush = FLAGS.get("tpu_device_flush")
+    FLAGS.set("tpu_device_flush", device_flush and not host_flush)
+    try:
+        tpu.flush()
+    finally:
+        FLAGS.set("tpu_device_flush", device_flush)
     return cpu, tpu, ht
 
 
@@ -531,7 +541,9 @@ def test_no_scatter_in_the_lowered_program(group_by):
 
     from yugabyte_db_tpu.ops import group_agg
 
-    _cpu, tpu, ht = _load(num=300)
+    from yugabyte_db_tpu.ops import encodings
+
+    _cpu, tpu, ht = _load(num=300, host_flush=True)
     spec = ScanSpec(read_ht=ht + 1, group_by=group_by,
                     aggregates=list(Q1_AGGS),
                     predicates=[Predicate("d", "<", 900)])
@@ -539,6 +551,7 @@ def test_no_scatter_in_the_lowered_program(group_by):
                                              spec.predicates)
     assert sig.flat and bool(sig.group_cols) == bool(group_by)
     arrays = tpu.runs[0].dev.arrays
+    assert encodings.leaf_kind(arrays["valid"]) == "bits"
     eqns = list(_equations(jax.make_jaxpr(functools.partial(
         group_agg._packed, sig))(arrays, params).jaxpr))
     names = [name for name, _e, _k in eqns]
@@ -557,11 +570,460 @@ def test_no_scatter_in_the_lowered_program(group_by):
                if in_kernel and name == "dot_general") == 1
     _KP5, C, _CP, _NBP, _KW = group_agg._kernel_dims(sig)
     N = sig.K * sig.R
-    rows = len(group_agg._kernel_rows(sig)[1]) + 2
+    # the mask word and the planes: the rowid is made in the kernel
+    rows = len(group_agg._kernel_rows(sig)[1]) + 1
     assert rows < 16 < C
     widest = max(math.prod(e.outvars[0].aval.shape)
                  for name, e in outside if name == "concatenate")
     assert widest == rows * N      # the kernel's operand, not [C, N]
+    # The presence planes stay packed (PR 34): ONE [K, R // 32, 32]
+    # value is laid out by rows, the mask word, where the rows form
+    # lays out valid, tomb, live and every column's set and isnull
+    # (31 of 0.020 ms each in Q1's program on the v5e).
+    assert _relayouts(eqns, sig) == 1
+    # (one plain plane, as the overlay's masked run has, and the others
+    # are unpacked one by one: group_start, tomb, live, set and isnull)
+    by_rows = list(_equations(jax.make_jaxpr(functools.partial(
+        group_agg._packed, sig))(
+            _plain_presence(arrays, sig, only=("valid",)), params).jaxpr))
+    assert [n for n, _e, _k in by_rows].count("pallas_call") == 1
+    assert _relayouts(by_rows, sig) == 3 + 2 * len(sig.cols) >= 17
+
+
+def _relayouts(eqns, sig):
+    """Reshapes of a ``[K, R // 32, 32]`` value (a window of packed
+    words, a bit a row) to rows, outside the kernel."""
+    return sum(1 for name, e, in_kernel in eqns
+               if name == "reshape" and not in_kernel
+               and e.invars[0].aval.shape == (sig.K, sig.R // 32, 32))
+
+
+def _plain_presence(arrays, sig, only=None):
+    """A run's device arrays with the "bits" leaves of the presence
+    planes (or of ``only``) decoded to plain bool planes."""
+    from yugabyte_db_tpu.ops import encodings
+
+    def plain(name, leaf):
+        if only is not None and name not in only:
+            return leaf
+        assert encodings.leaf_kind(leaf) == "bits", name
+        return np.asarray(encodings.decode_leaf(leaf, sig.B, sig.R))
+
+    out = dict(arrays)
+    for name in ("valid", "tomb", "live"):
+        out[name] = plain(name, arrays[name])
+    out["cols"] = {
+        cid: dict(col, set=plain("set", col["set"]),
+                  isnull=plain("isnull", col["isnull"]))
+        for cid, col in arrays["cols"].items()}
+    return out
+
+
+# -- the presence bits stay packed (PR 34) --------------------------------------
+
+def _presence_run(K, R, seed, extra_cols=0):
+    """Synthetic flat planes with everything a flat resolve has to mask:
+    rows that are not valid (and two invalid pad blocks at the end), row
+    tombstones, rows without a liveness marker, unset columns, NULLs,
+    commit times 100..199 and expiries of which some lie at or under
+    150. Columns: 1 int32 (the group, 0..4), 2 int64 (the base), 3 int32
+    (narrow, the predicate's), 5.. int32 that no aggregate names. Returns
+    (plain numpy tree, the planes as flat arrays)."""
+    from yugabyte_db_tpu.utils import planes as P
+
+    rng = np.random.default_rng(seed)
+    N = K * R
+
+    def coin(p):
+        return rng.random(N) < p
+
+    valid = coin(0.97)
+    valid[(K - 2) * R:] = False
+    ht = rng.integers(100, 200, N)
+    exp = np.where(coin(0.1), rng.integers(100, 200, N), 2**62)
+    flat = {"valid": valid, "tomb": coin(0.05), "live": coin(0.7),
+            "ht": ht, "exp": exp, "set": {}, "isnull": {}, "val": {}}
+    cols = {}
+
+    def add(cid, values, planes):
+        flat["set"][cid], flat["isnull"][cid] = coin(0.8), coin(0.15)
+        flat["val"][cid] = values
+        if planes == 2:
+            hi, lo = P.i64_to_ordered_planes(values.astype(np.int64))
+            cmp = np.stack([hi, lo], -1)
+        else:
+            cmp = values[:, None]
+        cols[cid] = {"set": flat["set"][cid].reshape(K, R),
+                     "isnull": flat["isnull"][cid].reshape(K, R),
+                     "cmp": cmp.reshape(K, R, planes).astype(np.int32)}
+
+    add(1, rng.integers(0, 5, N), 1)
+    add(2, rng.integers(10**11, 10**12, N), 2)
+    add(3, rng.integers(0, 100, N), 1)
+    for cid in range(5, 5 + extra_cols):
+        add(cid, rng.integers(0, 9, N), 1)
+
+    def i64(v):
+        hi, lo = P.i64_to_ordered_planes(np.asarray(v, np.int64))
+        return (hi.reshape(K, R).astype(np.int32),
+                lo.reshape(K, R).astype(np.int32))
+
+    ht_hi, ht_lo = i64(ht)
+    exp_hi, exp_lo = i64(exp)
+    tree = {"valid": valid.reshape(K, R),
+            "group_start": np.ones((K, R), bool),
+            "tomb": flat["tomb"].reshape(K, R),
+            "live": flat["live"].reshape(K, R),
+            "ht_hi": ht_hi, "ht_lo": ht_lo, "exp_hi": exp_hi,
+            "exp_lo": exp_lo, "cols": cols}
+    return tree, flat
+
+
+def _bits_presence(tree, but=()):
+    """The tree with its presence planes as "bits" leaves, as a run is
+    uploaded (those named in ``but`` stay plain bool planes)."""
+    from yugabyte_db_tpu.ops import encodings
+
+    def bits(name, plane):
+        return plane if name in but else encodings.encode_bool_plane(plane)
+
+    out = dict(tree)
+    for name in ("valid", "tomb", "live"):
+        out[name] = bits(name, tree[name])
+    out["cols"] = {cid: dict(col, set=bits("set", col["set"]),
+                             isnull=bits("isnull", col["isnull"]))
+                   for cid, col in tree["cols"].items()}
+    return out
+
+
+def _presence_sig(K, R, extra_cols=0, counts=()):
+    from yugabyte_db_tpu.ops import group_agg, scan
+
+    cols = [scan.ColSig(1, "i32"), scan.ColSig(2, "i64"),
+            scan.ColSig(3, "i32")] + [scan.ColSig(c, "i32")
+                                      for c in range(5, 5 + extra_cols)]
+    return group_agg.GroupAggSig(
+        B=K, R=R, K=K, NB=512, cols=tuple(cols),
+        preds=(scan.PredSig(3, "i32", "<"),), apply_preds=True, flat=True,
+        group_cols=((1, 1),),
+        aggs=(group_agg.GAgg("sum_prod", 2, planes=2,
+                             factors=(("+", ("k", 1), ("c", 3)),),
+                             need_cols=(2, 3)),
+              group_agg.GAgg("count", 2, need_cols=(2,)),
+              group_agg.GAgg("count", None))
+        + tuple(group_agg.GAgg("count", c, need_cols=(c,))
+                for c in counts))
+
+
+def _presence_params(sig, lo, hi, read_ht, read_exp, lit):
+    from yugabyte_db_tpu.ops import group_agg, row_gather
+    from yugabyte_db_tpu.utils import planes as P
+
+    def point(v):
+        hi_, lo_ = P.i64_to_ordered_planes(np.array([v], np.int64))
+        return int(hi_[0]), int(lo_[0])
+
+    ip, fp = row_gather.pack_params(
+        0, 0, lo, hi, point(read_ht) + point(read_exp), [lit], [])
+    return group_agg.pack_params(sig, ip, fp)
+
+
+def _presence_oracle(flat, lo, hi, read_ht, read_exp, lit):
+    """(scanned, matching rows, {column: its not-null mask over the
+    matching rows}) by numpy, from the flat resolve's own definition: a
+    row exists by its liveness marker or by a column that is set and not
+    NULL, if it is valid, visible, no tombstone and not expired."""
+    rows = np.arange(flat["valid"].size)
+    ok = flat["valid"] & (flat["ht"] <= read_ht) & ~flat["tomb"] \
+        & ~(flat["exp"] <= read_exp)
+    notnull = {c: ok & flat["set"][c] & ~flat["isnull"][c]
+               for c in flat["set"]}
+    exists = ok & flat["live"]
+    for c in notnull:
+        exists = exists | notnull[c]
+    pre = exists & (rows >= lo) & (rows < hi)
+    m = pre & notnull[3] & (flat["val"][3] < lit)
+    return int(pre.sum()), int(m.sum()), {c: m & nn
+                                          for c, nn in notnull.items()}
+
+
+PRESENCE_CASES = {
+    # row_lo, row_hi, read point, expiry read point, the predicate's literal
+    "whole_run_every_version_visible": (0, 20480, 10**6, 0, 100),
+    "bounds_cut_inside_a_word_and_inside_a_tile":
+        (37, 8192 + 45, 10**6, 0, 100),
+    "a_read_point_that_hides_versions": (0, 20480, 150, 0, 100),
+    "ttl_expired_versions": (0, 20480, 10**6, 150, 100),
+    "all_of_it_and_a_predicate": (1000 + 31, 20480 - 33, 170, 140, 60),
+    "one_row": (8191, 8192, 10**6, 0, 100),
+}
+
+
+@pytest.mark.parametrize("case", list(PRESENCE_CASES))
+def test_packed_presence_is_the_rows_form_bit_for_bit(case):
+    """The kernel's mask words made on the packed words of "bits" leaves
+    (``_packed_window``) against the same words made of planes laid out
+    by rows (``_rows_window``), and the two programs' outputs, bit for
+    bit: unset columns, NULLs, row tombstones, rows without liveness,
+    TTL-expired versions, rows that are not valid and invalid pad
+    blocks, bounds that cut inside a 32-row word and inside a tile, a
+    read point that hides versions."""
+    from yugabyte_db_tpu.ops import group_agg
+    from yugabyte_db_tpu.ops.row_gather import _unpack_literals
+
+    K, R = 20, 1024
+    lo, hi, read_ht, read_exp, lit = PRESENCE_CASES[case]
+    tree, flat = _presence_run(K, R, seed=34, extra_cols=2)
+    sig = _presence_sig(K, R, extra_cols=2)
+    packed = _bits_presence(tree)
+    params = _presence_params(sig, lo, hi, read_ht, read_exp, lit)
+    n = group_agg.int_params(sig)
+    window = (0, lo, hi, tuple(params[4:8]),
+              _unpack_literals(sig, params[:n], params[n:].view(np.float32)))
+    words, _plane = group_agg._packed_window(sig, packed, *window)
+    r, gvalid, m, _plane = group_agg._rows_window(sig, tree, *window)
+    want = group_agg._rows_words(sig, r, gvalid, m)
+    assert len(words) == len(want) == 1
+    assert np.asarray(words[0]).tobytes() == np.asarray(want[0]).tobytes()
+    # (the masks are not trivial: each bit is set somewhere, clear elsewhere)
+    notnull_cols, scanned_bit, _w = group_agg._mask_bits(sig)
+    scanned, matching, _notnull = _presence_oracle(flat, lo, hi, read_ht,
+                                                   read_exp, lit)
+    word = np.asarray(words[0])
+    assert int((word & 1).sum()) == matching
+    assert int(((word >> scanned_bit) & 1).sum()) == scanned
+    assert notnull_cols == (1, 2, 3) and scanned_bit == 4
+
+    fn = group_agg.compiled_grouped(sig)
+    got = group_agg.unpack(sig, np.asarray(fn(packed, params)))
+    _assert_same_bits(got, group_agg.unpack(sig, np.asarray(fn(tree,
+                                                               params))))
+    assert int(got["scanned"]) == scanned
+    assert int(got["count"].sum()) == matching == int(got["a2"].sum())
+    assert int(got["collisions"]) == 0 and int(got["negs"]) == 0
+    if case != "one_row":
+        assert 0 < matching < scanned < hi - lo
+
+
+def test_more_than_32_masks_take_a_second_word_packed_and_by_rows():
+    """33 not-null masks, the match and ``scanned``: two mask words a
+    row, the second one's bits cleared by the per-row parts as the
+    first's."""
+    from yugabyte_db_tpu.ops import group_agg
+
+    K, R = 4, 256
+    tree, flat = _presence_run(K, R, seed=5, extra_cols=31)
+    sig = _presence_sig(K, R, extra_cols=31, counts=tuple(range(5, 36)))
+    notnull_cols, scanned_bit, words = group_agg._mask_bits(sig)
+    assert len(notnull_cols) == 34 and scanned_bit == 35 and words == 2
+    lo, hi = 70, K * R - 300
+    params = _presence_params(sig, lo, hi, 160, 130, 80)
+    fn = group_agg.compiled_grouped(sig)
+    got = group_agg.unpack(sig, np.asarray(fn(_bits_presence(tree),
+                                              params)))
+    _assert_same_bits(got, group_agg.unpack(sig, np.asarray(fn(tree,
+                                                               params))))
+    scanned, matching, notnull = _presence_oracle(flat, lo, hi, 160, 130, 80)
+    assert int(got["scanned"]) == scanned > matching > 0
+    assert int(got["count"].sum()) == matching
+    # (count(c35): the last not-null mask of the second word)
+    assert int(got["a33"].sum()) == int(notnull[35].sum()) > 0
+
+
+def _presence_counts():
+    from yugabyte_db_tpu.utils import metrics
+
+    return metrics.grouped_presence()
+
+
+@pytest.mark.parametrize("plain", ["every_presence_plane", "valid_alone"])
+def test_a_plain_bool_leaf_takes_the_rows_form_and_agrees(plain):
+    """What the program sees decides: one plain bool plane among the
+    presence planes (a run uploaded unencoded; the delta overlay's
+    masked ``valid``, storage/tpu_engine.py ``_MaskedRun``) and the
+    window is resolved by rows, to the same outputs;
+    ``yb_grouped_presence{form}`` says which form a program got."""
+    from yugabyte_db_tpu.ops import group_agg
+
+    # (a shape of its own a case: the counter counts programs traced)
+    K, R = 6 + (plain == "valid_alone"), 512
+    tree, _flat = _presence_run(K, R, seed=9)
+    sig = _presence_sig(K, R)
+    params = _presence_params(sig, 5, K * R - 5, 180, 120, 70)
+    fn = group_agg.compiled_grouped(sig)
+    before = _presence_counts()
+    want = group_agg.unpack(sig, np.asarray(fn(_bits_presence(tree),
+                                               params)))
+    mid = _presence_counts()
+    assert mid == {"packed": before["packed"] + 1, "rows": before["rows"]}
+    run = tree if plain == "every_presence_plane" else _bits_presence(
+        tree, but=("valid",))
+    _assert_same_bits(group_agg.unpack(sig, np.asarray(fn(run, params))),
+                      want)
+    assert _presence_counts() == {"packed": mid["packed"],
+                                  "rows": mid["rows"] + 1}
+    from yugabyte_db_tpu.utils.metrics import process_registry
+
+    text = process_registry().prometheus_text()
+    for form in ("packed", "rows"):
+        assert f'yb_grouped_presence{{form="{form}"}}' in text
+
+
+def test_the_overlays_masked_run_takes_the_rows_form():
+    """The delta overlay's masked primary (``_MaskedRun``): its ``valid``
+    is a plain bool plane with the dirty rows cleared, beside the run's
+    "bits" leaves. A grouped program over it resolves by rows, and
+    answers as the packed form does over a packed ``valid`` with the
+    same rows cleared."""
+    from yugabyte_db_tpu.ops import encodings, group_agg
+
+    _cpu, tpu, ht = _load(num=700, host_flush=True)
+    spec = ScanSpec(read_ht=ht + 1, group_by=["flag", "status"],
+                    aggregates=list(Q1_AGGS),
+                    predicates=[Predicate("d", "<", 900)])
+    _kind, (sig, params) = tpu._grouped_prep(tpu.runs[0], spec,
+                                             spec.predicates)
+    arrays = tpu.runs[0].dev.arrays
+    idx = np.arange(3, 700, 11, dtype=np.int32)
+    masked = tpu._masked_primary(tpu.runs[0], idx).dev.arrays
+    assert encodings.leaf_kind(masked["valid"]) is None
+    assert encodings.leaf_kind(masked["tomb"]) == "bits"
+    fn = group_agg.compiled_grouped(sig)
+    before = _presence_counts()
+    whole = group_agg.unpack(sig, np.asarray(fn(arrays, params)))
+    got = group_agg.unpack(sig, np.asarray(fn(masked, params)))
+    assert _presence_counts() == {"packed": before["packed"] + 1,
+                                  "rows": before["rows"] + 1}
+    valid = np.array(encodings.decode_leaf(arrays["valid"], sig.B, sig.R))
+    assert valid.reshape(-1)[idx].all()
+    valid.reshape(-1)[idx] = False
+    cleared = dict(arrays, valid=encodings.encode_bool_plane(valid))
+    _assert_same_bits(got, group_agg.unpack(sig, np.asarray(fn(cleared,
+                                                               params))))
+    assert int(got["scanned"]) == int(whole["scanned"]) - idx.size
+
+
+def test_the_ungrouped_lowering_does_not_reach_the_packed_form(monkeypatch):
+    """Q6's program is its parent's: a signature with no group column
+    resolves its window by rows whatever the leaves are (XLA fuses the
+    unpacks into its reductions; a reordering there cost Q6 0.172 ->
+    0.251 ms on the v5e, PR 32), and counts no presence form."""
+    import jax
+
+    from yugabyte_db_tpu.ops import encodings, group_agg
+
+    def unreachable(*_a, **_k):
+        raise AssertionError("the packed form, from an ungrouped program")
+
+    _cpu, tpu, ht = _load(num=300, host_flush=True)
+    arrays = tpu.runs[0].dev.arrays
+    preds = [Predicate("qty", "<", 25), Predicate("d", ">=", 100)]
+    lowered = {}
+    for group_by in ([], ["flag"]):
+        spec = ScanSpec(read_ht=ht + 1, group_by=group_by,
+                        aggregates=list(Q6_AGGS), predicates=preds)
+        _kind, (sig, params) = tpu._grouped_prep(tpu.runs[0], spec, preds)
+        assert sig.flat and encodings.leaf_kind(arrays["valid"]) == "bits"
+        lowered[bool(group_by)] = (sig, params)
+    monkeypatch.setattr(group_agg, "_packed_window", unreachable)
+    monkeypatch.setattr(group_agg, "resolve_flat_packed", unreachable)
+    monkeypatch.setattr(encodings, "rows_of_words", unreachable)
+    before = _presence_counts()
+    sig, params = lowered[False]
+    text = jax.jit(functools.partial(group_agg._packed, sig)).lower(
+        arrays, params).as_text()
+    assert "scatter" not in text and _presence_counts() == before
+    # (the same walk does reach it from a grouped signature)
+    sig, params = lowered[True]
+    with pytest.raises(AssertionError, match="the packed form"):
+        jax.make_jaxpr(functools.partial(group_agg._packed, sig))(arrays,
+                                                                  params)
+
+
+@pytest.fixture(scope="module")
+def one_described_v5e():
+    """A sharding on one chip of a described, not attached, v5e: the
+    TPU's compiler compiles for it here (made inside a fixture: no
+    import of this file loads the TPU's library)."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no compiler here, or its lock
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_q1_compiles_for_the_v5e_with_one_relayout_of_its_masks(
+        one_described_v5e, monkeypatch):
+    """TPC-H Q1's signature at the benchmark's shape (384 blocks of 2,048
+    rows, 16 columns, "bits" presence leaves, delta16 and plain value
+    planes), compiled by the TPU's own compiler: Mosaic takes the kernel,
+    and in the optimized module no bool plane is laid out by rows, the
+    packed masks are laid out ONCE (one copy of an int32 ``[384, 64,
+    32]``), and nothing beside the kernel has the mask word's producer
+    fused into it a second time (a ``scanned`` reduction of XLA's did: a
+    broadcast and a reshape to rows a mask)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from yugabyte_db_tpu.ops import group_agg, scan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    K, R = 384, 2048
+    kinds = {12: "i32", 13: "i32", 14: "i32", 15: "i64", 16: "i32",
+             17: "i32", 18: "str", 19: "str", 20: "i32", 21: "i32",
+             22: "i32", 23: "str", 24: "str", 25: "str", 26: "str",
+             27: "str"}
+    G = group_agg.GAgg
+    disc, tax = ("-", ("k", 100), ("c", 16)), ("+", ("k", 100), ("c", 17))
+    sig = group_agg.GroupAggSig(
+        B=K, R=R, K=K, NB=group_agg.NUM_BUCKETS,
+        cols=tuple(scan.ColSig(c, k) for c, k in kinds.items()),
+        preds=(scan.PredSig(20, "i32", "<="),), apply_preds=True, flat=True,
+        group_cols=((18, 2), (19, 2)),
+        aggs=(G("sum_prod", 14, 1, (), (14,)), G("sum_prod", 15, 2, (), (15,)),
+              G("sum_prod", 15, 2, (disc,), (15, 16)),
+              G("sum_prod", 15, 2, (disc, tax), (15, 16, 17)),
+              G("sum_prod", 14, 1, (), (14,)), G("count", 14, 1, (), (14,)),
+              G("sum_prod", 15, 2, (), (15,)), G("count", 15, 1, (), (15,)),
+              G("count", None, 1, (), ())))
+    assert sig.tag() == "g2a9p1f1_d1ea91"     # the benchmark's Q1
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_described_v5e)
+
+    bits = {"bits": {"bw": S((K, R // 32))}}
+    run = {"valid": bits, "group_start": bits, "tomb": bits, "live": bits,
+           "ht_hi": {"const": {"cval": S((1, 1))}},
+           "ht_lo": {"delta16": {"dbase": S((K, 1)),
+                                 "doff": S((K, R), jnp.uint16)}},
+           "exp_hi": {"const": {"cval": S((1, 1))}},
+           "exp_lo": {"const": {"cval": S((1, 1))}},
+           "cols": {c: {"set": bits, "isnull": bits,
+                        "cmp": {"delta16": {"dbase": S((K, 1, 1)),
+                                            "doff": S((K, R, 1), jnp.uint16)}}
+                        if k == "i32" else S((K, R, 2))}
+                    for c, k in kinds.items()}}
+    before = _presence_counts()
+    text = jax.jit(functools.partial(group_agg._packed, sig)).lower(
+        run, S((group_agg.int_params(sig),))).compile().as_text()
+    assert _presence_counts()["packed"] == before["packed"] + 1
+    assert text.count("tpu_custom_call") == 1
+    assert "pred[384,64,32]" not in text and "pred[786432]" not in text
+    top = [ln for ln in text.splitlines() if re.match(r"  (ROOT )?%\S+ = ", ln)]
+    relayouts = [ln for ln in top if re.match(
+        r"  %\S+ = s32\[384,64,32\]\S* copy\(", ln)]
+    assert len(relayouts) == 1, relayouts
+    assert not [ln for ln in top if re.match(
+        r"  %\S+ = s32\[786432\]\S* reshape\(%broadcast", ln)]
 
 
 # -- the jit boundary (PR 28): one vector in, one vector out --------------------

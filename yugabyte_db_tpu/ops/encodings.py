@@ -49,6 +49,8 @@ fallback matrix lives in docs/columnar-encoding.md.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax.numpy as jnp
@@ -388,6 +390,28 @@ def _slice_b(arr, b0, K):
     return lax.dynamic_slice_in_dim(arr, b0, K, axis=0)
 
 
+def wwords(leaf, b0, K: int):
+    """A K-block window of a "bits" leaf as it is stored: int32 words
+    [K, R // 32], bit b of word w the plane's row 32 * w + b of the
+    block. ``&``, ``|`` and ``~`` of bool planes are the same on their
+    words, 32 rows an element and no relayout: a consumer that XLA
+    cannot fuse the unpack into (ops.group_agg's kernel) combines the
+    words and lays ONE result out by rows (``rows_of_words``)."""
+    return _slice_b(leaf["bits"]["bw"], b0, K)
+
+
+def rows_of_words(masks):
+    """Packed masks -> one int32 a row, in ``wplane``'s flat [K * R]
+    order: bit j of a row's value is the row's bit of ``masks[j]`` (at
+    most 32 of them, each int32 [K, R // 32] as ``wwords`` gives them).
+    ONE producer over [K, R // 32, 32] and one relayout to rows,
+    however many masks there are."""
+    shifts = jnp.arange(32, dtype=jnp.int32)
+    return functools.reduce(jnp.bitwise_or, [
+        ((m[:, :, None] >> shifts) & jnp.int32(1)) << jnp.int32(j)
+        for j, m in enumerate(masks)]).reshape(-1)
+
+
 def wplane(leaf, b0, K: int, R: int):
     """Decode a K-block window of a leaf to the flat [K*R, ...] layout
     ops.scan's plain-plane windowing produces. Dispatch is on pytree
@@ -397,7 +421,7 @@ def wplane(leaf, b0, K: int, R: int):
         return _slice_b(leaf, b0, K).reshape((K * R,) + leaf.shape[2:])
     e = leaf[k]
     if k == "bits":
-        w = _slice_b(e["bw"], b0, K)
+        w = wwords(leaf, b0, K)
         bits = (w[:, :, None] >> jnp.arange(32, dtype=jnp.int32)) \
             & jnp.int32(1)
         return bits.astype(jnp.bool_).reshape(K * R)

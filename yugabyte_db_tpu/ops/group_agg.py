@@ -10,14 +10,35 @@ of Q1's old program were 71 of its 82 ms). The signature decides how,
 nothing else:
 
 - with group columns, ONE Pallas kernel a window, gridded over row tiles
-  (``_window_kernel``; ``_tile_rows`` rows a step). XLA hands it a dozen
-  int32 vectors a row (rowid, the match and not-null masks as bits of a
-  word, the planes of the base, factor and group columns); what is made
-  per row — digits, carries, 7-bit pieces, key pieces, the bucket
-  (``_bucket_hash`` of the key planes), the bucket one-hot — lives in
-  VMEM for the length of a tile and never exists in HBM (as XLA ops it
-  was an ``[109, N]`` int8 operand, 860 launches and 70 MB of
-  temporaries a call of Q1):
+  (``_window_kernel``; ``_tile_rows`` rows a step). XLA hands it ten
+  int32 vectors a row (the match, the not-null masks and ``scanned`` as
+  bits of ONE word, ``_mask_bits``; the planes of the base, factor and
+  group columns); what is made per row — the rowid, digits, carries,
+  7-bit pieces, key pieces, the bucket (``_bucket_hash`` of the key
+  planes), the bucket one-hot — lives in VMEM for the length of a tile
+  and never exists in HBM (as XLA ops it was an ``[109, N]`` int8
+  operand, 860 launches and 70 MB of temporaries a call of Q1).
+  The mask word is made in one of two forms, by what the program sees
+  where it is traced (the signature and the run's pytree; no flag):
+  - *packed* (``_packed_window``), for a flat run whose presence planes
+    — valid, tomb, live, every column's set and isnull — are "bits"
+    leaves (a run uploaded encoded: the served tables): ``alive & set &
+    ~isnull``, ``exists = live | any(not null)`` and the predicates'
+    not-null are ``&``, ``|``, ``~`` on the packed words ``[K, R //
+    32]`` (ops.scan.resolve_flat_packed), 32 rows an element and no
+    relayout; ONE producer lays the word's masks out by rows
+    (encodings.rows_of_words) and the per-row parts — the read point
+    and the TTL against ``ht`` / ``exp``, the scan's bounds, the
+    predicates' compares — clear their bits there. A custom call is a
+    consumer XLA fuses no unpack into: by rows, each of the 31 bool
+    planes of Q1 was materialised, 0.65 of the program's 2.0 ms on the
+    v5e;
+  - *by rows* (``_rows_window``), for everything else: a run that is
+    not flat, a plain bool plane among the presence planes (a device
+    flush's run; the delta overlay's masked ``valid``). Bit for bit the
+    same words: the oracle of tests/test_group_agg.py.
+  ``yb_grouped_presence{form}`` counts the programs traced in each form.
+  In the kernel:
   - sums and counts: the bucket one-hot ``[NB, T]`` (bucket == iota)
     times ONE matrix ``[C, T]`` of everything a bucket sums — the 0/1
     masks of ``count`` / ``n<i>`` / count aggregates, every sum's masked
@@ -46,7 +67,9 @@ nothing else:
 - no group column (Q6, every ungrouped expression sum): no hash, no
   one-hot, no buckets, no kernel — ``jnp.sum`` / ``jnp.min`` of the same
   columns (``_columns``) over the window's rows, into bucket 0 of the
-  same outputs.
+  same outputs. Its window is always resolved by rows: XLA fuses the
+  unpacks of the bit planes into the reductions (Q6's whole program is
+  0.172 ms on the v5e), and its lowering is kept as it is to the line.
 
 Integer sums (including product expressions like
 sum(price * (100 - disc) * (100 + tax)) over scaled-integer money
@@ -75,8 +98,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from yugabyte_db_tpu.ops.scan import I32_MAX, _eval_pred, resolve_window
-from yugabyte_db_tpu.utils import jitting
+from yugabyte_db_tpu.ops import encodings
+from yugabyte_db_tpu.ops.scan import (I32_MAX, _eval_pred, presence_is_packed,
+                                      resolve_flat_packed, resolve_window)
+from yugabyte_db_tpu.utils import jitting, metrics
 from yugabyte_db_tpu.utils.jitting import compile_contract
 
 NUM_BUCKETS = 512
@@ -372,6 +397,30 @@ def _kernel_rows(sig: GroupAggSig):
     return tuple(notnull), tuple(planes)
 
 
+def _mask_bits(sig: GroupAggSig):
+    """The masks a row's mask words hold, mask j in bit j % 32 of word
+    j // 32: the match mask, the not-null masks of ``_kernel_rows``'
+    columns, and last the row's ``scanned`` mask (exists and in range,
+    before the predicates: the kernel counts it off the word it reads
+    anyway; a reduction of XLA's beside the kernel has the mask word's
+    whole producer fused into it a second time, one relayout a mask).
+    Returns (the not-null columns, the bit of ``scanned``,
+    the number of words)."""
+    notnull_cols = _kernel_rows(sig)[0]
+    scanned_bit = 1 + len(notnull_cols)
+    return notnull_cols, scanned_bit, scanned_bit // 32 + 1
+
+
+def _words_of_rows(bits):
+    """``[N]`` bool masks -> the rows' mask words, ``_mask_bits``'
+    layout: 32 shifts and ORs a word over ``[N]``, each mask laid out
+    by rows before it comes here."""
+    return [functools.reduce(jnp.bitwise_or, [
+        b.astype(jnp.int32) << jnp.int32(k)
+        for k, b in enumerate(bits[w:w + 32])])
+        for w in range(0, len(bits), 32)]
+
+
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
@@ -411,19 +460,23 @@ def _fold8(v):
     return v.reshape(-1, 8, 128).sum(axis=0)
 
 
-def _window_kernel(sig: GroupAggSig, S: int, x_ref, cnt0_ref, key0_ref,
-                   sums_ref, keyp_ref, rep_ref, stat_ref,
+def _window_kernel(sig: GroupAggSig, S: int, x_ref, base_ref, cnt0_ref,
+                   key0_ref, sums_ref, keyp_ref, rep_ref, stat_ref,
                    p_ref, b_ref, tile_ref, seen_ref, ktab_ref):
     """One tile of S x 128 rows (grid axis 0, ``arbitrary``: the outputs
     stay in VMEM and accumulate over the window).
 
-    x_ref[V, S, 128]: rowid, the mask words, the planes of
-    ``_kernel_rows``; cnt0_ref[NBP, 1] / key0_ref[NBP, KW]: the buckets'
-    counts and key pieces of the windows before. Outputs: sums[NBP, CP]
+    x_ref[V, S, 128]: the mask words (``_mask_bits``), the planes of
+    ``_kernel_rows`` and, of a run that is not flat, the rowid of each
+    key group's first row; base_ref[1, 128]: the rowid of the window's
+    first row (a flat row's own is that, its tile's offset and its place
+    in the tile: made here, not handed in); cnt0_ref[NBP, 1] /
+    key0_ref[NBP, KW]: the buckets' counts and key pieces of the windows
+    before. Outputs: sums[NBP, CP]
     (the window's; column order: key pieces, then ``_columns``'),
     keyp[NBP, KW] (every seen bucket's key pieces), rep[NBP, 1] (first
-    matching row of the buckets first seen in this window), stat[2, 8,
-    128] (partial counts: negs, collisions). Scratch: p[S * CP, 128] the
+    matching row of the buckets first seen in this window), stat[3, 8,
+    128] (partial counts: negs, collisions, scanned). Scratch: p[S * CP, 128] the
     tile's columns (row s * CP + c: column c of the rows of lane-row s),
     b[S, 128] the rows' buckets, tile[NBP, CP] the tile's sums, seen[NBP,
     1] the buckets' rows so far, ktab[KP, NBP] the kept keys' planes with
@@ -432,8 +485,8 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, cnt0_ref, key0_ref,
 
     KP, NB = _key_planes(sig), sig.NB
     KP5, _C, CP, NBP, KW = _kernel_dims(sig)
-    notnull_cols, planes = _kernel_rows(sig)
-    words = -(-(1 + len(notnull_cols)) // 32)    # x_ref[1:1 + words]
+    notnull_cols, scanned_bit, words = _mask_bits(sig)    # x_ref[:words]
+    planes = _kernel_rows(sig)[1]
     G = min(_PRODUCT_LANE_ROWS, S)
     i = pl.program_id(0)
 
@@ -456,9 +509,10 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, cnt0_ref, key0_ref,
         keep_keys(key0_ref[...])
 
     def bit(k):
-        return ((x_ref[1 + k // 32] >> jnp.int32(k % 32)) & jnp.int32(1)) != 0
+        return ((x_ref[k // 32] >> jnp.int32(k % 32)) & jnp.int32(1)) != 0
 
     m = bit(0)
+    stat_ref[2] += _fold8(bit(scanned_bit).astype(jnp.int32))
 
     # A tile no row of which matches (past the run's rows, outside the
     # scan's bounds) sums nothing.
@@ -467,7 +521,7 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, cnt0_ref, key0_ref,
         notnull = {cid: bit(1 + j) for j, cid in enumerate(notnull_cols)}
 
         def plane(cid, pi):
-            return x_ref[1 + words + planes.index((cid, pi))]
+            return x_ref[words + planes.index((cid, pi))]
 
         cols, bad = _columns(sig, m, notnull, plane)
         stat_ref[0] += _fold8(bad.astype(jnp.int32))
@@ -516,10 +570,15 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, cnt0_ref, key0_ref,
                 - (q * n > a).astype(jnp.int32)
             keep_keys(jnp.where(first_seen, q, keyp_ref[...]))
 
+            lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+
             def first_row(s, rep):
+                if sig.flat:
+                    rowid = base_ref[...] + ((i * S + s) * 128 + lane)
+                else:
+                    rowid = x_ref[words + len(planes), pl.ds(s, 1), :]
                 return jnp.minimum(rep, jnp.where(
-                    buckets == b_ref[pl.ds(s, 1), :],
-                    x_ref[0, pl.ds(s, 1), :], I32_MAX))
+                    buckets == b_ref[pl.ds(s, 1), :], rowid, I32_MAX))
 
             rep = lax.fori_loop(0, S, first_row,
                                 jnp.full((NBP, 128), I32_MAX, jnp.int32))
@@ -546,27 +605,28 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, cnt0_ref, key0_ref,
         stat_ref[1] += _fold8((m & differs).astype(jnp.int32))
 
 
-def _grouped_window(sig: GroupAggSig, m, notnull, plane, rowid, count, key):
+def _grouped_window(sig: GroupAggSig, words, plane, base, start_idx,
+                    count, key):
     """The grouped reduction of one window, as one ``pallas_call`` over
-    row tiles (interpreted where the backend is no TPU): ``m``,
-    ``notnull`` and ``plane`` as ``_columns`` takes them (``[N]``
-    vectors), ``rowid[N]``, and the accumulator's ``count[NB]`` and
+    row tiles (interpreted where the backend is no TPU): the rows' mask
+    ``words`` (``_mask_bits``; ``[N]`` int32 each), ``plane`` as
+    ``_columns`` takes it (``[N]`` vectors), ``base`` the rowid of the
+    window's first row, ``start_idx[N]`` the first row of each key group
+    in the window (read of a run that is not flat only: a flat row is
+    its own group), and the accumulator's ``count[NB]`` and
     ``key[NB, KP]`` of the windows before. Returns (sums[NB, C] in
-    ``_columns``' order, rep[NB], key[NB, KP], collisions, negs)."""
+    ``_columns``' order, rep[NB], key[NB, KP], collisions, negs,
+    scanned)."""
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     NB, KP = sig.NB, _key_planes(sig)
     KP5, C, CP, NBP, KW = _kernel_dims(sig)
-    notnull_cols, planes = _kernel_rows(sig)
-    bits = [m] + [notnull[cid] for cid in notnull_cols]
-    rows = [rowid] + [
-        functools.reduce(jnp.bitwise_or, [
-            b.astype(jnp.int32) << jnp.int32(k)
-            for k, b in enumerate(bits[w:w + 32])])
-        for w in range(0, len(bits), 32)] + [plane(*cp) for cp in planes]
-    n = rowid.shape[0]
+    rows = list(words) + [plane(*cp) for cp in _kernel_rows(sig)[1]]
+    if not sig.flat:
+        rows.append(base + start_idx)
+    n = rows[0].shape[0]
     T = _tile_rows(sig, n)
     S = T // 128
     x = jnp.stack(rows)
@@ -576,6 +636,7 @@ def _grouped_window(sig: GroupAggSig, m, notnull, plane, rowid, count, key):
     key0 = jnp.pad(jnp.stack(_plane_pieces(key), axis=-1).reshape(NB, KP5),
                    ((0, NBP - NB), (0, KW - KP5)))
     cnt0 = jnp.pad(count, (0, NBP - NB))[:, None]
+    base = jnp.full((1, 128), base, jnp.int32)
 
     def whole(*shape):
         return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
@@ -585,13 +646,13 @@ def _grouped_window(sig: GroupAggSig, m, notnull, plane, rowid, count, key):
         functools.partial(_window_kernel, sig, S),
         grid=(x.shape[1] // S,),
         in_specs=[pl.BlockSpec((len(rows), S, 128), lambda i: (0, i, 0)),
-                  whole(NBP, 1), whole(NBP, KW)],
+                  whole(1, 128), whole(NBP, 1), whole(NBP, KW)],
         out_specs=[whole(NBP, CP), whole(NBP, KW), whole(NBP, 1),
-                   whole(2, 8, 128)],
+                   whole(3, 8, 128)],
         out_shape=[jax.ShapeDtypeStruct((NBP, CP), jnp.int32),
                    jax.ShapeDtypeStruct((NBP, KW), jnp.int32),
                    jax.ShapeDtypeStruct((NBP, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((2, 8, 128), jnp.int32)],
+                   jax.ShapeDtypeStruct((3, 8, 128), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((S * CP, 128), jnp.int32),
                         pltpu.VMEM((S, 128), jnp.int32),
                         pltpu.VMEM((NBP, CP), jnp.int32),
@@ -602,10 +663,93 @@ def _grouped_window(sig: GroupAggSig, m, notnull, plane, rowid, count, key):
             vmem_limit_bytes=2 * vmem + (8 << 20)),
         interpret=jax.default_backend() != "tpu",
         name="grouped_window",
-    )(x, cnt0, key0)
+    )(x, base, cnt0, key0)
     return (sums[:NB, KP5:C], rep[:NB, 0],
             _plane_of_pieces(keyp[:NB, :KP5].reshape(NB, KP, 5)),
-            jnp.sum(stat[1]), jnp.sum(stat[0]))
+            jnp.sum(stat[1]), jnp.sum(stat[0]), jnp.sum(stat[2]))
+
+
+def _rows_window(sig: GroupAggSig, run, b0, row_lo, row_hi, read,
+                 pred_literals):
+    """A window by rows: every plane ``resolve_window`` reads laid out
+    by rows (XLA fuses the unpacks into the ungrouped program's
+    reductions; for the kernel it materialises each). Returns
+    (``resolve_window``'s dict, the mask of the entries that are key
+    groups, the match mask, plane)."""
+    r = resolve_window(dataclasses.replace(sig, apply_preds=False), run, b0,
+                       row_lo, row_hi, *read, pred_literals)
+    gvalid = r["ridx"] < r["num_groups"]
+    cmp_w = r["cmp_w"]
+    col_idx = r["col_idx"]
+    col_notnull = r["col_notnull"]
+    # The predicates, on the window's planes themselves where every
+    # row is its own group: resolve_window would index them through
+    # col_idx, and a gather by arange is still a gather on the TPU
+    # (117 us a predicate column a window of 16,384 rows on the v5e).
+    m = r["pre_pred"] & gvalid
+    if sig.apply_preds:
+        for ps, lit in zip(sig.preds, pred_literals):
+            m = m & col_notnull[ps.col_id] & _eval_pred(
+                ps, cmp_w.get(ps.col_id), r["arith_w"].get(ps.col_id),
+                slice(None) if sig.flat else col_idx[ps.col_id], lit)
+
+    def plane(cid, pi):
+        return (cmp_w[cid][:, pi] if sig.flat
+                else cmp_w[cid][col_idx[cid], pi])
+
+    return r, gvalid, m, plane
+
+
+def _rows_words(sig: GroupAggSig, r, gvalid, m):
+    """``_rows_window``'s masks as the kernel's mask words."""
+    return _words_of_rows(
+        [m] + [r["col_notnull"][cid] for cid in _mask_bits(sig)[0]]
+        + [r["pre_pred"] & gvalid])
+
+
+def _packed_window(sig: GroupAggSig, run, b0, row_lo, row_hi, read,
+                   pred_literals):
+    """The mask words and planes of a flat window whose presence planes
+    are "bits" leaves, bit for bit the rows form's (``_words_of_rows``
+    over ``resolve_window``'s masks), without one of those planes laid
+    out by rows: the masks are combined on the packed words
+    (ops.scan.resolve_flat_packed), ONE producer lays a word's 32 masks
+    out by rows (encodings.rows_of_words), and what is per row and no
+    bit plane — the read point and the TTL against ``ht`` / ``exp``, the
+    scan's bounds, the predicates' compares — clears its bits of the
+    word there. Returns (words, plane)."""
+    notnull_cols, scanned_bit, _words = _mask_bits(sig)
+    p = resolve_flat_packed(sig, run, b0, row_lo, row_hi, *read)
+    cmp_w, arith_w = p["cmp_w"], p["arith_w"]
+    match_w = p["exists_w"]
+    hit = p["in_range"]
+    if sig.apply_preds:
+        for ps, lit in zip(sig.preds, pred_literals):
+            match_w = match_w & p["notnull_w"][ps.col_id]
+            hit = hit & _eval_pred(ps, cmp_w.get(ps.col_id),
+                                   arith_w.get(ps.col_id), slice(None), lit)
+    masks = [match_w] + [p["notnull_w"][cid] for cid in notnull_cols] \
+        + [p["exists_w"]]
+    words = []
+    for w in range(0, len(masks), 32):
+        # a row outside the bounds keeps its not-null bits (they hold
+        # no bound in the rows form either), one that is not visible or
+        # is expired keeps none
+        drop = jnp.int32(0)
+        if w == 0:
+            drop = jnp.where(hit, drop, jnp.int32(1))
+        if w <= scanned_bit < w + 32:
+            drop = drop | jnp.where(
+                p["in_range"], jnp.int32(0),
+                jnp.int32(1) << jnp.int32(scanned_bit - w))
+        words.append(jnp.where(
+            p["row_ok"], encodings.rows_of_words(masks[w:w + 32]) & ~drop,
+            jnp.int32(0)))
+
+    def plane(cid, pi):
+        return cmp_w[cid][:, pi]
+
+    return words, plane
 
 
 def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
@@ -634,7 +778,6 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
     read = (iparams[4], iparams[5], iparams[6], iparams[7])
     pred_literals = _unpack_literals(sig, iparams, fparams)
 
-    unfiltered = dataclasses.replace(sig, apply_preds=False)
     KP = _key_planes(sig)
     NA = NB if sig.group_cols else 1   # buckets the loop accumulates
     mask_at, digits_at, _C = _column_layout(sig)
@@ -658,51 +801,50 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
                 acc[f"n{i}"] = jnp.zeros((NA,), jnp.int32)
         return acc
 
+    # A flat grouped window hands its kernel the presence masks; where
+    # the run keeps them as "bits" leaves they are combined packed. What
+    # the program sees decides, nothing else; the ungrouped program's
+    # reductions fuse the unpacks and keep the rows form.
+    packed = (len(sig.group_cols) > 0 and sig.flat
+              and presence_is_packed(sig, run))
+    if sig.group_cols:
+        metrics.count_grouped_presence("packed" if packed else "rows")
+
     def body(w, acc):
         b0 = w * K
         base = b0 * R
-        r = resolve_window(unfiltered, run, b0, row_lo - base,
-                           row_hi - base, *read, pred_literals)
-        gvalid = r["ridx"] < r["num_groups"]
-        cmp_w = r["cmp_w"]
-        col_idx = r["col_idx"]
-        col_notnull = r["col_notnull"]
-        # The predicates, on the window's planes themselves where every
-        # row is its own group: resolve_window would index them through
-        # col_idx, and a gather by arange is still a gather on the TPU
-        # (117 us a predicate column a window of 16,384 rows on the v5e).
-        m = r["pre_pred"] & gvalid
-        if sig.apply_preds:
-            for ps, lit in zip(sig.preds, pred_literals):
-                m = m & col_notnull[ps.col_id] & _eval_pred(
-                    ps, cmp_w.get(ps.col_id), r["arith_w"].get(ps.col_id),
-                    slice(None) if sig.flat else col_idx[ps.col_id], lit)
+        window = (sig, run, b0, row_lo - base, row_hi - base, read,
+                  pred_literals)
+        if packed:
+            words, plane = _packed_window(*window)
+            return accumulate(acc, base, None, words, plane)
+        r, gvalid, m, plane = _rows_window(*window)
+        if sig.group_cols:
+            return accumulate(acc, base, r["start_idx"],
+                              _rows_words(sig, r, gvalid, m), plane)
+        # No group column, no buckets: plain reductions over the rows.
         rowid = base + r["start_idx"]
-
-        def plane(cid, pi):
-            return (cmp_w[cid][:, pi] if sig.flat
-                    else cmp_w[cid][col_idx[cid], pi])
-
-        if not sig.group_cols:
-            # (the grouped program builds its columns in the kernel)
-            cols, bad = _columns(sig, m, col_notnull, plane)
+        cols, bad = _columns(sig, m, r["col_notnull"], plane)
         new = {
             "scanned": acc["scanned"] + jnp.sum(
                 (r["pre_pred"] & gvalid).astype(jnp.int32)),
-            "negs": acc["negs"] if sig.group_cols else acc["negs"] + jnp.sum(
-                bad.astype(jnp.int32)),
+            "negs": acc["negs"] + jnp.sum(bad.astype(jnp.int32)),
             "key": acc["key"], "collisions": acc["collisions"],
         }
-        if sig.group_cols:
-            sums, rep, new["key"], collisions, negs = _grouped_window(
-                sig, m, col_notnull, plane, rowid, acc["count"], acc["key"])
-            new["collisions"] = acc["collisions"] + collisions
-            new["negs"] = acc["negs"] + negs
-        else:
-            # No group column, no buckets: plain reductions over the rows.
-            sums = jnp.stack([jnp.sum(c) for c in cols])[None]   # [1, C]
-            rep = jnp.min(jnp.where(m, rowid, I32_MAX))[None]
+        sums = jnp.stack([jnp.sum(c) for c in cols])[None]   # [1, C]
+        rep = jnp.min(jnp.where(m, rowid, I32_MAX))[None]
+        return add_sums(acc, new, sums, rep)
 
+    def accumulate(acc, base, start_idx, words, plane):
+        """A grouped window, from its rows' mask words on."""
+        sums, rep, key, collisions, negs, scanned = _grouped_window(
+            sig, words, plane, base, start_idx, acc["count"], acc["key"])
+        return add_sums(acc, {
+            "scanned": acc["scanned"] + scanned,
+            "negs": acc["negs"] + negs, "key": key,
+            "collisions": acc["collisions"] + collisions}, sums, rep)
+
+    def add_sums(acc, new, sums, rep):
         new["rep"] = jnp.minimum(acc["rep"], rep)
         for name, col in mask_at.items():
             new[name] = acc[name] + sums[:, col]

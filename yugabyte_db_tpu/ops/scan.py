@@ -324,6 +324,73 @@ def _resolve_flat(sig, run, b0, row_lo, row_hi, pred_literals,
     }
 
 
+def presence_is_packed(sig, run) -> bool:
+    """Every presence plane a flat resolve reads of ``run`` — valid,
+    tomb, live, each touched column's set and isnull — is a "bits" leaf
+    (pytree structure: known where the program is traced). A plain bool
+    plane among them (a run uploaded unencoded, the delta overlay's
+    masked ``valid``) makes it False."""
+    leaves = [run["valid"], run["tomb"], run["live"]]
+    for cs in sig.cols:
+        c = run["cols"][cs.col_id]
+        leaves += [c["set"], c["isnull"]]
+    return all(encodings.leaf_kind(leaf) == "bits" for leaf in leaves)
+
+
+def resolve_flat_packed(sig, run, b0, row_lo, row_hi,
+                        read_hi, read_lo, rexp_hi, rexp_lo):
+    """``_resolve_flat`` with the presence algebra left on the packed
+    words (``presence_is_packed`` runs only), for a consumer XLA cannot
+    fuse an unpack into: ops.group_agg's kernel, which takes ONE mask
+    word a row. Every ``&``, ``|``, ``~`` of the flat resolve's bit
+    planes is done on int32 [K, R // 32] (32 rows an element, no
+    relayout); what is per row and no bit plane stays per row. Returns
+      exists_w      i32 [K, R // 32]   valid & ~tomb & (live | any column
+                                        set and not NULL)
+      notnull_w     {col_id: i32 [K, R // 32]}  valid & ~tomb & set & ~isnull
+      row_ok        bool [N]  visible at the read point and not expired
+      in_range      bool [N]  inside the scan's row bounds
+      cmp_w, arith_w          as ``_resolve_flat`` gives them
+    with, bit for bit, ``_resolve_flat``'s
+      pre_pred        == rows(exists_w) & row_ok & in_range
+      col_notnull[c]  == rows(notnull_w[c]) & row_ok
+    (every term of ``exists`` holds ``alive & ~expired``, whose per-row
+    part is ``row_ok`` and whose packed part ``valid & ~tomb``)."""
+    K, R = sig.K, sig.R
+    N = K * R
+
+    def ww(leaf):
+        return encodings.wwords(leaf, b0, K)
+
+    def wp(leaf):
+        return encodings.wplane(leaf, b0, K, R)
+
+    alive_w = ww(run["valid"]) & ~ww(run["tomb"])
+    exists_w = alive_w & ww(run["live"])
+    notnull_w = {}
+    cmp_w = {}
+    arith_w = {}
+    for cs in sig.cols:
+        c = run["cols"][cs.col_id]
+        notnull_w[cs.col_id] = alive_w & ww(c["set"]) & ~ww(c["isnull"])
+        exists_w = exists_w | notnull_w[cs.col_id]
+        cmp_w[cs.col_id] = wp(c["cmp"])
+        if "arith" in c:
+            arith_w[cs.col_id] = wp(c["arith"])
+
+    ridx = jnp.arange(N, dtype=jnp.int32)
+    visible = le2(wp(run["ht_hi"]), wp(run["ht_lo"]), read_hi, read_lo)
+    expired = le2(wp(run["exp_hi"]), wp(run["exp_lo"]), rexp_hi, rexp_lo)
+    return {
+        "exists_w": exists_w,
+        "notnull_w": notnull_w,
+        "row_ok": visible & ~expired,
+        "in_range": (ridx >= row_lo) & (ridx < row_hi),
+        "cmp_w": cmp_w,
+        "arith_w": arith_w,
+    }
+
+
 def scan_window(sig: ScanSig, run, b0, row_lo, row_hi,
                 read_hi, read_lo, rexp_hi, rexp_lo, pred_literals):
     """The traced scan program. ``run`` is the device-array pytree

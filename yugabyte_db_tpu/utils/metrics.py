@@ -787,3 +787,31 @@ def grouped_agg_fallbacks() -> dict[str, int]:
 # (every reason reads 0 on /metrics from the start: a series that is
 # missing cannot be told from one that never grew)
 grouped_agg_fallbacks()
+
+
+GROUPED_PRESENCE_FORMS = ("packed", "rows")
+
+
+def _grouped_presence_counter(form: str) -> Counter:
+    return _span_entity(("grouped_presence", form),
+                        form=form).counter("yb_grouped_presence")
+
+
+def count_grouped_presence(form: str) -> None:
+    """``yb_grouped_presence{form=packed|rows}``: a grouped-aggregate
+    program with group columns was traced (ops/group_agg.py
+    ``grouped_aggregate``; once a compiled program, nothing a request
+    pays) and handed its kernel the presence masks combined on the
+    packed words of "bits" leaves (``packed``) or unpacked plane by
+    plane (``rows``: a run that is not flat, a plain bool plane among
+    the presence planes)."""
+    _grouped_presence_counter(form).increment()
+
+
+def grouped_presence() -> dict[str, int]:
+    """Current ``yb_grouped_presence`` by form."""
+    return {f: _grouped_presence_counter(f).get()
+            for f in GROUPED_PRESENCE_FORMS}
+
+
+grouped_presence()
