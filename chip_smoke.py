@@ -183,6 +183,9 @@ class Smoke:
         self.phases: dict[str, float] = {}
         self.findings: list[str] = []
         self.mesh_first_s: dict[str, float] = {}
+        # The route is the node's own chip count (client/mesh_route.py):
+        # only a host with several chips sends mesh requests.
+        self.multi_chip = len(jax.local_devices()) > 1
         self.ref = Reference(self.rows)
         self.rng = np.random.default_rng(args.seed)
         # Keys the point SELECTs must cover, by what happened to them.
@@ -480,8 +483,10 @@ class Smoke:
         """The query set, each answer exact against the reference.
 
         ``one_run``: every tablet is a single run with an empty memtable,
-        so every query is a device program and the session's scans must
-        ride the mesh. Otherwise reads are multi-source, and whatever the
+        so every query is a device program and, on a host with several
+        chips, PG's Q1 and Q6 and the session's scans must ride the mesh
+        (a one-chip host keeps one request a tablet: the route is the
+        node's own chip count). Otherwise reads are multi-source, and whatever the
         engine cannot keep on the device — any row scan, any grouped or
         expression aggregate, and plain aggregates when no overlay
         applies — is merged on the host row by row inside one ts.scan
@@ -491,21 +496,37 @@ class Smoke:
         log(f"checkpoint {when}")
         ref = self.ref
         quoted = f'"{TABLE}"'
+        # On a host with several chips the PG frontend sends a leader's
+        # tablets as ONE ts.multi_agg_scan (client/mesh_route.py): with
+        # one run a tablet, count(*), Q1 and Q6 must each be a mesh
+        # program.
+        on_mesh = one_run and self.multi_chip
         t0 = time.perf_counter()
+        before = self.mesh_counters()
         got = self.pg.execute(f"SELECT count(*) FROM {quoted}").rows
         check(got == [(ref.count(),)],
               f"{when}: count(*) {got} != {ref.count()}")
+        if on_mesh:
+            self.check_rode_mesh(when, "PG count(*)", before, "served", 1)
         self.timed(f"{when}.count", t0)
         t0 = time.perf_counter()
+        before = self.mesh_counters()
         got = self.pg.execute(tpch.q1_sql(Q1_CUTOFF, table=quoted)).rows
         want = ref.q1()
         check([tuple(r) for r in got] == want,
               f"{when}: Q1 differs\n got {got}\nwant {want}")
+        if on_mesh:
+            self.mesh_first_s.setdefault(
+                "pg_q1", round(time.perf_counter() - t0, 2))
+            self.check_rode_mesh(when, "PG Q1", before, "served", 1)
         self.timed(f"{when}.q1", t0)
         t0 = time.perf_counter()
+        before = self.mesh_counters()
         got = self.pg.execute(
             tpch.q6_sql(Q6_LO, Q6_DISC, Q6_QTY, table=quoted)).rows
         check(got == [(ref.q6(),)], f"{when}: Q6 {got} != {ref.q6()}")
+        if on_mesh:
+            self.check_rode_mesh(when, "PG Q6", before, "served", 1)
         self.timed(f"{when}.q6", t0)
 
         # A shipdate band wide enough for MIN_PAGES+ pages of PAGE rows:
@@ -519,8 +540,8 @@ class Smoke:
         self.cql_pages(when, lo, lo + width, want_rows)
         self.timed(f"{when}.cql_pages", t0)
         t0 = time.perf_counter()
-        self.session_pages(when, lo, lo + width, want_rows, one_run)
-        self.session_aggregate(when, lo, one_run)
+        self.session_pages(when, lo, lo + width, want_rows, on_mesh)
+        self.session_aggregate(when, lo, on_mesh)
         self.timed(f"{when}.session_scans", t0)
         t0 = time.perf_counter()
         self.point_selects(when)
@@ -573,7 +594,7 @@ class Smoke:
               and not grew["chip_losses"],
               f"{when}: {n} {what} moved the mesh counters by {grew}")
         swallowed = {k: v for k, v in metrics.swallowed_errors().items()
-                     if k.startswith("session.multi_") and v}
+                     if k.startswith(("session.multi_", "mesh_route.")) and v}
         check(not swallowed, f"{when}: the session swallowed {swallowed}")
 
     def session_pages(self, when, lo, hi, want_rows, one_run) -> None:
@@ -725,7 +746,7 @@ class Smoke:
         log(f"swallowed errors by site: {swallowed}")
         for site, n in swallowed.items():
             if n and (site in ("session.multi_row_scan",
-                               "session.multi_agg_scan")
+                               "mesh_route.multi_agg_scan")
                       or site.startswith("tpu_engine.")):
                 bad.append(f"{n} swallowed errors at {site}")
         if not metrics.flush_path_count("device"):
@@ -737,15 +758,19 @@ class Smoke:
                 f"chip_losses={m.chip_losses}")
             if m.chip_losses:
                 bad.append(f"{m.chip_losses} mesh chip losses on {u}")
-        if not any(m.served > 0 and m.served_rows > 0
-                   for m in mesh.values()):
+        if self.multi_chip and not any(m.served > 0 and m.served_rows > 0
+                                       for m in mesh.values()):
             bad.append("no tserver served both an aggregate and a row "
                        "page on the mesh")
+        if not self.multi_chip and any(m.served or m.served_rows
+                                       for m in mesh.values()):
+            bad.append("a one-chip host served a mesh request")
         for group in (("replay_flush",),
                       ("gather_batch", "scan_window", "dist_page"),
                       ("flat_aggregate", "lookback_aggregate", "batched_agg",
                        "dist_agg"),
-                      ("grouped_aggregate", "batched_grouped")):
+                      ("grouped_aggregate", "batched_grouped",
+                       "dist_grouped_aggregate")):
             if not any(compiles_served.get(e, 0) for e in group):
                 bad.append(f"the served path compiled none of {group}")
         if self.leaders_now() != self.leader_map:
@@ -883,6 +908,29 @@ def compile_unreached(smoke: Smoke, served: dict) -> dict:
     check(mv_t.scan(at(page, mv_ht)).rows == mv_c.scan(at(page, mv_ht)).rows,
           "gather page differs")
     how["gather_batch"] = "multi-version LIMIT page vs CPU oracle"
+    # The mesh's three programs, where the served path did not take them
+    # (a host with one chip sends one request a tablet): the node's own
+    # mesh over a stack of the same run twice, against the oracle's
+    # answer combined with itself.
+    from yugabyte_db_tpu.parallel import (sharded_aggregate,
+                                          sharded_grouped_aggregate,
+                                          sharded_row_page)
+    from yugabyte_db_tpu.storage.scan_spec import combine_grouped
+
+    mesh = next(iter(smoke.mc.tservers.values())).mesh_scan._get_mesh()
+    twice = ShardedTablets(schema, [mv_t.runs[0].crun] * 2, mesh)
+    for entry, fn, spec in (
+            ("dist_agg", sharded_aggregate, at(agg, mv_ht)),
+            ("dist_grouped_aggregate",
+             lambda st, sp: sharded_grouped_aggregate(st, sp, mv_t), q1_mv)):
+        one = mv_c.scan(spec)
+        check(fn(twice, spec).rows == combine_grouped(spec, [one, one]).rows,
+              f"{entry} over the run stacked twice differs")
+        how[entry] = "stack of one run twice vs CPU oracle combined"
+    check(sharded_row_page(twice, at(page, mv_ht)).rows
+          == mv_c.scan(at(page, mv_ht)).rows, "dist_page differs")
+    how["dist_page"] = "first LIMIT page of a two-tablet stack vs CPU oracle"
+    twice.close()
     extra = list(tpch.generate_lineitem(200, seed=smoke.args.seed + 2))
     from yugabyte_db_tpu.models.partition import compute_hash_code
     from yugabyte_db_tpu.storage.row_version import RowVersion
@@ -962,7 +1010,6 @@ def compile_unreached(smoke: Smoke, served: dict) -> dict:
 
     # stack_update: only un-encoded stacks update in place, and stacks
     # encode by default, so the served path rebuilds instead.
-    mesh = next(iter(smoke.mc.tservers.values())).mesh_scan._get_mesh()
     run = mv_t.runs[0].crun
     st = ShardedTablets(schema, [run, run], mesh, encode=False)
     check(st.update_tablet(1, run), "stack_update refused a same-shape run")

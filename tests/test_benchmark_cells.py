@@ -1,4 +1,6 @@
-"""Every cell of ``BENCHMARK.json`` rehearsed on the CPU, plain and traced.
+"""Every cell of ``BENCHMARK.json`` rehearsed on the CPU, plain here and
+traced in ``test_benchmark_cells_traced.py`` (one file for both was 330 s
+of one worker with four cells, more than the rest of tier-1 takes).
 
 What ``benchmark/selfcheck.py`` does before a chip call, one case a run:
 ``run.py --rehearse-cpu`` in a process of its own, its last line through
@@ -30,9 +32,10 @@ BENCH = contract.load_benchmark(ROOT)
 SEED = {0: 2_147_483_659, 1: 2_147_483_660}  # selfcheck.py's, by --trace
 
 
-@pytest.mark.parametrize("trace", [0, 1], ids=["plain", "traced"])
-@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
-def test_cell_rehearses(cell, trace):
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def rehearse(cell, trace):
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
          "--workload", cell, "--seed", str(SEED[trace]),
@@ -50,3 +53,9 @@ def test_cell_rehearses(cell, trace):
         dark = sorted(n for n, m in line["metrics"].items()
                       if not m["value"] > 0)
         assert not dark, f"per-layer metrics that read nothing: {dark}: {err}"
+
+
+@pytest.mark.parametrize("trace", [0], ids=["plain"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_rehearses(cell, trace):
+    rehearse(cell, trace)

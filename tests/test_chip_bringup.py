@@ -191,8 +191,9 @@ def test_mesh_attempts_get_the_callers_budget():
         wire.encode_result(ScanResult([], [], None, 0)), code="ok"))
     client = YBClient(tr, ["m-0"])
     locs = TableLocations("id", {}, [
-        TabletLocation("t1", 0, 32768, ["ts-0"], "ts-0"),
-        TabletLocation("t2", 32768, 65536, ["ts-0"], "ts-0")])
+        TabletLocation("t1", 0, 32768, ["ts-0"], "ts-0", {}, {"ts-0": 4}),
+        TabletLocation("t2", 32768, 65536, ["ts-0"], "ts-0", {},
+                       {"ts-0": 4})])   # (a node with four chips)
     client.meta_cache.locations = lambda name, refresh=False: locs
     table = YBTable("tbl", "id", tpch.lineitem_schema(), engine="tpu")
     before = metrics.swallowed_errors()

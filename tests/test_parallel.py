@@ -458,3 +458,364 @@ def test_stack_close_mid_serve(mesh):
     for o in oracles:
         want.extend(o.scan(ScanSpec(**spec_kw)).rows)
     assert got == want
+
+
+# -- the grouped mesh program: TPC-H Q1 and Q6 over a ("t", "b") mesh -----------
+#
+# ops.group_agg's window loop as the shard body
+# (parallel.sharded.sharded_grouped_aggregate), against (i) the
+# benchmark's plain reference (numpy; imports nothing of the program) and
+# (ii) the CPU oracle engine, bit for bit.
+
+def _mesh_of(n):
+    shape = {1: (1, 1), 2: (1, 2), 4: (2, 2), 8: (4, 2)}[n]
+    return Mesh(np.array(jax.devices()[:n]).reshape(shape), ("t", "b"))
+
+
+def _pg_q1_spec(read_ht, cutoff):
+    """Q1 as the PG executor pushes it down: the two averages are a sum
+    and a count each (``g2a9p1f1``)."""
+    from yugabyte_db_tpu.yql.pgsql import tpch
+
+    base = tpch.q1_spec(read_ht, cutoff)
+    a = base.aggregates
+    return ScanSpec(
+        read_ht=read_ht, predicates=base.predicates, group_by=base.group_by,
+        aggregates=a[:4] + [
+            AggSpec("sum", "l_quantity", label="aq_s"),
+            AggSpec("count", "l_quantity", label="aq_c"),
+            AggSpec("sum", "l_extendedprice", label="ap_s"),
+            AggSpec("count", "l_extendedprice", label="ap_c"), a[4]])
+
+
+def _q1_as_reference(res):
+    return sorted([f, s, sq, sp, sd, sc, aq / aqn, ap / apn, n]
+                  for f, s, sq, sp, sd, sc, aq, aqn, ap, apn, n in res.rows)
+
+
+def _q6_spec(read_ht, lo, hi, dlo, dhi, qty):
+    from yugabyte_db_tpu.yql.pgsql import tpch
+
+    spec = tpch.q6_spec(read_ht)
+    spec.predicates = [
+        Predicate("l_shipdate", ">=", lo), Predicate("l_shipdate", "<", hi),
+        Predicate("l_discount", ">=", dlo), Predicate("l_discount", "<=", dhi),
+        Predicate("l_quantity", "<", qty)]
+    return spec
+
+
+class _Lineitem:
+    """A seeded LINEITEM from the benchmark's reference generator, in
+    ``T`` host-flushed TPU engines (hash-partitioned as tablets are), a
+    CPU oracle engine holding all of it, and the reference itself."""
+
+    def __init__(self, rows=4000, seed=2_147_483_777, tablets=2,
+                 tablet_of=None, rows_per_block=64):
+        from benchmark.references.tpch_lineitem import Reference
+        from yugabyte_db_tpu.utils.flags import FLAGS
+        from yugabyte_db_tpu.yql.pgsql import tpch
+
+        self.schema = schema = tpch.lineitem_schema()
+        self.ref = Reference({"scale": {"rows": rows},
+                              "schema": {"table": "lineitem", "ddl": []},
+                              "load": {"batch_ops": 1000}}, seed)
+        self.cpu = make_engine("cpu", schema)
+        cid = {c.name: c.col_id for c in schema.columns}
+        keys = [c.name for c in schema.key_columns]
+        # a host-built run keeps its presence planes as "bits" leaves
+        old = FLAGS.get("tpu_device_flush")
+        FLAGS.set("tpu_device_flush", False)
+        try:
+            self.tpus = [make_engine("tpu", schema,
+                                     dict(rows_per_block=rows_per_block))
+                         for _ in range(tablets)]
+            ht = 1000
+            for batch in self.ref.batches():
+                for row in batch:
+                    kv = {k: row[k] for k in keys}
+                    hc = compute_hash_code(schema, kv)
+                    ht += 1
+                    rv = RowVersion(
+                        schema.encode_primary_key(kv, hc), ht=ht,
+                        liveness=True,
+                        columns={cid[n]: row[n] for n in cid
+                                 if n not in keys})
+                    t = (tablet_of(row) if tablet_of
+                         else hc * tablets >> 16)
+                    self.tpus[t].apply([rv])
+                    self.cpu.apply([rv])
+            for e in self.tpus:
+                e.flush()
+        finally:
+            FLAGS.set("tpu_device_flush", old)
+        self.max_ht = ht
+        self.runs = [e.runs[0].crun for e in self.tpus]
+
+    def stack(self, mesh, encode=True):
+        return ShardedTablets(self.schema, self.runs, mesh, window_blocks=2,
+                              encode=encode)
+
+
+@pytest.fixture(scope="module")
+def lineitem():
+    return _Lineitem()
+
+
+Q6_PARAMS = dict(lo=9131, hi=9496, dlo=4, dhi=6, qty=24)
+
+
+@pytest.mark.parametrize("encode", [True, False], ids=["encoded", "plain"])
+@pytest.mark.parametrize("devices", [1, 2, 4, 8])
+@pytest.mark.parametrize("stmt", ["q1", "q6"])
+def test_grouped_mesh_equals_reference_and_oracle(lineitem, stmt, devices,
+                                                  encode):
+    from yugabyte_db_tpu.parallel import sharded_grouped_aggregate
+
+    st = lineitem.stack(_mesh_of(devices), encode)
+    assert st.encoded == encode
+    rht = lineitem.max_ht + 1
+    if stmt == "q1":
+        spec = _pg_q1_spec(rht, 10471)
+        got = sharded_grouped_aggregate(st, spec, lineitem.tpus[0])
+        assert _q1_as_reference(got) == lineitem.ref.q1(10471)
+    else:
+        spec = _q6_spec(rht, **Q6_PARAMS)
+        got = sharded_grouped_aggregate(st, spec, lineitem.tpus[0])
+        assert [list(r) for r in got.rows] == lineitem.ref.q6(**Q6_PARAMS)
+    want = lineitem.cpu.scan(spec)
+    assert (got.columns, got.rows, got.rows_scanned) == (
+        want.columns, want.rows, want.rows_scanned)
+
+
+def test_grouped_mesh_traces_the_packed_presence_form_on_an_encoded_stack(
+        lineitem):
+    """Hazard of the re-encoded stack: Q1's program must see "bits"
+    leaves (the packed presence form), and no plane may be an "rle" leaf
+    (its decode is a gather, serialized on the TPU)."""
+    from yugabyte_db_tpu.parallel import sharded, sharded_grouped_aggregate
+    from yugabyte_db_tpu.utils import metrics
+
+    st = lineitem.stack(_mesh_of(4), True)
+    planes, cols = st.enc_struct
+    kinds = [k for _n, k in planes] + [k for _c, e in cols for _n, k in e]
+    assert "rle" not in kinds and "bits" in kinds
+    sharded._compiled_dist_grouped.cache_clear()
+    before = metrics.grouped_presence()
+    sharded_grouped_aggregate(st, _pg_q1_spec(lineitem.max_ht + 1, 10471),
+                              lineitem.tpus[0])
+    after = metrics.grouped_presence()
+    assert after["packed"] > before["packed"]
+    assert after["rows"] == before["rows"]
+
+
+def test_grouped_mesh_bounded_range_and_empty_tablet_range(lineitem):
+    """Row bounds are rebased to each "b" shard; a range that misses a
+    shard (or a whole tablet) walks no window there."""
+    from yugabyte_db_tpu.parallel import sharded_grouped_aggregate
+
+    st = lineitem.stack(_mesh_of(4))
+    run = lineitem.runs[0]
+    lo, hi = run.key_at(run.total_rows() // 3), \
+        run.key_at(2 * run.total_rows() // 3)
+    spec = _pg_q1_spec(lineitem.max_ht + 1, 10471)
+    spec.lower, spec.upper = lo, hi
+    got = sharded_grouped_aggregate(st, spec, lineitem.tpus[0])
+    want = lineitem.cpu.scan(spec)
+    assert (got.rows, got.rows_scanned) == (want.rows, want.rows_scanned)
+
+
+@pytest.mark.parametrize("case", ["lacks_a_value", "other_order"])
+def test_grouped_mesh_tablets_whose_dictionaries_differ(case):
+    """Group values and ``rep`` rows belong to ONE run: a tablet that
+    lacks an ``l_returnflag`` value, and one that met its values in
+    another order, finish with their own runs and combine exactly."""
+    from yugabyte_db_tpu.parallel import sharded_grouped_aggregate
+
+    if case == "lacks_a_value":
+        world = _Lineitem(rows=1500, tablet_of=lambda row: int(
+            row["l_returnflag"] == "R"))
+        flag = world.schema.column("l_returnflag").col_id
+        for run, has_r in zip(world.runs, (False, True)):
+            run.encoded_arrays()
+            assert (b"R" in run.enc_dicts[flag]) == has_r
+            assert (b"N" in run.enc_dicts[flag]) != has_r
+    else:
+        world = _Lineitem(rows=1500, tablet_of=lambda row: int(
+            row["l_orderkey"] % 64 < 32))
+    st = world.stack(_mesh_of(4))
+    for spec in (_pg_q1_spec(world.max_ht + 1, 10471),
+                 _q6_spec(world.max_ht + 1, **Q6_PARAMS)):
+        got = sharded_grouped_aggregate(st, spec, world.tpus[0])
+        want = world.cpu.scan(spec)
+        assert (got.rows, got.rows_scanned) == (want.rows,
+                                                want.rows_scanned)
+    assert _q1_as_reference(sharded_grouped_aggregate(
+        st, _pg_q1_spec(world.max_ht + 1, 10471), world.tpus[0])) \
+        == world.ref.q1(10471)
+
+
+def test_grouped_mesh_multi_version_run_and_ttl(lineitem):
+    """Rows overwritten, deleted and expiring: the run is not flat, so
+    the shard body resolves MVCC by rows; answers at two read points
+    equal the oracle's."""
+    from yugabyte_db_tpu.parallel import sharded_grouped_aggregate
+    from yugabyte_db_tpu.utils.flags import FLAGS
+
+    schema = lineitem.schema
+    cid = {c.name: c.col_id for c in schema.columns}
+    cpu = make_engine("cpu", schema)
+    old = FLAGS.get("tpu_device_flush")
+    FLAGS.set("tpu_device_flush", False)
+    try:
+        tpus = [make_engine("tpu", schema, dict(rows_per_block=64))
+                for _ in range(2)]
+        rng = random.Random(5)
+        ht = 1000
+        for i in range(1200):
+            kv = {"l_orderkey": i // 4 + 1, "l_linenumber": i % 4 + 1}
+            hc = compute_hash_code(schema, kv)
+            key = schema.encode_primary_key(kv, hc)
+            for v in range(rng.randrange(1, 4)):
+                ht += 1
+                if v and rng.random() < 0.15:
+                    rv = RowVersion(key, ht=ht, tombstone=True)
+                else:
+                    rv = RowVersion(
+                        key, ht=ht, liveness=True,
+                        expire_ht=(ht + 900 if rng.random() < 0.2
+                                   else MAX_HT),
+                        columns={
+                            cid["l_quantity"]: rng.randrange(1, 51),
+                            cid["l_extendedprice"]: rng.randrange(
+                                90_000, 10_000_000),
+                            cid["l_discount"]: rng.randrange(0, 11),
+                            cid["l_tax"]: rng.randrange(0, 9),
+                            cid["l_returnflag"]: rng.choice("ANR"),
+                            cid["l_linestatus"]: rng.choice("FO"),
+                            cid["l_shipdate"]: rng.randrange(9000, 10600)})
+                tpus[hc * 2 >> 16].apply([rv])
+                cpu.apply([rv])
+        for e in tpus:
+            e.flush()
+    finally:
+        FLAGS.set("tpu_device_flush", old)
+    runs = [e.runs[0].crun for e in tpus]
+    assert any(r.max_group_versions > 1 for r in runs)
+    st = ShardedTablets(schema, runs, _mesh_of(4), window_blocks=2)
+    for rht in (ht + 1, ht - 700, ht + 5000):
+        for spec in (_pg_q1_spec(rht, 10471),
+                     _q6_spec(rht, 9131, 9900, 2, 8, 40)):
+            got = sharded_grouped_aggregate(st, spec, tpus[0])
+            assert got.rows == cpu.scan(spec).rows, rht
+            # (``scanned`` counts what the device resolve sees: the
+            # single-chip programs' own count, tablet by tablet)
+            assert got.rows_scanned == sum(
+                e.scan(spec).rows_scanned for e in tpus), rht
+
+
+@pytest.fixture
+def one_bucket(monkeypatch):
+    """Every key hashes to bucket 0 in programs traced from here on."""
+    import jax.numpy as jnp
+
+    from yugabyte_db_tpu.ops import group_agg
+    from yugabyte_db_tpu.parallel import sharded
+
+    sharded._compiled_dist_grouped.cache_clear()
+    monkeypatch.setattr(group_agg, "_bucket_hash",
+                        lambda planes: jnp.zeros_like(planes[0]))
+    yield
+    sharded._compiled_dist_grouped.cache_clear()
+
+
+def test_grouped_mesh_forced_collision_is_ineligible(lineitem, one_bucket):
+    """Two groups in one bucket: the program counts them, the host
+    throws its answer away (counted) and the caller's per-tablet path
+    gives the same answer the oracle does."""
+    from yugabyte_db_tpu.parallel import (GroupedIneligible,
+                                          sharded_grouped_aggregate)
+    from yugabyte_db_tpu.utils import metrics
+    from yugabyte_db_tpu.yql.pgsql.operations import combine_grouped
+
+    st = lineitem.stack(_mesh_of(4))
+    spec = _pg_q1_spec(lineitem.max_ht + 1, 10471)
+    before = metrics.grouped_agg_fallbacks()["collision"]
+    with pytest.raises(GroupedIneligible):
+        sharded_grouped_aggregate(st, spec, lineitem.tpus[0])
+    assert metrics.grouped_agg_fallbacks()["collision"] == before + 1
+    per_tablet = combine_grouped(spec, [e.scan(spec)
+                                        for e in lineitem.tpus])
+    assert per_tablet.rows == lineitem.cpu.scan(spec).rows
+
+
+def test_grouped_mesh_keys_that_differ_across_b_shards_collide(one_bucket):
+    """A bucket whose rows have one key in each "b" shard but ANOTHER key
+    in the next shard: no shard sees a collision alone; the combine over
+    "b" takes the key of the shard that holds ``rep`` and counts the
+    other shard's rows."""
+    from yugabyte_db_tpu.parallel import (GroupedIneligible,
+                                          sharded_grouped_aggregate)
+    from yugabyte_db_tpu.yql.pgsql import tpch
+
+    schema = tpch.lineitem_schema()
+    cid = {c.name: c.col_id for c in schema.columns}
+    rows = []
+    for i in range(512):
+        kv = {"l_orderkey": i + 1, "l_linenumber": 1}
+        rows.append(schema.encode_primary_key(
+            kv, compute_hash_code(schema, kv)))
+    rows.sort()
+    mem, cpu = MemTable(), make_engine("cpu", schema)
+    for pos, key in enumerate(rows):
+        rv = RowVersion(key, ht=100 + pos, liveness=True, columns={
+            cid["l_quantity"]: 1 + pos % 7, cid["l_extendedprice"]: 1000,
+            cid["l_discount"]: 1, cid["l_tax"]: 1,
+            # first half of the key space one group, second half another
+            cid["l_returnflag"]: "A" if pos < 256 else "N",
+            cid["l_linestatus"]: "F", cid["l_shipdate"]: 9000})
+        mem.apply([rv])
+        cpu.apply([rv])
+    run = ColumnarRun.build(schema, mem.drain_sorted(), 64)
+    mesh = _mesh_of(2)                      # (1, 2): 4 blocks a "b" shard
+    st = ShardedTablets(schema, [run], mesh, window_blocks=2)
+    assert st.Bl * st.R == 256
+    tpu = make_engine("tpu", schema, dict(rows_per_block=64))
+    spec = _pg_q1_spec(10_000, 10471)
+    with pytest.raises(GroupedIneligible):
+        sharded_grouped_aggregate(st, spec, tpu)
+
+
+def test_grouped_mesh_groups_straddle_b_shards(lineitem):
+    """Every group of Q1 has rows in both "b" shards of its tablet: the
+    tablet's table is the shards' tables added (psum, digit carries),
+    ``rep`` the least row of either."""
+    from yugabyte_db_tpu.parallel import sharded_grouped_aggregate
+
+    mesh = _mesh_of(2)
+    st = lineitem.stack(mesh)
+    assert mesh.shape["b"] == 2 and st.Bl * 2 == st.B
+    spec = _pg_q1_spec(lineitem.max_ht + 1, 10600)
+    got = sharded_grouped_aggregate(st, spec, lineitem.tpus[0])
+    assert got.rows == lineitem.cpu.scan(spec).rows
+    assert got.rows_scanned == sum(r.num_versions for r in lineitem.runs)
+
+
+def test_grouped_mesh_refuses_what_group_agg_does_not_lower(lineitem):
+    from yugabyte_db_tpu.parallel import (GroupedIneligible,
+                                          sharded_grouped_aggregate)
+
+    st = lineitem.stack(_mesh_of(4))
+    rht = lineitem.max_ht + 1
+    for spec in (
+            ScanSpec(read_ht=rht, group_by=["l_returnflag"],
+                     aggregates=[AggSpec("min", "l_quantity")]),
+            ScanSpec(read_ht=rht, group_by=["l_returnflag"],
+                     predicates=[Predicate("l_linestatus", "=", "F")],
+                     aggregates=[AggSpec("count", None)])):
+        with pytest.raises(GroupedIneligible):
+            sharded_grouped_aggregate(st, spec, lineitem.tpus[0])
+        # the per-tablet path answers it
+        from yugabyte_db_tpu.yql.pgsql.operations import combine_grouped
+
+        assert combine_grouped(spec, [e.scan(spec) for e in lineitem.tpus]
+                               ).rows == lineitem.cpu.scan(spec).rows

@@ -128,22 +128,32 @@ DDL = ("CREATE TABLE lineitem (l_orderkey BIGINT, l_linenumber INT, "
        "l_shipdate INT, PRIMARY KEY ((l_orderkey), l_linenumber))")
 
 
-def test_request_id_survives_frontend_pool_rpc_and_tserver(tmp_path):
+@pytest.mark.parametrize("chips", [1, 8])
+def test_request_id_survives_frontend_pool_rpc_and_tserver(tmp_path, chips,
+                                                           monkeypatch):
     """PG frontend -> pg-docop worker -> RPC payload ->
     TabletServer.handle: one /rpcz sample of the tserver holds the
-    engine's three phases under the id the frontend gave the statement."""
+    engine's three phases under the id the frontend gave the statement.
+    On a node with one chip that is one ``ts.scan`` a tablet; where the
+    tserver reports several and leads both tablets, ONE
+    ``ts.multi_agg_scan`` with the mesh's phases."""
     from yugabyte_db_tpu.drivers.minipg import PgConnection
     from yugabyte_db_tpu.integration import MiniCluster
     from yugabyte_db_tpu.tools.admin_client import AdminClient
+    from yugabyte_db_tpu.tserver.tablet_server import TabletServer
     from yugabyte_db_tpu.yql.pgsql import tpch
 
+    monkeypatch.setattr(TabletServer, "local_chips", lambda self: chips)
+    method, sent = (("ts.scan", 2) if chips == 1
+                    else ("ts.multi_agg_scan", 1))
     mc = MiniCluster(str(tmp_path), num_masters=1, num_tservers=1,
                      transport="socket").start()
     srv = None
     try:
         mc.wait_tservers_registered()
         srv, addr = mc.start_pg_server(engine="tpu", num_tablets=2,
-                                       replication_factor=1)
+                                       replication_factor=1,
+                                       rpc_timeout_s=120)
         conn = PgConnection(*addr, timeout=120)
         conn.execute(DDL)
         cols = ("l_orderkey", "l_linenumber", "l_quantity",
@@ -159,12 +169,13 @@ def test_request_id_survives_frontend_pool_rpc_and_tserver(tmp_path):
         waits = metrics.span_histogram("pg.scan_wait").count
         conn.execute(tpch.q6_sql())
         conn.close()
-        # two tablets, so two scans went through the pg-docop pool
-        assert metrics.span_histogram("pg.scan_wait").count == waits + 2
+        # a unit a scan: two tablets through the pg-docop pool, or the
+        # leader's group as one
+        assert metrics.span_histogram("pg.scan_wait").count == waits + sent
 
         front = trace.FRONTEND_RPCZ.dump()["methods"]["pg.statement"][-1]
         names = [s["name"] for s in front["spans"]]
-        assert names.count("pg.scan_wait") == 2 and "rpc.queue" in names
+        assert names.count("pg.scan_wait") == sent and "rpc.queue" in names
         ts = next(iter(mc.tservers.values()))
         addrs = mc.start_webservers()
         import urllib.request
@@ -174,18 +185,21 @@ def test_request_id_survives_frontend_pool_rpc_and_tserver(tmp_path):
                                     timeout=5) as r:
             rpcz = json.load(r)
         assert rpcz["frontends"]["methods"]["pg.statement"]
-        mine = [s for s in rpcz["methods"]["ts.scan"]
+        mine = [s for s in rpcz["methods"][method]
                 if s["trace_id"] == front["trace_id"]]
-        assert len(mine) == 2                  # one a tablet
+        assert len(mine) == sent
         for sample in mine:
             assert sample["parent_span"] == "pg.statement"
             spans = {s["name"]: s for s in sample["spans"]}
             for phase in PHASES:
-                assert spans["engine." + phase]["parent"] == "ts.scan"
+                assert spans["engine." + phase]["parent"] == method
                 assert spans["engine." + phase]["duration_us"] >= 0
+                if chips > 1:
+                    assert spans["engine." + phase]["route"] == "mesh"
             assert spans["rpc.queue"]["duration_us"] >= 0
         text = ts.metrics.prometheus_text()
-        assert 'rpc_queue_us_count{daemon="tserver",method="ts.scan"' in text
+        assert f'rpc_queue_us_count{{daemon="tserver",method="{method}"' \
+            in text
     finally:
         if srv is not None:
             srv.shutdown()
@@ -456,6 +470,8 @@ def _build_entries():
         "dist_agg": sharded._compiled_dist_agg(sig, mesh, ((), ()), 1, 4),
         "dist_page": sharded._compiled_dist_page(gather, mesh, ((), ()),
                                                  1, 4),
+        "dist_grouped_aggregate": sharded._compiled_dist_grouped(
+            gsig, mesh, ((), ()), 1, 4),
         "stack_update": sharded._compiled_stack_update(2, 8, 64, ()),
         "flat_aggregate": flat_fold.compiled_flat_aggregate(sig),
         "lookback_aggregate":

@@ -29,6 +29,8 @@ class TabletLocation:
     leader: str | None = None
     # replica uuid -> {"cloud", "region", "zone"} (zone-aware routing)
     replica_clouds: dict = field(default_factory=dict)
+    # replica uuid -> accelerator chips of its node (absent: 1)
+    replica_chips: dict = field(default_factory=dict)
 
     def contains(self, hash_code: int) -> bool:
         return self.partition_start <= hash_code < self.partition_end
@@ -72,6 +74,8 @@ class MetaCache:
                 t["tablet_id"], t["partition_start"], t["partition_end"],
                 [r["uuid"] for r in t["replicas"]], t.get("leader"),
                 {r["uuid"]: r.get("cloud_info") or {}
+                 for r in t["replicas"]},
+                {r["uuid"]: int(r.get("chips") or 1)
                  for r in t["replicas"]}))
         with self._lock:
             self._tables[table_name] = locs

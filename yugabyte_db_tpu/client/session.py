@@ -12,6 +12,7 @@ and recombined here — the cross-shard combine (CP analog) of SURVEY §2.4.
 
 from __future__ import annotations
 
+from yugabyte_db_tpu.client import mesh_route
 from yugabyte_db_tpu.client.client import YBClient, YBTable
 from yugabyte_db_tpu.models.datatypes import DataType
 from yugabyte_db_tpu.storage import rowblock, wire
@@ -478,12 +479,15 @@ class YBSession:
         # page as ONE ts.multi_row_scan group — the tserver runs them as
         # one device program (tserver.mesh_scan) and the cross-tablet
         # resume token stays opaque here. Consecutive-only keeps rows in
-        # partition (key) order; singleton or ineligible groups take the
+        # partition (key) order; singleton or ineligible groups, and the
+        # tablets of a node with one chip (mesh_route's rule), take the
         # per-tablet path below.
         groups: list[tuple[str | None, list]] = []
         for loc in locs.tablets:
-            leader = (loc.leader if (not stale_ok and not spec.group_by
-                                     and table.engine == "tpu") else None)
+            leader = (loc.leader if (
+                not stale_ok and not spec.group_by
+                and table.engine == "tpu"
+                and loc.replica_chips.get(loc.leader, 1) > 1) else None)
             if groups and leader is not None and groups[-1][0] == leader:
                 groups[-1][1].append(loc)
             else:
@@ -615,38 +619,24 @@ class YBSession:
                 gkey = tuple(row[:ngb])
                 groups.setdefault(gkey, []).append(list(row[ngb:]))
 
-        # Mesh path first: tablets grouped by leading tserver, ONE
-        # ts.multi_agg_scan per group — the tserver runs all its tablets
-        # as one device program with an ICI collective combine
-        # (tserver.mesh_scan). Any non-ok reply demotes that group to the
-        # per-tablet path below; the host combine here remains only the
-        # cross-tserver (and fallback) merge.
+        # Mesh path first (client/mesh_route.py): a leader's tablets on a
+        # node with several chips go as ONE ts.multi_agg_scan, GROUP BY
+        # and all — the tserver runs them as one device program and
+        # combines its tablets. Any non-ok reply demotes that group to
+        # the per-tablet path below; the host combine here remains only
+        # the cross-tserver (and fallback) merge.
         remaining_tablets = list(locs.tablets)
-        if not gb and table.engine == "tpu" and not stale_ok:
-            by_leader: dict[str, list] = {}
-            for loc in locs.tablets:
-                if loc.leader:
-                    by_leader.setdefault(loc.leader, []).append(loc)
-            for leader, group in by_leader.items():
-                if len(group) < 2:
-                    continue
+        if not stale_ok:
+            mesh_groups, _rest = mesh_route.leader_groups(locs.tablets,
+                                                          table.engine)
+            for leader, group in mesh_groups:
                 sub = ScanSpec(lower=spec.lower, upper=spec.upper,
                                read_ht=read_ht, predicates=spec.predicates,
-                               aggregates=partial_aggs)
-                try:
-                    # The caller's budget (see _mesh_row_pages), riding
-                    # server-side below the transport timeout so a slow
-                    # pin returns a clean timed_out.
-                    resp = self.client.transport.send(
-                        leader, "ts.multi_agg_scan",
-                        {"tablet_ids": [g.tablet_id for g in group],
-                         "spec": wire.encode_spec(sub),
-                         "timeout": max(0.05, round(timeout_s * 0.8, 3))},
-                        timeout=timeout_s)
-                except Exception as e:  # noqa: BLE001 — per-tablet fallback
-                    count_swallowed("session.multi_agg_scan", e)
-                    continue
-                if resp.get("code") != "ok":
+                               aggregates=partial_aggs,
+                               group_by=spec.group_by)
+                resp = mesh_route.multi_agg_scan(self.client, leader, group,
+                                                 sub, timeout_s)
+                if resp is None:
                     continue
                 consume(resp)
                 served = {g.tablet_id for g in group}

@@ -577,7 +577,8 @@ class Master:
                 "partition_end": info.partition_end,
                 "replicas": [
                     {"uuid": r, "addr": self.ts_manager.addr_of(r),
-                     "cloud_info": self.ts_manager.cloud_info_of(r)}
+                     "cloud_info": self.ts_manager.cloud_info_of(r),
+                     "chips": self.ts_manager.local_chips_of(r)}
                     for r in info.replicas
                 ],
                 "leader": self.ts_manager.leader_of(info.tablet_id),

@@ -23,6 +23,9 @@ class TSDescriptor:
     # Topology labels (reference: CloudInfoPB, master.proto:172):
     # {"cloud", "region", "zone"} — empty for unlabeled tservers.
     cloud_info: dict = field(default_factory=dict)
+    # Accelerator chips of the node (TabletServer.local_chips): clients
+    # group a leader's tablets into one mesh request only above one.
+    local_chips: int = 1
 
 
 class TSManager:
@@ -58,6 +61,7 @@ class TSManager:
                 self._descs[d.uuid] = d
             d.addr = req.get("addr")
             d.cloud_info = req.get("cloud_info") or {}
+            d.local_chips = int(req.get("local_chips") or 1)
             d.last_heartbeat = now
             d.num_live_tablets = req.get("num_live_tablets", 0)
             # Normalize roles at the ingestion boundary: raft reports
@@ -129,6 +133,11 @@ class TSManager:
         with self._lock:
             d = self._descs.get(uuid)
             return dict(d.cloud_info) if d else {}
+
+    def local_chips_of(self, uuid: str) -> int:
+        with self._lock:
+            d = self._descs.get(uuid)
+            return d.local_chips if d else 1
 
     def tablet_load(self, tablet_id: str) -> tuple[int, float]:
         """(size_bytes, ops_per_sec) from the leader's latest heartbeat

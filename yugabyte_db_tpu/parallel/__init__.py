@@ -11,6 +11,8 @@ combine that the reference does client-side becomes psum / two-plane
 lexicographic pmax over ICI (BASELINE config 5).
 """
 
-from yugabyte_db_tpu.parallel.sharded import (ShardedTablets,
+from yugabyte_db_tpu.parallel.sharded import (GroupedIneligible,
+                                              ShardedTablets,
                                               sharded_aggregate,
+                                              sharded_grouped_aggregate,
                                               sharded_row_page)

@@ -12,9 +12,13 @@ from __future__ import annotations
 import jax
 
 
-def shard_map(body, mesh, in_specs, out_specs):
+def shard_map(body, mesh, in_specs, out_specs, check_vma: bool = True):
+    """``check_vma=False`` for a body that holds a ``pallas_call``: the
+    interpreter that runs a kernel where the backend is no TPU does not
+    type its own values by mesh axis, so the body goes unchecked (and
+    needs no :func:`varying`)."""
     return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def varying(x, axes):

@@ -28,7 +28,9 @@ src/yb/docdb/pgsql_operation.cc:473).
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -37,10 +39,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from yugabyte_db_tpu.models.schema import Schema
 from yugabyte_db_tpu.ops import encodings
+from yugabyte_db_tpu.ops import group_agg
 from yugabyte_db_tpu.ops import row_gather as RG
 from yugabyte_db_tpu.ops import scan as dscan
 from yugabyte_db_tpu.parallel import meshcompat
-from yugabyte_db_tpu.utils import jitting
+from yugabyte_db_tpu.utils import jitting, metrics
 from yugabyte_db_tpu.utils.jitting import compile_contract
 from yugabyte_db_tpu.ops.agg_fold import (agg_init, check_limb_bound,
                                           finalize, fold_window, lower_aggs,
@@ -48,7 +51,8 @@ from yugabyte_db_tpu.ops.agg_fold import (agg_init, check_limb_bound,
 from yugabyte_db_tpu.ops.scan import I32_MAX, I32_MIN
 from yugabyte_db_tpu.storage.columnar import ColumnarRun
 from yugabyte_db_tpu.storage.residency import device_nbytes, hbm_cache
-from yugabyte_db_tpu.storage.scan_spec import ScanResult, ScanSpec
+from yugabyte_db_tpu.storage.scan_spec import (ScanResult, ScanSpec,
+                                                combine_grouped)
 from yugabyte_db_tpu.utils import planes as PL
 from yugabyte_db_tpu.utils.memtracker import root_tracker
 
@@ -89,7 +93,6 @@ def shard_dev_bytes(tree) -> dict:
 _ENC_SPEC_PARTS = {
     "bits": ("bw",),
     "delta16": ("dbase", "doff"),
-    "rle": ("rid", "rvals"),
     "dict": ("codes",),
 }
 
@@ -162,8 +165,21 @@ def _encode_stack(stacked):
     [T, B, ...]. Padding (invalid blocks / pad tablets) is already baked
     into the plain planes, so decode is byte-identical by construction.
     The stack-level encoder never emits dict leaves (those come from
-    per-run device flush output); pathological planes stay plain."""
+    per-run device flush output) and never "rle" leaves: an rle plane
+    decodes by a ``jnp.take``, and a gather is serialized on the TPU
+    (5.6 ms for a plane of 786K rows), in every program that reads the
+    plane; a table loaded in few large batches has its commit times in
+    exactly such long runs. Pathological planes stay plain."""
     T, B = stacked["valid"].shape[:2]
+
+    def int_plane(plane):
+        c = encodings.encode_const(plane)
+        return c if c is not None else encodings._pick_smaller(
+            plane, [encodings.encode_delta16(plane)])
+
+    def float_plane(plane):
+        c = encodings.encode_const(plane)
+        return plane if c is None else c
 
     def enc(plane, how):
         leaf = how(plane.reshape((T * B,) + plane.shape[2:]))
@@ -178,14 +194,14 @@ def _encode_stack(stacked):
     out = {n: enc(stacked[n], encodings.encode_bool_plane)
            for n in ("valid", "group_start", "tomb", "live")}
     for n in ("ht_hi", "ht_lo", "exp_hi", "exp_lo"):
-        out[n] = enc(stacked[n], encodings.encode_int_plane)
+        out[n] = enc(stacked[n], int_plane)
     out["cols"] = {}
     for cid, col in stacked["cols"].items():
         e = {"set": enc(col["set"], encodings.encode_bool_plane),
              "isnull": enc(col["isnull"], encodings.encode_bool_plane),
-             "cmp": enc(col["cmp"], encodings.encode_int_plane)}
+             "cmp": enc(col["cmp"], int_plane)}
         if "arith" in col:
-            e["arith"] = enc(col["arith"], encodings.encode_float_plane)
+            e["arith"] = enc(col["arith"], float_plane)
         out["cols"][cid] = e
     return out
 
@@ -229,6 +245,9 @@ class ShardedTablets:
             stacked = _encode_stack(stacked)
         self.enc_struct = _tree_struct(stacked)
         self.encoded = encodings.tree_encoded(stacked)
+        # grouped signature -> bytes one chip's shard holds of its planes
+        # (stack_read_bytes; update_tablet keeps the tree's structure)
+        self._read_bytes: dict = {}
         # Mesh placement must shard, not cache: plane-group residency for
         # sharded arrays is accounted (and pinned) via add_external below.
         self.arrays = jax.tree.map(
@@ -334,16 +353,23 @@ class ShardedTablets:
         def alloc(shape, dtype, fill=0):
             return np.full((T, B) + shape, fill, dtype=dtype)
 
+        def like(plane):
+            # Pad rows are invalid, so their times are never read: they
+            # take the first row's, and a plane that is one value in
+            # every run (no TTL anywhere) stays a "const" leaf.
+            return alloc((R,), np.int32,
+                         plane.flat[0] if plane.size else 0)
+
         out = {
             "valid": alloc((R,), bool, False),
             # pad rows are their own groups so they never join a real one
             "group_start": alloc((R,), bool, True),
             "tomb": alloc((R,), bool, False),
             "live": alloc((R,), bool, False),
-            "ht_hi": alloc((R,), np.int32),
-            "ht_lo": alloc((R,), np.int32),
-            "exp_hi": alloc((R,), np.int32),
-            "exp_lo": alloc((R,), np.int32),
+            "ht_hi": like(runs[0].ht_hi),
+            "ht_lo": like(runs[0].ht_lo),
+            "exp_hi": like(runs[0].exp_hi),
+            "exp_lo": like(runs[0].exp_lo),
             "cols": {},
         }
         for c in self.schema.value_columns:
@@ -475,6 +501,91 @@ def _compiled_dist_agg(sig: dscan.ScanSig, mesh: Mesh, enc_struct,
     return jitting.jit(smapped, "dist_agg", sig.tag())
 
 
+# -- the grouped program ------------------------------------------------------
+#
+# GROUP BY and expression aggregates (TPC-H Q1, Q6): the per-shard body
+# is ops.group_agg's window loop as the single-chip engine runs it, over
+# this shard's block range of each local tablet. Dictionaries, ``rep``
+# rows and string group values belong to ONE run, so bucket tables of
+# different tablets are never added on the device: across "b" (one
+# tablet's block ranges) the shards' partial tables are combined by
+# collectives, across "t" every tablet keeps its own packed vector
+# (``out_specs`` on "t") and the host finishes each with that tablet's
+# ColumnarRun, then combines as it combines per-tablet replies.
+
+def _combine_blocks(acc: dict, row_base):
+    """One tablet's partial bucket tables of the "b" shards -> the
+    tablet's table, replicated over "b". Sums by ``psum`` (digit
+    vectors carry-normalized again behind it: a shard's digits are under
+    2^17, so no int32 digit overflows below 2^13 shards); ``rep`` (local
+    to the shard's rows: ``row_base`` makes it the run's) by ``pmin``;
+    a bucket's key from the shard that holds its ``rep``, and a shard
+    whose rows of that bucket have another key counts them as
+    collisions, which send the host to the per-tablet path."""
+    out = {}
+    for name, v in acc.items():
+        if name in ("rep", "key"):
+            continue
+        v = jax.lax.psum(v, "b")
+        out[name] = group_agg._carry_norm(v) if v.ndim == 2 else v
+    has = acc["count"] > 0
+    rep = jnp.where(acc["rep"] == I32_MAX, I32_MAX, acc["rep"] + row_base)
+    out["rep"] = jax.lax.pmin(rep, "b")
+    mine = has & (rep == out["rep"])
+    out["key"] = jax.lax.psum(
+        jnp.where(mine[:, None], acc["key"], jnp.int32(0)), "b")
+    differs = has & jnp.any(acc["key"] != out["key"], axis=1)
+    out["collisions"] = out["collisions"] + jax.lax.psum(
+        jnp.sum(jnp.where(differs, acc["count"], jnp.int32(0))), "b")
+    return out
+
+
+def _grouped_body(sig: group_agg.GroupAggSig, Tl: int, Bl: int, run,
+                  params):
+    """Per device: ops.group_agg's program over each local tablet's
+    [Bl, R] shard. ``params[t]`` is the tablet's packed parameter vector
+    (group_agg.pack_params) with the RUN's row bounds; each shard
+    rebases them to its own block range and walks only the windows that
+    overlap (none where the range misses the shard)."""
+    n = group_agg.int_params(sig)
+    shard_rows = Bl * sig.R
+    KR = sig.K * sig.R
+    Wl = Bl // sig.K
+    row_base = jax.lax.axis_index("b") * shard_rows
+    layout = group_agg.out_layout(sig)
+    outs = []
+    for t in range(Tl):
+        p = params[t]
+        lo = jnp.clip(p[2] - row_base, 0, shard_rows)
+        hi = jnp.clip(p[3] - row_base, 0, shard_rows)
+        w_first = jnp.clip(lo // KR, 0, Wl - 1)
+        w_last = jnp.where(hi > lo, jnp.clip((hi - 1) // KR, 0, Wl - 1),
+                           w_first - 1)
+        ip = jnp.concatenate([jnp.stack([w_first, w_last, lo, hi]), p[4:n]])
+        acc = group_agg.grouped_aggregate(
+            sig, _tablet_slice(run, t), ip,
+            jax.lax.bitcast_convert_type(p[n:], jnp.float32))
+        acc = _combine_blocks(acc, row_base)
+        outs.append(jnp.concatenate(
+            [acc[name].reshape(-1) for name in layout]))
+    return jnp.stack(outs)
+
+
+@functools.lru_cache(maxsize=64)
+@compile_contract("dist_grouped_aggregate", max_compiles=64)
+def _compiled_dist_grouped(sig: group_agg.GroupAggSig, mesh: Mesh,
+                           enc_struct, Tl: int, Bl: int):
+    """One jitted shard_map program per (grouped signature, mesh, stack
+    encoding structure): ``(stack arrays, params i32[T, P + F]) ->
+    i32[T, L]``, a packed vector a tablet (group_agg.out_layout)."""
+    group_agg.check_window_bound(sig)
+    body = functools.partial(_grouped_body, sig, Tl, Bl)
+    smapped = meshcompat.shard_map(
+        body, mesh, (_specs_from_struct(enc_struct, P("t", "b")), P("t")),
+        P("t"), check_vma=False)  # (the kernel: see meshcompat)
+    return jitting.jit(smapped, "dist_grouped_aggregate", sig.tag())
+
+
 @functools.lru_cache(maxsize=32)
 @compile_contract("stack_update", max_compiles=32)
 def _compiled_stack_update(padded_T: int, B: int, R: int, cols_desc):
@@ -560,6 +671,90 @@ def sharded_aggregate(st: ShardedTablets, spec: ScanSpec) -> ScanResult:
         names.append(f"{a.fn}({a.column or '*'})")
         out_row.append(finalize(dev_aggs[di], acc[di], fn_name))
     return ScanResult(names, [tuple(out_row)], None, int(scanned))
+
+
+_FIRST_CALL = threading.Lock()
+
+
+class GroupedIneligible(ValueError):
+    """The grouped mesh program cannot answer this spec over this stack
+    exactly (nothing ops.group_agg lowers; a bucket collision, a
+    negative factor, an undecodable group): the per-tablet path does."""
+
+
+def stack_read_bytes(st: ShardedTablets, sig) -> int:
+    """Bytes ONE chip's shard of the stack holds of the planes ``sig``
+    names (ops.device_run.program_read_bytes over the stacked tree, by
+    the mesh's size: shards are padded to one size, so this is what the
+    fullest chip reads a dispatch). What a roofline sets against one
+    chip's module time; the whole stack would read mesh-size times it."""
+    from yugabyte_db_tpu.storage.tpu_engine import _sig_read_bytes
+
+    if sig not in st._read_bytes:
+        st._read_bytes[sig] = _sig_read_bytes(st.arrays, sig) // st.mesh.size
+    return st._read_bytes[sig]
+
+
+def sharded_grouped_aggregate(st: ShardedTablets, spec: ScanSpec, engine,
+                              phase=None) -> ScanResult:
+    """GROUP BY / expression aggregates over all tablets on the mesh, as
+    ONE device program (``jit_dist_grouped_aggregate_<tag>``) and ONE
+    fetch. ``engine`` is any TPU engine of the table's schema: it lowers
+    the spec to ops.group_agg's signature and finishes a tablet's packed
+    vector with that tablet's run, exactly as it does its own. Raises
+    :class:`GroupedIneligible` where the per-tablet path has to serve.
+    ``phase(name)``, where given, is a context manager around the three
+    phases (issue, wait_fetch, finish)."""
+    phase = phase or (lambda _name: contextlib.nullcontext())
+    with phase("issue"):
+        exact, superset, host_only = engine._split_predicates(spec)
+        if superset or host_only:
+            raise GroupedIneligible("predicates the device cannot decide")
+        sig0 = vecs = None
+        K = group_agg.window_blocks(st.Bl, st.R)
+        flat = all(r.max_group_versions <= 1 for r in st.runs)
+        for t, run in enumerate(st.runs):
+            low = engine._grouped_lower(run, spec, exact)
+            if low is None:
+                raise GroupedIneligible("not a signature group_agg lowers")
+            make_sig, int_lits, f32_lits = low
+            sig = make_sig(st.Bl, K, flat)
+            if sig0 is not None and sig != sig0:
+                raise GroupedIneligible("tablets lower to different "
+                                        "signatures")
+            ip, fp = RG.pack_params(
+                0, 0, run.lower_row(spec.lower), run.upper_row(spec.upper),
+                engine._read_plane_ints(spec), int_lits, f32_lits)
+            vec = group_agg.pack_params(sig, ip, fp)
+            if sig0 is None:
+                # (pad tablets keep zero bounds: their shards walk no
+                # window and give empty tables)
+                sig0, vecs = sig, np.zeros((st.padded_T, vec.size), np.int32)
+            vecs[t] = vec
+        Tl = st.padded_T // st.mesh.shape["t"]
+        fn = _compiled_dist_grouped(sig0, st.mesh, st.enc_struct, Tl, st.Bl)
+        if fn._cache_size():
+            out = fn(st.arrays, vecs)
+        else:
+            # The first requests after a flush come from every tserver
+            # of the process at once: one traces and compiles, the
+            # others find its program.
+            with _FIRST_CALL:
+                out = fn(st.arrays, vecs)
+        metrics.count_device_dispatch(
+            "dist_grouped_aggregate", stack_read_bytes(st, sig0), h2d=1,
+            d2h=1)
+    with phase("wait_fetch"):
+        out = jax.device_get(out)
+
+    def ineligible():
+        raise GroupedIneligible("the program's answer cannot be used")
+
+    with phase("finish"):
+        results = [engine._finish_grouped(run, spec, sig0, out[t],
+                                          ineligible)
+                   for t, run in enumerate(st.runs)]
+        return combine_grouped(spec, results)
 
 
 def _kind(c):

@@ -134,3 +134,40 @@ def point_key_of(spec: ScanSpec, schema=None) -> bytes | None:
         return None
     return full_doc_key_of(spec.lower, len(schema.hash_columns),
                            len(schema.range_columns))
+
+
+def combine_grouped(spec: ScanSpec, results: list[ScanResult]) -> ScanResult:
+    """Merge per-tablet grouped aggregate partials (sum/count add,
+    min/max extremize)."""
+    gb = spec.group_by or []
+    ngb = len(gb)
+    aggs = spec.aggregates or []
+    groups: dict[tuple, list] = {}
+    scanned = 0
+    for res in results:
+        scanned += res.rows_scanned
+        for row in res.rows:
+            gkey = tuple(row[:ngb])
+            acc = groups.get(gkey)
+            if acc is None:
+                groups[gkey] = list(row[ngb:])
+                continue
+            for i, a in enumerate(aggs):
+                v = row[ngb + i]
+                if v is None:
+                    continue
+                if acc[i] is None:
+                    acc[i] = v
+                elif a.fn in ("sum", "count"):
+                    acc[i] += v
+                elif a.fn == "min":
+                    acc[i] = min(acc[i], v)
+                elif a.fn == "max":
+                    acc[i] = max(acc[i], v)
+    if not groups and not gb:
+        groups[()] = [0 if a.fn == "count" else None for a in aggs]
+    rows = [tuple(g) + tuple(groups[g])
+            for g in sorted(groups, key=lambda g: tuple(
+                (v is None, v) for v in g))]
+    names = list(gb) + [a.output_name for a in aggs]
+    return ScanResult(names, rows, None, scanned)

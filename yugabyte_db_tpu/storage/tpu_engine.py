@@ -84,30 +84,14 @@ PAD_BLOCKS = 64            # run block-axis padding (multiple of every window)
 HOST_GC_MASK_MAX = 2_000_000
 
 
-# Round-robin cursor for --tpu_run_placement=round_robin (module-level:
-# placement balances across ALL engines in the process, which is the
-# point — one tserver, one local mesh).
-_PLACE_LOCK = threading.Lock()
-_PLACE_NEXT = 0
-
-
 def _place_run():
-    """The device a new run's planes will live on, per
-    --tpu_run_placement."""
-    from yugabyte_db_tpu.utils.flags import FLAGS
-
-    global _PLACE_NEXT
-    devs = jax.local_devices()
-    try:
-        policy = FLAGS.get("tpu_run_placement")
-    except KeyError:
-        policy = "default"
-    if policy != "round_robin" or len(devs) == 1:
-        return devs[0]
-    with _PLACE_LOCK:
-        d = devs[_PLACE_NEXT % len(devs)]
-        _PLACE_NEXT += 1
-    return d
+    """The device a run's own planes live on: the node's first chip,
+    where the single-chip programs run. A node with several chips
+    spreads a tablet by serving it through a mesh stack
+    (tserver/mesh_scan.py: the stack's shards are then the tablet's
+    device copy and this one is released), not by placing whole runs
+    on other chips."""
+    return jax.local_devices()[0]
 
 
 class TpuRun:
@@ -2703,17 +2687,16 @@ class TpuStorageEngine(StorageEngine):
         return (node.op, self._encode_factor(node.left),
                 self._encode_factor(node.right))
 
-    def _grouped_prep(self, trun: TpuRun, spec: ScanSpec, exact_preds):
-        """Device GROUP BY / expression aggregates (ops.group_agg) — the
-        TPC-H Q1/Q6 path. Host-side planning only: returns None when the
-        spec isn't device-lowerable (caller falls back), ("empty", plan)
-        for empty ranges, or ("params", (sig, params)) ready for a
-        single or vmapped-batch dispatch (``params``: the program's one
-        int32 vector, group_agg.pack_params)."""
-        from yugabyte_db_tpu.ops import group_agg, row_gather
+    def _grouped_lower(self, crun, spec: ScanSpec, exact_preds):
+        """A GROUP BY / expression-aggregate spec over ``crun`` as
+        ops.group_agg takes it: ``(make_sig, int_lits, f32_lits)``, with
+        ``make_sig(B, K, flat)`` the signature of a program over ``B``
+        blocks in windows of ``K`` (the run's own for the engine's
+        program, a mesh shard's for parallel.sharded's), or None where
+        the spec is not device-lowerable."""
+        from yugabyte_db_tpu.ops import group_agg
         from yugabyte_db_tpu.storage import expr as X
 
-        crun = trun.crun
         group_cols = []
         for name in (spec.group_by or []):
             cid = self._name_to_id.get(name)
@@ -2773,16 +2756,34 @@ class TpuStorageEngine(StorageEngine):
 
         pred_sigs = self._pred_sigs_only(exact_preds)
         int_lits, f32_lits = self._pred_host_literals(exact_preds)
+
+        def make_sig(B: int, K: int, flat: bool):
+            return group_agg.GroupAggSig(
+                B=B, R=crun.R, K=K, NB=group_agg.NUM_BUCKETS,
+                cols=self._col_sigs(), preds=pred_sigs, apply_preds=True,
+                flat=flat, group_cols=tuple(group_cols), aggs=tuple(gaggs))
+
+        return make_sig, int_lits, f32_lits
+
+    def _grouped_prep(self, trun: TpuRun, spec: ScanSpec, exact_preds):
+        """Device GROUP BY / expression aggregates (ops.group_agg) — the
+        TPC-H Q1/Q6 path. Host-side planning only: returns None when the
+        spec isn't device-lowerable (caller falls back), ("empty", plan)
+        for empty ranges, or ("params", (sig, params)) ready for a
+        single or vmapped-batch dispatch (``params``: the program's one
+        int32 vector, group_agg.pack_params)."""
+        from yugabyte_db_tpu.ops import group_agg, row_gather
+
+        crun = trun.crun
+        lowered = self._grouped_lower(crun, spec, exact_preds)
+        if lowered is None:
+            return None
+        make_sig, int_lits, f32_lits = lowered
         row_lo = crun.lower_row(spec.lower)
         row_hi = crun.upper_row(spec.upper)
         R = crun.R
         K = group_agg.window_blocks(trun.dev.B, R)
-        sig = group_agg.GroupAggSig(
-            B=trun.dev.B, R=R, K=K,
-            NB=group_agg.NUM_BUCKETS, cols=self._col_sigs(),
-            preds=pred_sigs, apply_preds=True,
-            flat=crun.max_group_versions <= 1,
-            group_cols=tuple(group_cols), aggs=tuple(gaggs))
+        sig = make_sig(trun.dev.B, K, crun.max_group_versions <= 1)
 
         if row_lo >= row_hi:
             agg = Aggregator(spec.aggregates, spec.group_by or [])

@@ -77,6 +77,7 @@ class Heartbeater:
             "ts_uuid": self.server.uuid,
             "addr": self.server.advertised_addr,
             "cloud_info": getattr(self.server, "cloud_info", None) or {},
+            "local_chips": self.server.local_chips(),
             "tablets": self.server.tablet_manager.tablet_reports(),
             "num_live_tablets": len(self.server.tablet_manager.peers()),
         }

@@ -8,8 +8,15 @@ batcher's thread-per-tablet row fan-out, src/yb/client/batcher.h:80),
 the tserver serves them with ONE device program: tablets sharded over
 the mesh "t" axis, each tablet's blocks over "b".
 
-- Aggregates: partials combined with psum / two-plane lexicographic
-  pmax over ICI (parallel.sharded.sharded_aggregate).
+- Aggregates: GROUP BY and expression aggregates (TPC-H Q1, Q6) run
+  ops.group_agg's window loop on every (tablet, block-range) shard
+  (parallel.sharded.sharded_grouped_aggregate): a tablet's partial
+  bucket tables are combined over "b" by collectives, tablets stay
+  apart (dictionaries and ``rep`` rows are per run) and the host
+  finishes and combines them as it does per-tablet replies. Plain
+  min/max/float aggregates that group_agg does not lower keep the
+  fold program, combined with psum / two-plane lexicographic pmax over
+  ICI (parallel.sharded.sharded_aggregate).
 - Row scans: the packed MVCC row gather runs on every (tablet,
   block-range) shard, per-device match counts psum over ICI, and the
   host decodes only the LIMIT page's rows
@@ -18,6 +25,12 @@ the mesh "t" axis, each tablet's blocks over "b".
 
 The client-side merge remains only as the cross-tserver / ineligible-
 spec fallback.
+
+Where a tablet's planes live: a tablet served through a stack has its
+device copy in the stack's shards, a share on every chip of the mesh;
+the per-run copy the engine's own programs would upload to the node's
+first chip is released when a stack is built over the run, and comes
+back by demand upload if a per-tablet scan needs it.
 
 Mesh policy: built once from the visible devices — "t" gets the larger
 factor (tablet parallelism is the dominant axis), "b" gets 2 when the
@@ -40,7 +53,16 @@ from __future__ import annotations
 import threading
 
 from yugabyte_db_tpu.storage.scan_spec import ScanResult, ScanSpec
+from yugabyte_db_tpu.utils import metrics, trace
 from yugabyte_db_tpu.utils.fault_injection import maybe_fault
+
+
+def _phase(name: str) -> trace.span:
+    """A phase of a mesh request, beside the per-tablet batches':
+    ``yb_engine_phase_us{phase, route="mesh"}``."""
+    return trace.span("engine." + name,
+                      metrics.engine_phase_histogram(name, "mesh"),
+                      route="mesh")
 
 
 class MeshScanService:
@@ -54,11 +76,18 @@ class MeshScanService:
         self._mesh = None
         self._stacks: dict[tuple, object] = {}
         self._max_cached = max_cached_stacks
+        # This server's own counts (a process may hold several); the
+        # series are yb_mesh_scans{kind, outcome} and
+        # yb_mesh_stack_builds{how} on the process registry.
         self.served = 0       # aggregates answered on the mesh
         self.served_rows = 0  # row pages answered on the mesh
         self.updated = 0      # stacks refreshed in place (update_tablet)
         self.fallbacks = 0    # ineligible requests bounced to per-tablet
         self.chip_losses = 0  # mesh dispatches lost to a dropped chip
+
+    def _ineligible(self, kind: str) -> None:
+        self.fallbacks += 1
+        metrics.count_mesh_scan(kind, "ineligible")
 
     def _get_mesh(self):
         if self._mesh is None:
@@ -121,20 +150,37 @@ class MeshScanService:
                     del self._stacks[okey]
                     self._stacks[key] = ost
                     self.updated += 1
+                    metrics.count_mesh_stack_build("update")
+                    self._release_run_copies(peers)
                     return ost
                 break
             from yugabyte_db_tpu.parallel import ShardedTablets
 
             schema = peers[0].tablet.meta.schema
-            try:
-                st = ShardedTablets(schema, runs, mesh)
-            except ValueError:
-                return None
+            with trace.span("mesh.stack_build", tablets=len(runs)) as sp:
+                try:
+                    st = ShardedTablets(schema, runs, mesh)
+                except ValueError:
+                    return None
+                sp.labels["encoded"] = st.encoded
+            metrics.count_mesh_stack_build("build")
             while len(self._stacks) >= self._max_cached:
                 old = self._stacks.pop(next(iter(self._stacks)))
                 old.close()  # release residency; in-flight scans finish
             self._stacks[key] = st
+            self._release_run_copies(peers)
             return st
+
+    @staticmethod
+    def _release_run_copies(peers: list) -> None:
+        """The stack's shards are these tablets' device copy from here
+        on: a per-run copy on the node's first chip (an earlier
+        per-tablet scan's upload, a device flush's output that has just
+        fed the stack) would hold the tablet a second time there."""
+        for p in peers:
+            trun = p.tablet.engine.runs[0]
+            if trun.peek_device() is not None:
+                trun.invalidate_device()
 
     def drop_stacks(self) -> int:
         """Release every cached stack's residency (chip loss / device
@@ -149,7 +195,7 @@ class MeshScanService:
             st.close()
         return len(stacks)
 
-    def _lost_chip(self) -> bool:
+    def _lost_chip(self, kind: str) -> bool:
         """The ``fault.mesh_dispatch`` point, evaluated right before a
         device dispatch: a fired fault models a mesh chip dropping out
         mid-scan. The service releases all stacked residency and bounces
@@ -160,6 +206,7 @@ class MeshScanService:
             return False
         self.chip_losses += 1
         self.fallbacks += 1
+        metrics.count_mesh_scan(kind, "chip_loss")
         self.drop_stacks()
         return True
 
@@ -171,25 +218,37 @@ class MeshScanService:
     def aggregate(self, peers: list, spec: ScanSpec) -> ScanResult | None:
         """Run spec's aggregates over all peers' tablets on the mesh.
         Returns None when ineligible (caller falls back to per-tablet
-        scans + host combine)."""
-        from yugabyte_db_tpu.parallel import sharded_aggregate
+        scans + host combine): an engine state the mesh has no stage
+        for, a spec neither program lowers, or a grouped answer the host
+        cannot use (a bucket collision, a negative factor)."""
+        from yugabyte_db_tpu.parallel import (sharded_aggregate,
+                                              sharded_grouped_aggregate)
 
-        if not spec.is_aggregate or spec.group_by:
-            self.fallbacks += 1
+        if not spec.is_aggregate:
+            self._ineligible("agg")
             return None
         runs = self._eligible_runs(peers, spec)
         st = self._get_stack(peers, runs) if runs else None
         if st is None:
-            self.fallbacks += 1
+            self._ineligible("agg")
             return None
-        if self._lost_chip():
+        if self._lost_chip("agg"):
             return None
+        grouped = spec.group_by or any(a.expr is not None
+                                       for a in spec.aggregates)
         try:
-            res = sharded_aggregate(st, spec)
-        except ValueError:
-            self.fallbacks += 1
+            if grouped:
+                # (as the engine's own planner: GROUP BY and expression
+                # aggregates are ops.group_agg's, the rest the folds')
+                res = sharded_grouped_aggregate(
+                    st, spec, peers[0].tablet.engine, phase=_phase)
+            else:
+                res = sharded_aggregate(st, spec)
+        except ValueError:  # (GroupedIneligible is one)
+            self._ineligible("agg")
             return None  # spec not device-exact: fallback
         self.served += 1
+        metrics.count_mesh_scan("agg", "served")
         return res
 
     def rows(self, peers: list, spec: ScanSpec,
@@ -202,19 +261,20 @@ class MeshScanService:
         from yugabyte_db_tpu.parallel import sharded_row_page
 
         if spec.is_aggregate or spec.group_by:
-            self.fallbacks += 1
+            self._ineligible("rows")
             return None
         runs = self._eligible_runs(peers, spec)
         st = self._get_stack(peers, runs) if runs else None
         if st is None:
-            self.fallbacks += 1
+            self._ineligible("rows")
             return None
-        if self._lost_chip():
+        if self._lost_chip("rows"):
             return None
         try:
             res = sharded_row_page(st, spec, resume=resume)
         except ValueError:
-            self.fallbacks += 1
+            self._ineligible("rows")
             return None  # spec not device-exact: fallback
         self.served_rows += 1
+        metrics.count_mesh_scan("rows", "served")
         return res

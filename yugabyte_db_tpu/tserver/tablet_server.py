@@ -1329,6 +1329,21 @@ class TabletServer:
         self.txn_notifier.trigger()
         return {"code": "ok"}
 
+    def local_chips(self) -> int:
+        """Accelerator chips this node's TPU engines can place planes on:
+        what the heartbeat tells the master, and the master the clients,
+        which send a leader's tablets as ONE mesh request only where this
+        is above one. 1 on a server that runs no JAX (a CPU-engine
+        daemon never imports it)."""
+        import sys
+
+        jax = sys.modules.get("jax")
+        if jax is None or not any(
+                hasattr(p.tablet.engine, "breaker")
+                for p in self.tablet_manager.peers()):
+            return 1
+        return len(jax.local_devices())
+
     def _multi_scan_peers(self, p: dict):
         """Shared front half of the multi-tablet mesh scan handlers:
         gather the named peers (all must be leaders holding leases on

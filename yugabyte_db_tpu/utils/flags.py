@@ -90,12 +90,6 @@ FLAGS.define("tpu_hbm_budget_bytes", 0,
              "policy (reference: rocksdb/util/cache.cc high-pri/low-pri "
              "split). Each mesh chip gets its own bucket of this size",
              ("evolving", "runtime"))
-FLAGS.define("tpu_run_placement", "default",
-             "which device a tablet's run planes live on: 'default' = "
-             "jax's default device (single-chip behavior), "
-             "'round_robin' = spread runs across the local mesh so "
-             "per-device HBM budgets are actually load-balanced",
-             ("evolving", "runtime"))
 FLAGS.define("global_memstore_limit_bytes", 1 << 40,
              "process-wide memtable budget; crossing it flushes the "
              "engine that noticed (reference: the shared memory_monitor "

@@ -815,3 +815,40 @@ def grouped_presence() -> dict[str, int]:
 
 
 grouped_presence()
+
+
+MESH_SCAN_KINDS = ("agg", "rows")
+MESH_SCAN_OUTCOMES = ("served", "ineligible", "chip_loss")
+
+
+def _mesh_scan_counter(kind: str, outcome: str) -> Counter:
+    return _span_entity(("mesh_scan", kind, outcome), kind=kind,
+                        outcome=outcome).counter("yb_mesh_scans")
+
+
+def count_mesh_scan(kind: str, outcome: str) -> None:
+    """``yb_mesh_scans{kind=agg|rows, outcome}``: one multi-tablet
+    request at a tserver's mesh (tserver/mesh_scan.py): ``served`` as
+    one device program over the node's chips, ``ineligible`` (an engine
+    state or a spec the mesh cannot answer exactly: the client's
+    per-tablet path serves it) or ``chip_loss`` (the dispatch lost a
+    chip; the stacks were dropped and the per-tablet path serves)."""
+    _mesh_scan_counter(kind, outcome).increment()
+
+
+def mesh_scans() -> dict[tuple[str, str], int]:
+    """Current ``yb_mesh_scans`` by (kind, outcome)."""
+    return {(k, o): _mesh_scan_counter(k, o).get()
+            for k in MESH_SCAN_KINDS for o in MESH_SCAN_OUTCOMES}
+
+
+mesh_scans()
+
+
+def count_mesh_stack_build(how: str) -> None:
+    """``yb_mesh_stack_builds{how=build|update}``: a tserver stacked its
+    tablets' runs over the mesh anew (``build``: host stack, encode,
+    sharded upload; span ``mesh.stack_build``) or rewrote one tablet's
+    slot of a cached stack in place (``update``)."""
+    _span_entity(("mesh_stack", how), how=how).counter(
+        "yb_mesh_stack_builds").increment()

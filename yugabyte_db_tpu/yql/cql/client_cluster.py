@@ -10,12 +10,14 @@ as tserver RPCs through the client's MetaCache + TabletInvoker."""
 
 from __future__ import annotations
 
+from yugabyte_db_tpu.client import mesh_route
 from yugabyte_db_tpu.client.client import YBClient
 from yugabyte_db_tpu.models.partition import PartitionSchema
 from yugabyte_db_tpu.models.schema import Schema
 from yugabyte_db_tpu.storage import wire
 from yugabyte_db_tpu.storage.row_version import RowVersion
-from yugabyte_db_tpu.storage.scan_spec import ScanResult, ScanSpec
+from yugabyte_db_tpu.storage.scan_spec import (ScanResult, ScanSpec,
+                                                combine_grouped)
 from yugabyte_db_tpu.utils.hybrid_time import HybridClock, HybridTime
 from yugabyte_db_tpu.utils.status import AlreadyPresent, NotFound
 
@@ -92,13 +94,48 @@ class RemoteTablet:
         return pages
 
 
+class RemoteTabletGroup:
+    """The tablets of one table that one tserver leads on a node with
+    several chips, as ONE aggregate read: ``ts.multi_agg_scan``
+    (client/mesh_route.py), the tserver's single device program over its
+    chips, its tablets already combined in the reply. Duck-types
+    RemoteTablet's read surface for the PG executor's aggregate path; a
+    reply that is not ``ok`` demotes the group to one ``ts.scan`` a
+    tablet, combined here."""
+
+    def __init__(self, client: YBClient, table_name: str, leader: str,
+                 locs: list):
+        self.client = client
+        self.table_name = table_name
+        self.leader = leader
+        self.locs = locs
+
+    def read_time(self) -> HybridTime:
+        # MAX: the tserver pins ONE read point across the group's
+        # tablets (the least of their safe times) and returns it.
+        return HybridTime.max()
+
+    def scan(self, spec: ScanSpec) -> ScanResult:
+        resp = mesh_route.multi_agg_scan(
+            self.client, self.leader, self.locs, spec,
+            self.client.default_rpc_timeout_s)
+        if resp is not None:
+            res = wire.decode_result(resp)
+            res.read_ht = resp.get("read_ht")
+            return res
+        return combine_grouped(spec, [
+            RemoteTablet(self.client, self.table_name, loc).scan(spec)
+            for loc in self.locs])
+
+
 class RemoteTable:
     def __init__(self, client: YBClient, name: str, schema: Schema,
-                 indexes: list | None = None):
+                 indexes: list | None = None, engine: str = "cpu"):
         self.client = client
         self.name = name
         self.schema = schema
         self.indexes = list(indexes or [])
+        self.engine = engine    # the table's storage engine, as created
         self.partition_schema = PartitionSchema(
             1, hash_partitioned=schema.num_hash > 0)  # routing via MetaCache
 
@@ -107,6 +144,17 @@ class RemoteTable:
         locs = self.client.meta_cache.locations(self.name)
         return [RemoteTablet(self.client, self.name, loc)
                 for loc in locs.tablets]
+
+    def aggregate_units(self) -> list:
+        """What an aggregate over the whole table is sent to: a
+        RemoteTabletGroup for each leader that takes its tablets as one
+        mesh request (mesh_route.leader_groups' rule), a RemoteTablet
+        for every other tablet."""
+        locs = self.client.meta_cache.locations(self.name)
+        groups, rest = mesh_route.leader_groups(locs.tablets, self.engine)
+        return [RemoteTabletGroup(self.client, self.name, leader, g)
+                for leader, g in groups] + [
+            RemoteTablet(self.client, self.name, loc) for loc in rest]
 
 
 class ClientCluster:
@@ -164,7 +212,7 @@ class ClientCluster:
             if "already_present" in str(e):
                 raise AlreadyPresent(f"table {name} exists") from e
             raise
-        t = RemoteTable(self.client, name, schema)
+        t = RemoteTable(self.client, name, schema, engine=self.engine)
         self._tables[name] = t
         return t
 
@@ -187,7 +235,8 @@ class ClientCluster:
                 raise NotFound(f"table {name} not found")
             t = RemoteTable(self.client, name,
                             Schema.from_dict(resp["schema"]),
-                            resp.get("indexes"))
+                            resp.get("indexes"),
+                            engine=resp.get("engine", "cpu"))
             self._tables[name] = t
         return t
 

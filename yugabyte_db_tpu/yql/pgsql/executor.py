@@ -2005,10 +2005,16 @@ class PgProcessor:
 
         spec = ScanSpec(read_ht=MAX_HT, predicates=preds,
                         aggregates=aggs, group_by=group_by or None)
-        # Per-tablet partial aggregates with PgDocOp-style prefetching:
-        # every tablet's scan is in flight while partials combine.
+        # Partial aggregates with PgDocOp-style prefetching: every unit's
+        # read is in flight while partials combine. A unit is a tablet
+        # (ts.scan) or, where a tserver with several chips leads two or
+        # more of the table's tablets, that leader's group as ONE
+        # ts.multi_agg_scan (client/mesh_route.py; the tserver combines
+        # its tablets on its mesh).
+        units = (handle.aggregate_units() if self._txn is None
+                 and hasattr(handle, "aggregate_units") else handle.tablets)
         results = [res for _t, res in self._prefetch_scans(
-            handle.tablets,
+            units,
             lambda t: ScanSpec(read_ht=self._read_ht(t),
                                predicates=preds, aggregates=aggs,
                                group_by=group_by or None))]
