@@ -22,10 +22,12 @@ from yugabyte_db_tpu.storage.row_version import RowVersion
 
 
 def _load(num=3000, seed=7, with_nulls=True, negatives=False,
-          versions=1, host_flush=False):
+          versions=1, host_flush=False, null_groups=False):
     """``host_flush``: the run is built on the host and uploaded encoded
     ("bits" presence leaves, as the benchmark's tables are); a device
-    flush leaves plain planes."""
+    flush leaves plain planes but for the string columns' dictionaries.
+    ``null_groups``: a tenth of the versions write NULL into ``status``
+    and a tenth leave ``flag`` unset."""
     schema = Schema([
         ColumnSchema("k", DataType.STRING, ColumnKind.HASH),
         ColumnSchema("flag", DataType.STRING),       # 1-char, Q1-like
@@ -60,6 +62,10 @@ def _load(num=3000, seed=7, with_nulls=True, negatives=False,
             }
             if with_nulls and rng.random() < 0.05:
                 cols[cid["qty"]] = None
+            if null_groups and rng.random() < 0.1:
+                cols[cid["status"]] = None
+            if null_groups and rng.random() < 0.1:
+                del cols[cid["flag"]]
             rv = RowVersion(key, ht=ht, liveness=True, columns=cols)
             cpu.apply([rv])
             tpu.apply([rv])
@@ -959,11 +965,14 @@ def one_described_v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.mark.parametrize("form", ["hashed", "direct"])
 def test_q1_compiles_for_the_v5e_with_one_relayout_of_its_masks(
-        one_described_v5e, monkeypatch):
+        one_described_v5e, monkeypatch, form):
     """TPC-H Q1's signature at the benchmark's shape (384 blocks of 2,048
     rows, 16 columns, "bits" presence leaves, delta16 and plain value
-    planes), compiled by the TPU's own compiler: Mosaic takes the kernel,
+    planes; ``direct``: the two group columns as the "dict" leaves of 4
+    slots a served run holds, so 16 buckets under the same name),
+    compiled by the TPU's own compiler: Mosaic takes the kernel,
     and in the optimized module no bool plane is laid out by rows, the
     packed masks are laid out ONCE (one copy of an int32 ``[384, 64,
     32]``), and nothing beside the kernel has the mask word's producer
@@ -1012,6 +1021,16 @@ def test_q1_compiles_for_the_v5e_with_one_relayout_of_its_masks(
                                             "doff": S((K, R, 1), jnp.uint16)}}
                         if k == "i32" else S((K, R, 2))}
                     for c, k in kinds.items()}}
+    if form == "direct":
+        for c, _planes in sig.group_cols:
+            run["cols"][c]["cmp"] = {"dict": {
+                "codes": S((K, R), jnp.uint16), "dhi": S((4,)),
+                "dlo": S((4,))}}
+        sig = group_agg.addressed(sig, run)
+        assert (sig.NB, sig.radix) == (16, (4, 4))
+        assert sig.tag() == "g2a9p1f1_d1ea91"     # the name does not move
+    else:
+        assert group_agg.addressed(sig, run) == sig
     before = _presence_counts()
     text = jax.jit(functools.partial(group_agg._packed, sig)).lower(
         run, S((group_agg.int_params(sig),))).compile().as_text()
@@ -1024,6 +1043,128 @@ def test_q1_compiles_for_the_v5e_with_one_relayout_of_its_masks(
     assert len(relayouts) == 1, relayouts
     assert not [ln for ln in top if re.match(
         r"  %\S+ = s32\[786432\]\S* reshape\(%broadcast", ln)]
+    # (the dictionaries' prefix planes are looked up nowhere)
+    assert " gather(" not in text
+
+
+# -- the buckets by dictionary code (PR 38) --------------------------------------
+
+def _leaf_of_cap(cap):
+    """A group column's ``cmp`` leaf as a dictionary of ``cap`` slots
+    (0: a plain plane), by shapes alone: what ``addressed`` looks at."""
+    if not cap:
+        return np.zeros((2, 32, 1), np.int32)
+    return {"dict": {"codes": np.zeros((2, 32), np.uint16),
+                     "dhi": np.zeros(cap, np.int32),
+                     "dlo": np.zeros(cap, np.int32)}}
+
+
+ADDRESSING = {
+    # the group columns' dictionary caps (0: no dictionary) -> (NB, radix)
+    "q1s_two_dictionaries": ((4, 4), (16, (4, 4))),
+    "one_dictionary": ((8,), (8, (8,))),
+    "a_dictionary_and_an_integer_column": ((4, 0), (512, ())),
+    "an_integer_column_alone": ((0,), (512, ())),
+    "a_product_of_512_is_direct": ((32, 16), (512, (32, 16))),
+    "a_product_of_1024_is_hashed": ((32, 32), (512, ())),
+    "three_dictionaries": ((2, 4, 8), (64, (2, 4, 8))),
+    "no_group_column": ((), (512, ())),
+}
+
+
+@pytest.mark.parametrize("case", list(ADDRESSING))
+def test_the_leaves_of_the_group_columns_decide_the_bucket_form(case):
+    """``addressed``: direct iff EVERY group column's ``cmp`` leaf is a
+    "dict" leaf and the product of the caps is within NUM_BUCKETS; the
+    table then has that product's buckets. Nothing of it is in the name,
+    and a hashed signature over the same leaves comes back the same."""
+    import dataclasses
+
+    from yugabyte_db_tpu.ops import group_agg, scan
+
+    caps, (NB, radix) = ADDRESSING[case]
+    run = {"cols": {10 + i: {"cmp": _leaf_of_cap(cap)}
+                    for i, cap in enumerate(caps)}}
+    sig = group_agg.GroupAggSig(
+        B=2, R=32, K=2, NB=group_agg.NUM_BUCKETS,
+        cols=tuple(scan.ColSig(10 + i, "str" if cap else "i32")
+                   for i, cap in enumerate(caps)),
+        preds=(), apply_preds=True, flat=True,
+        group_cols=tuple((10 + i, 2 if cap else 1)
+                         for i, cap in enumerate(caps)),
+        aggs=(group_agg.GAgg("count", None),))
+    got = group_agg.addressed(sig, run)
+    assert (got.NB, got.radix) == (NB, radix)
+    assert got.tag() == sig.tag()
+    assert group_agg.addressed(got, run) == got
+    assert dataclasses.replace(got, NB=sig.NB, radix=()) == sig
+    if radix:
+        # a bucket's codes are its mixed-radix digits, first column first
+        assert group_agg.bucket_codes(got, NB - 1) == [c - 1 for c in radix]
+        assert group_agg.bucket_codes(got, radix[-1]) == (
+            [0] * (len(radix) - 2) + [1, 0] if len(radix) > 1 else [0])
+        # leaves of another cap are another program: refused where traced
+        other = {"cols": {c: {"cmp": _leaf_of_cap(2 * cap)} for c, cap in
+                          zip(run["cols"], radix)}}
+        with pytest.raises(ValueError, match="addresses its buckets"):
+            group_agg.grouped_aggregate(got, other, None, None)
+
+
+def _golden_case():
+    """Three blocks of 64 flat rows, no random number in them: group
+    column 1 a "dict" leaf (A, N, R and the absent slot, NULL every 11th
+    row), group column 4 an int32 (row % 2), base column 2 = 10^12 + row,
+    narrow column 3 = row % 100 (NULL every 7th row), rows [1, 190)."""
+    from yugabyte_db_tpu.ops import encodings, group_agg, row_gather, scan
+    from yugabyte_db_tpu.utils import planes as P
+
+    K, R = 3, 64
+    rows = np.arange(K * R, dtype=np.int64)
+    i32 = np.iinfo(np.int32)
+    hi, lo = P.varlen_prefix_planes([b"A", b"N", b"R"])
+    dhi, dlo = np.zeros(4, np.int32), np.zeros(4, np.int32)
+    dhi[:3], dlo[:3] = hi, lo
+    null1 = rows % 11 == 0
+    bh, bl = P.i64_to_ordered_planes(10**12 + rows)
+    run = _flat_run(K, R, {
+        1: (encodings.dict_leaf(np.where(null1, 3, rows % 3).reshape(K, R),
+                                dhi, dlo), null1),
+        2: (np.stack([bh, bl], -1).reshape(K, R, 2).astype(np.int32), None),
+        3: ((rows % 100).astype(np.int32).reshape(K, R, 1), rows % 7 == 0),
+        4: ((rows % 2).astype(np.int32).reshape(K, R, 1), None)})
+    sig = group_agg.GroupAggSig(
+        B=K, R=R, K=K, NB=512,
+        cols=(scan.ColSig(1, "str"), scan.ColSig(2, "i64"),
+              scan.ColSig(3, "i32"), scan.ColSig(4, "i32")),
+        preds=(), apply_preds=True, flat=True, group_cols=((1, 2), (4, 1)),
+        aggs=(group_agg.GAgg("sum_prod", 2, planes=2,
+                             factors=(("+", ("k", 1), ("c", 3)),),
+                             need_cols=(2, 3)),
+              group_agg.GAgg("count", 3, need_cols=(3,)),
+              group_agg.GAgg("count", None)))
+    ip, fp = row_gather.pack_params(
+        0, 0, 1, 190, (i32.max, i32.max, i32.min, i32.min), [], [])
+    return sig, run, group_agg.pack_params(sig, ip, fp)
+
+
+def test_the_hashed_forms_vector_is_its_parents_bit_for_bit():
+    """The packed result of the hashed form over a run with a "dict" leaf
+    and an integer group column, against the sha256 of the vector PR 38's
+    parent (5062ecb) gives for the same planes: the direct form was put
+    beside the hashed one, not into it."""
+    import hashlib
+
+    from yugabyte_db_tpu.ops import group_agg
+
+    sig, run, params = _golden_case()
+    assert group_agg.addressed(sig, run) == sig        # hashed it stays
+    vec = np.asarray(group_agg.compiled_grouped(sig)(run, params))
+    assert vec.size == 9219 and np.count_nonzero(vec) == 595
+    assert hashlib.sha256(vec.tobytes()).hexdigest() == (
+        "83f11a01052f8f34a1cb8aa982fd28cae6387d42031f4d4fa9203a6d252277c3")
+    out = group_agg.unpack(sig, vec)
+    assert int(out["collisions"]) == 0 and int(out["scanned"]) == 189
+    assert len(out["count"].nonzero()[0]) == 8      # (A, N, R, NULL) x 2
 
 
 # -- the jit boundary (PR 28): one vector in, one vector out --------------------
@@ -1126,8 +1267,12 @@ def test_out_layout_is_a_pure_function_of_the_signature():
         dataclasses.replace(sig, B=sig.B * 4, R=128, K=2, preds=()))
     # back to back, in grouped_aggregate's documented order, every
     # output there and nothing else
-    assert list(layout)[:6] == ["count", "rep", "key", "collisions",
-                                "scanned", "negs"]
+    assert sig.radix == (4, 4) and sig.NB == 16      # the direct form
+    assert list(layout)[:4] == ["count", "collisions", "scanned", "negs"]
+    hashed = dataclasses.replace(sig, NB=group_agg.NUM_BUCKETS, radix=())
+    assert list(group_agg.out_layout(hashed))[:6] == [
+        "count", "rep", "key", "collisions", "scanned", "negs"]
+    assert set(group_agg.out_layout(hashed)) == set(layout) | {"rep", "key"}
     assert set(layout) == set(want[0])
     off = 0
     for name, (o, shape) in layout.items():
@@ -1136,8 +1281,8 @@ def test_out_layout_is_a_pure_function_of_the_signature():
     # the parameter vector: row_gather's nine, the literals' ints, then
     # the float literals' bits
     assert group_agg.int_params(sig) == 9 + 1 and params[0].size == 11
-    assert group_agg.out_layout(dataclasses.replace(sig, group_cols=()))[
-        "key"] == (2 * sig.NB, (sig.NB, 1))
+    assert group_agg.out_layout(dataclasses.replace(hashed, group_cols=()))[
+        "key"] == (2 * hashed.NB, (hashed.NB, 1))
     with pytest.raises(ValueError, match="int parameters"):
         group_agg.pack_params(sig, np.zeros(3, np.int32),
                               np.zeros(1, np.float32))

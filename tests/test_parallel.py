@@ -608,6 +608,43 @@ def test_grouped_mesh_traces_the_packed_presence_form_on_an_encoded_stack(
     assert after["rows"] == before["rows"]
 
 
+def test_grouped_mesh_stays_hashed_at_num_buckets(lineitem, monkeypatch):
+    """A stack holds no "dict" leaf (its encoder drops the code plane),
+    so the mesh's signature is not addressed by dictionary codes: NB
+    stays NUM_BUCKETS whatever the tablets' own runs hold, and a mesh
+    dispatch of Q1 counts in ``yb_grouped_buckets{form="hashed"}``, one
+    of Q6 (no group column, no buckets) in neither form."""
+    from yugabyte_db_tpu.ops import group_agg
+    from yugabyte_db_tpu.parallel import sharded, sharded_grouped_aggregate
+    from yugabyte_db_tpu.utils import metrics
+
+    st = lineitem.stack(_mesh_of(4), True)
+    planes, cols = st.enc_struct
+    assert "dict" not in [k for _n, k in planes] + [
+        k for _c, e in cols for _n, k in e]
+    sigs = []
+    compiled = sharded._compiled_dist_grouped
+
+    def recording(sig, *rest):
+        sigs.append(sig)
+        return compiled(sig, *rest)
+
+    monkeypatch.setattr(sharded, "_compiled_dist_grouped", recording)
+    rht = lineitem.max_ht + 1
+    before = metrics.grouped_buckets()
+    sharded_grouped_aggregate(st, _pg_q1_spec(rht, 10471), lineitem.tpus[0])
+    assert metrics.grouped_buckets() == dict(before,
+                                             hashed=before["hashed"] + 1)
+    sharded_grouped_aggregate(st, _q6_spec(rht, **Q6_PARAMS),
+                              lineitem.tpus[0])
+    assert metrics.grouped_buckets() == dict(before,
+                                             hashed=before["hashed"] + 1)
+    q1, q6 = sigs
+    assert q1.group_cols and not q6.group_cols
+    assert q1.NB == q6.NB == group_agg.NUM_BUCKETS and q1.radix == ()
+    assert group_agg.addressed(q1, st.arrays) == q1
+
+
 def test_grouped_mesh_bounded_range_and_empty_tablet_range(lineitem):
     """Row bounds are rebased to each "b" shard; a range that misses a
     shard (or a whole tablet) walks no window there."""

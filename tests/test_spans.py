@@ -341,6 +341,55 @@ def test_a_grouped_batch_moves_one_array_each_way(n_specs, entry):
     assert [r.rows for r in got] == [cpu.scan(s).rows for s in specs]
 
 
+@pytest.mark.parametrize("encoding,form", [("auto", "direct"),
+                                           ("off", "hashed")])
+def test_a_q1_shaped_batch_counts_its_bucket_form_once_a_dispatch(
+        encoding, form):
+    """``yb_grouped_buckets{form}`` beside ``yb_device_dispatches``: a
+    GROUP BY over two string columns is addressed by their dictionary
+    codes where the resident run holds "dict" leaves (uploaded encoded)
+    and hashed where it holds plain prefix planes (--tpu_plane_encoding
+    off); either way one array goes up, one program runs, one array
+    comes down, and the answer is the CPU engine's. An ungrouped
+    expression aggregate (Q6's shape) counts in neither form."""
+    from tests.test_group_agg import Q1_AGGS, Q6_AGGS
+    from tests.test_group_agg import _load as load_lineitem_like
+    from yugabyte_db_tpu.ops import encodings
+    from yugabyte_db_tpu.utils.flags import FLAGS
+
+    old = FLAGS.get("tpu_plane_encoding")
+    FLAGS.set("tpu_plane_encoding", encoding)
+    try:
+        cpu, tpu, ht = load_lineitem_like(num=400, host_flush=True)
+        spec = ScanSpec(read_ht=ht + 1, group_by=["flag", "status"],
+                        aggregates=list(Q1_AGGS),
+                        predicates=[Predicate("d", "<", 900)])
+        q6 = ScanSpec(read_ht=ht + 1, aggregates=list(Q6_AGGS),
+                      predicates=[Predicate("qty", "<", 25)])
+        tpu.scan_batch([spec])    # compiled and resident
+        cmp = tpu.runs[0].dev.arrays["cols"][
+            tpu._name_to_id["flag"]]["cmp"]
+        assert encodings.leaf_kind(cmp) == (
+            "dict" if form == "direct" else None)
+        before, moved = metrics.grouped_buckets(), _moved("grouped_aggregate")
+        fallbacks = metrics.grouped_agg_fallbacks()
+        got = tpu.scan_batch([spec])
+        assert metrics.grouped_buckets() == dict(before,
+                                                 **{form: before[form] + 1})
+        assert [m - b for m, b in zip(_moved("grouped_aggregate"),
+                                      moved)] == [1, 1, 1]
+        assert got[0].rows == cpu.scan(spec).rows and len(got[0].rows) == 6
+        assert tpu.scan_batch([q6])[0].rows == cpu.scan(q6).rows
+        assert metrics.grouped_buckets() == dict(before,
+                                                 **{form: before[form] + 1})
+        assert metrics.grouped_agg_fallbacks() == fallbacks
+    finally:
+        FLAGS.set("tpu_plane_encoding", old)
+    text = metrics.process_registry().prometheus_text()
+    for f in ("direct", "hashed"):
+        assert f'yb_grouped_buckets{{form="{f}"}}' in text
+
+
 def test_first_scan_records_upload_and_compile():
     schema, _cpu, tpu, ht = _load(150, seed=23)
     uploads = metrics.device_upload_histogram().count

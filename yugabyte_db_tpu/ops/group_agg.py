@@ -1,4 +1,4 @@
-"""Device GROUP BY aggregates: bucket-hashed dense reduction.
+"""Device GROUP BY aggregates: a dense reduction into a bucket table.
 
 The TPC-H Q1 shape — few, low-cardinality groups over millions of rows —
 runs as ONE device dispatch: a fori_loop over windows (one window for a
@@ -14,10 +14,31 @@ nothing else:
   int32 vectors a row (the match, the not-null masks and ``scanned`` as
   bits of ONE word, ``_mask_bits``; the planes of the base, factor and
   group columns); what is made per row — the rowid, digits, carries,
-  7-bit pieces, key pieces, the bucket (``_bucket_hash`` of the key
-  planes), the bucket one-hot — lives in VMEM for the length of a tile
-  and never exists in HBM (as XLA ops it was an ``[109, N]`` int8
-  operand, 860 launches and 70 MB of temporaries a call of Q1).
+  7-bit pieces, key pieces, the bucket, the bucket one-hot — lives in
+  VMEM for the length of a tile and never exists in HBM (as XLA ops it
+  was an ``[109, N]`` int8 operand, 860 launches and 70 MB of
+  temporaries a call of Q1).
+  The buckets are addressed in one of two forms, by what the program
+  sees of the run it reads (``addressed``: the group columns' leaf kinds
+  and shapes; no flag, nothing of a name; the form is not in ``tag()``):
+  - *direct*, where EVERY group column's ``cmp`` leaf is a "dict" leaf
+    and the product of the dictionaries' caps is within NUM_BUCKETS
+    (TPC-H Q1 over a served run: 3 and 2 values, caps 4 and 4): the
+    bucket is the mixed-radix number of the columns' codes
+    (``_code_bucket``; NULL and unset rows take a dictionary's last,
+    absent slot) and the table has the product's buckets, 16 for Q1,
+    padded to 128 lanes. Bucket <-> key is a bijection, so the kernel
+    keeps no key, no ``rep``, looks no key up and counts no collision
+    (``collisions`` is 0 by construction); the group columns' prefix
+    planes and their ``jnp.take`` of the dictionary leave the program,
+    and the host reads a bucket's values off the run's dictionaries
+    (``bucket_codes``);
+  - *hashed*, everything else (an integer group column, plain string
+    planes, a product of caps over NUM_BUCKETS, a mesh stack: it holds
+    no "dict" leaf): ``_bucket_hash`` of the key planes into
+    NUM_BUCKETS, a bucket keeps ONE key and every row is checked against
+    it, as below. ``yb_grouped_buckets{form}`` counts the dispatches of
+    each form.
   The mask word is made in one of two forms, by what the program sees
   where it is traced (the signature and the run's pytree; no flag):
   - *packed* (``_packed_window``), for a flat run whose presence planes
@@ -49,14 +70,16 @@ nothing else:
     piece sums recombine into the ``[NB, DIGITS]`` int32 accumulators
     and a per-window carry normalization keeps them inside int32 at any
     scale (the same discipline as ops.agg_fold's limb sums);
-  - ``rep`` (a bucket's first matching row, through which the host
-    decodes string groups) and the bucket's key: taken in the tile that
-    first holds a row of the bucket — rows come in rowid order — ``rep``
-    as a masked minimum over the one-hot, the key from the tile's sums
-    (its rows' key pieces sum to count x piece when they agree);
-  - collisions: a bucket keeps ONE key. Every matching row looks its
-    bucket's key planes up (a lane gather of a 128-bucket table) and
-    rows whose own key differs are counted in ``collisions``; the host
+  - hashed form only: ``rep`` (a bucket's first matching row, through
+    which the host decodes string groups) and the bucket's key, taken
+    in the tile that first holds a row of the bucket — rows come in
+    rowid order — ``rep`` as a masked minimum over the one-hot, the key
+    from the tile's sums (its rows' key pieces sum to count x piece
+    when they agree);
+  - hashed form only: collisions. A bucket keeps ONE key. Every
+    matching row looks its bucket's key planes up (a lane gather of a
+    128-bucket table) and rows whose own key differs are counted in
+    ``collisions``; the host
     falls back to its row scan when that is non-zero
     (retry-with-salt left for later; collisions are vanishingly rare
     with NB >= 16x groups). Varlen group columns are exact only when
@@ -132,11 +155,15 @@ class GroupAggSig:
     flat: bool
     group_cols: tuple    # tuple[(col_id, planes)]
     aggs: tuple          # tuple[GAgg]
+    # The direct form: each group column's dictionary cap (NB is their
+    # product). () is the hashed form.
+    radix: tuple = ()
 
     def tag(self) -> str:
         """What the query decides of the program, for its name
         (utils.jitting.tag): TPC-H Q1 is ``g2a8p1f1_...``, Q6
-        ``g0a1p4f1_...``, whatever the run's size."""
+        ``g0a1p4f1_...``, whatever the run's size and whichever way its
+        buckets are addressed."""
         return jitting.tag(groups=self.group_cols, aggs=self.aggs,
                            preds=self.preds, flat=self.flat)
 
@@ -288,6 +315,55 @@ def _bucket_hash(planes):
     return h & jnp.int32(0x7FFFFFFF)
 
 
+def addressed(sig: GroupAggSig, run) -> GroupAggSig:
+    """``sig`` with its buckets addressed the way ``run``'s leaves allow
+    (pytree structure and shapes; no flag, no name): *direct* where
+    every group column's ``cmp`` leaf is a "dict" leaf and the product
+    of the dictionaries' caps is within NUM_BUCKETS — the bucket is the
+    mixed-radix number of the columns' codes and the table has that
+    product's buckets — else *hashed* into NUM_BUCKETS. A signature with
+    no group column has no buckets and comes back as it is."""
+    if not sig.group_cols:
+        return sig
+    leaves = [run["cols"][cid]["cmp"] for cid, _planes in sig.group_cols]
+    if all(encodings.leaf_kind(leaf) == "dict" for leaf in leaves):
+        caps = tuple(int(leaf["dict"]["dhi"].shape[0]) for leaf in leaves)
+        if math.prod(caps) <= NUM_BUCKETS:
+            return dataclasses.replace(sig, NB=math.prod(caps), radix=caps)
+    return dataclasses.replace(sig, NB=NUM_BUCKETS, radix=())
+
+
+def count_bucket_form(sig: GroupAggSig) -> None:
+    """One dispatch of ``sig``'s program in ``yb_grouped_buckets{form}``
+    (a signature without group columns has no buckets: neither form)."""
+    if sig.group_cols:
+        metrics.count_grouped_buckets("direct" if sig.radix else "hashed")
+
+
+def bucket_codes(sig: GroupAggSig, bucket: int) -> list:
+    """Host side, the direct form: a bucket's dictionary code of each
+    group column (``_code_bucket``'s digits, the first column the most
+    significant). Code ``cap - 1`` is the absent slot: the NULL group."""
+    codes = []
+    for cap in reversed(sig.radix):
+        bucket, code = divmod(bucket, cap)
+        codes.append(code)
+    return codes[::-1]
+
+
+def _code_bucket(sig: GroupAggSig, notnull, plane):
+    """The rows' bucket in the direct form: distinct keys have distinct
+    buckets by construction, NULL included. A dictionary's codes are its
+    sorted FULL values' ranks, and its last slot is the absent rows'
+    (encodings.dict_leaf); a row whose merged value is unset or NULL
+    takes that slot whatever code the version it was read off holds."""
+    bucket = None
+    for (cid, _planes), cap in zip(sig.group_cols, sig.radix):
+        code = jnp.where(notnull[cid], plane(cid, 2), jnp.int32(cap - 1))
+        bucket = code if bucket is None else bucket * jnp.int32(cap) + code
+    return bucket
+
+
 def _int8_dot(a, b, axis_a, axis_b):
     """int8 x int8 -> int32 product, contracting one axis of each."""
     return lax.dot_general(a.astype(jnp.int8), b.astype(jnp.int8),
@@ -380,11 +456,15 @@ def _factor_cols(expr):
 def _kernel_rows(sig: GroupAggSig):
     """What the XLA prologue hands the kernel of every row, from the
     signature: (the columns whose not-null masks follow the match mask
-    as bits of the mask words, the (col_id, plane) vectors)."""
+    as bits of the mask words, the (col_id, plane) vectors). Of a group
+    column the direct form takes the code (a "dict" leaf's third plane,
+    encodings.wplane) and not the prefix planes: their ``jnp.take`` of
+    the dictionary leaves the program."""
     notnull, planes = {}, {}
     for cid, np_ in sig.group_cols:
         notnull[cid] = None
-        planes.update({(cid, i): None for i in range(np_)})
+        planes.update({(cid, i): None
+                       for i in ((2,) if sig.radix else range(np_))})
     for ag in sig.aggs:
         if ag.kind == "count":
             if ag.col_id is not None:
@@ -426,11 +506,11 @@ def _round_up(n: int, m: int) -> int:
 
 
 def _kernel_dims(sig: GroupAggSig):
-    """(KP5, C, CP, NBP, KW): the key's pieces lead the kernel's columns,
-    ``_columns``' follow; the MXU's operands and the key tables want the
-    columns, the buckets and the key's pieces padded to whole lanes (CP,
-    NBP, KW)."""
-    KP5 = 5 * _key_planes(sig)
+    """(KP5, C, CP, NBP, KW): the key's pieces lead the kernel's columns
+    (none in the direct form: a bucket IS its key), ``_columns``' follow;
+    the MXU's operands and the key tables want the columns, the buckets
+    and the key's pieces padded to whole lanes (CP, NBP, KW)."""
+    KP5 = 0 if sig.radix else 5 * _key_planes(sig)
     C = KP5 + _column_layout(sig)[2]
     return (KP5, C, _round_up(C, 128), _round_up(sig.NB, 128),
             _round_up(KP5, 128))
@@ -460,11 +540,13 @@ def _fold8(v):
     return v.reshape(-1, 8, 128).sum(axis=0)
 
 
-def _window_kernel(sig: GroupAggSig, S: int, x_ref, base_ref, cnt0_ref,
-                   key0_ref, sums_ref, keyp_ref, rep_ref, stat_ref,
-                   p_ref, b_ref, tile_ref, seen_ref, ktab_ref):
+def _window_kernel(sig: GroupAggSig, S: int, *refs):
     """One tile of S x 128 rows (grid axis 0, ``arbitrary``: the outputs
-    stay in VMEM and accumulate over the window).
+    stay in VMEM and accumulate over the window). ``refs``, hashed form:
+    x, base, cnt0, key0 | sums, keyp, rep, stat | p, b, tile, seen, ktab;
+    direct form (bucket <-> key is a bijection: no key to keep, no
+    ``rep`` to decode it through, no collision to look for): x | sums,
+    stat | p, b.
 
     x_ref[V, S, 128]: the mask words (``_mask_bits``), the planes of
     ``_kernel_rows`` and, of a run that is not flat, the rowid of each
@@ -483,6 +565,12 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, base_ref, cnt0_ref,
     the buckets along the lanes, as the rows look their bucket's up."""
     from jax.experimental import pallas as pl
 
+    direct = sig.radix != ()
+    if direct:
+        x_ref, sums_ref, stat_ref, p_ref, b_ref = refs
+    else:
+        (x_ref, base_ref, cnt0_ref, key0_ref, sums_ref, keyp_ref, rep_ref,
+         stat_ref, p_ref, b_ref, tile_ref, seen_ref, ktab_ref) = refs
     KP, NB = _key_planes(sig), sig.NB
     KP5, _C, CP, NBP, KW = _kernel_dims(sig)
     notnull_cols, scanned_bit, words = _mask_bits(sig)    # x_ref[:words]
@@ -503,10 +591,12 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, base_ref, cnt0_ref,
     def _():
         sums_ref[...] = jnp.zeros_like(sums_ref)
         stat_ref[...] = jnp.zeros_like(stat_ref)
-        rep_ref[...] = jnp.full_like(rep_ref, I32_MAX)
+        if not direct:
+            rep_ref[...] = jnp.full_like(rep_ref, I32_MAX)
         p_ref[...] = jnp.zeros_like(p_ref)      # (the padding columns)
-        seen_ref[...] = cnt0_ref[...]
-        keep_keys(key0_ref[...])
+        if not direct:
+            seen_ref[...] = cnt0_ref[...]
+            keep_keys(key0_ref[...])
 
     def bit(k):
         return ((x_ref[k // 32] >> jnp.int32(k % 32)) & jnp.int32(1)) != 0
@@ -525,8 +615,13 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, base_ref, cnt0_ref,
 
         cols, bad = _columns(sig, m, notnull, plane)
         stat_ref[0] += _fold8(bad.astype(jnp.int32))
-        key = _group_planes(sig, notnull, plane)
-        bucket = jnp.where(m, _bucket_hash(key) % NB, NBP)   # NBP: none
+        if direct:
+            key = []
+            bucket = _code_bucket(sig, notnull, plane)
+        else:
+            key = _group_planes(sig, notnull, plane)
+            bucket = _bucket_hash(key) % NB
+        bucket = jnp.where(m, bucket, NBP)                   # NBP: none
         b_ref[...] = bucket
         for c, col in enumerate(
                 [q for p in key for q in _plane_pieces(p)] + cols):
@@ -535,8 +630,12 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, base_ref, cnt0_ref,
         # ONE product of the bucket one-hot with the tile's columns for
         # every per-bucket sum, G lane-rows of the tile at a time:
         # onehot[NBP, G * 128] x columns[CP, G * 128]^T, int8 operands.
+        # The direct form has nothing to read off a tile's own sums and
+        # adds the products to the window's.
         buckets = lax.broadcasted_iota(jnp.int32, (NBP, 128), 0)
-        tile_ref[...] = jnp.zeros_like(tile_ref)
+        acc_ref = sums_ref if direct else tile_ref
+        if not direct:
+            tile_ref[...] = jnp.zeros_like(tile_ref)
 
         def product(g, carry):
             onehot, columns = [], []
@@ -545,11 +644,13 @@ def _window_kernel(sig: GroupAggSig, S: int, x_ref, base_ref, cnt0_ref,
                     jnp.int32).astype(jnp.int8))
                 columns.append(p_ref[pl.ds(pl.multiple_of(s * CP, CP), CP),
                                      :].astype(jnp.int8))
-            tile_ref[...] += _int8_dot(jnp.concatenate(onehot, axis=1),
-                                       jnp.concatenate(columns, axis=1), 1, 1)
+            acc_ref[...] += _int8_dot(jnp.concatenate(onehot, axis=1),
+                                      jnp.concatenate(columns, axis=1), 1, 1)
             return carry
 
         lax.fori_loop(0, S // G, product, 0)
+        if direct:
+            return
         tile = tile_ref[...]
         cnt = tile[:, KP5:KP5 + 1]
         first_seen = (seen_ref[...] == 0) & (cnt > 0)
@@ -616,15 +717,17 @@ def _grouped_window(sig: GroupAggSig, words, plane, base, start_idx,
     its own group), and the accumulator's ``count[NB]`` and
     ``key[NB, KP]`` of the windows before. Returns (sums[NB, C] in
     ``_columns``' order, rep[NB], key[NB, KP], collisions, negs,
-    scanned)."""
+    scanned); the direct form reads neither ``base`` nor the last three
+    arguments and gives None for ``rep`` and ``key``."""
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     NB, KP = sig.NB, _key_planes(sig)
     KP5, C, CP, NBP, KW = _kernel_dims(sig)
+    direct = sig.radix != ()
     rows = list(words) + [plane(*cp) for cp in _kernel_rows(sig)[1]]
-    if not sig.flat:
+    if not sig.flat and not direct:
         rows.append(base + start_idx)
     n = rows[0].shape[0]
     T = _tile_rows(sig, n)
@@ -633,37 +736,45 @@ def _grouped_window(sig: GroupAggSig, words, plane, base, start_idx,
     if n % T:
         x = jnp.pad(x, ((0, 0), (0, -n % T)))   # (mask word 0: no match)
     x = x.reshape(len(rows), -1, 128)
-    key0 = jnp.pad(jnp.stack(_plane_pieces(key), axis=-1).reshape(NB, KP5),
-                   ((0, NBP - NB), (0, KW - KP5)))
-    cnt0 = jnp.pad(count, (0, NBP - NB))[:, None]
-    base = jnp.full((1, 128), base, jnp.int32)
 
     def whole(*shape):
         return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
-    vmem = 4 * (T * (CP + 2 * len(rows)) + NBP * (3 * CP + 4 * KW))
-    sums, keyp, rep, stat = pl.pallas_call(
-        functools.partial(_window_kernel, sig, S),
-        grid=(x.shape[1] // S,),
-        in_specs=[pl.BlockSpec((len(rows), S, 128), lambda i: (0, i, 0)),
-                  whole(1, 128), whole(NBP, 1), whole(NBP, KW)],
-        out_specs=[whole(NBP, CP), whole(NBP, KW), whole(NBP, 1),
-                   whole(3, 8, 128)],
-        out_shape=[jax.ShapeDtypeStruct((NBP, CP), jnp.int32),
-                   jax.ShapeDtypeStruct((NBP, KW), jnp.int32),
-                   jax.ShapeDtypeStruct((NBP, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((3, 8, 128), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((S * CP, 128), jnp.int32),
-                        pltpu.VMEM((S, 128), jnp.int32),
-                        pltpu.VMEM((NBP, CP), jnp.int32),
-                        pltpu.VMEM((NBP, 1), jnp.int32),
-                        pltpu.VMEM((KP, NBP), jnp.int32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=2 * vmem + (8 << 20)),
-        interpret=jax.default_backend() != "tpu",
-        name="grouped_window",
-    )(x, base, cnt0, key0)
+    def call(operands, in_shapes, out_shapes, scratch_shapes):
+        """``_window_kernel`` over the row tiles: ``x`` by tiles, every
+        other operand and every output whole."""
+        vmem = 4 * (T * (CP + 2 * len(rows)) + NBP * (3 * CP + 4 * KW))
+        return pl.pallas_call(
+            functools.partial(_window_kernel, sig, S),
+            grid=(x.shape[1] // S,),
+            in_specs=[pl.BlockSpec((len(rows), S, 128),
+                                   lambda i: (0, i, 0))]
+            + [whole(*shape) for shape in in_shapes],
+            out_specs=[whole(*shape) for shape in out_shapes],
+            out_shape=[jax.ShapeDtypeStruct(shape, jnp.int32)
+                       for shape in out_shapes],
+            scratch_shapes=[pltpu.VMEM(shape, jnp.int32)
+                            for shape in scratch_shapes],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=2 * vmem + (8 << 20)),
+            interpret=jax.default_backend() != "tpu",
+            name="grouped_window",
+        )(x, *operands)
+
+    if direct:
+        sums, stat = call((), [], [(NBP, CP), (3, 8, 128)],
+                          [(S * CP, 128), (S, 128)])
+        return (sums[:NB, :C], None, None, jnp.int32(0),
+                jnp.sum(stat[0]), jnp.sum(stat[2]))
+    key0 = jnp.pad(jnp.stack(_plane_pieces(key), axis=-1).reshape(NB, KP5),
+                   ((0, NBP - NB), (0, KW - KP5)))
+    cnt0 = jnp.pad(count, (0, NBP - NB))[:, None]
+    base = jnp.full((1, 128), base, jnp.int32)
+    sums, keyp, rep, stat = call(
+        (base, cnt0, key0), [(1, 128), (NBP, 1), (NBP, KW)],
+        [(NBP, CP), (NBP, KW), (NBP, 1), (3, 8, 128)],
+        [(S * CP, 128), (S, 128), (NBP, CP), (NBP, 1), (KP, NBP)])
     return (sums[:NB, KP5:C], rep[:NB, 0],
             _plane_of_pieces(keyp[:NB, :KP5].reshape(NB, KP, 5)),
             jnp.sum(stat[1]), jnp.sum(stat[0]), jnp.sum(stat[2]))
@@ -768,10 +879,17 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
       negative base or factor — host falls back), and per agg
       a<i>[NB, DIGITS] i32 digit sums with n<i>[NB] i32 non-null inputs
       (count aggs: a<i>[NB] i32). With no group column everything is in
-      bucket 0.
+      bucket 0. The direct form (``sig.radix``, ``addressed``) has no
+      ``rep`` and no ``key`` — bucket b IS the key ``bucket_codes(sig,
+      b)`` — and its ``collisions`` is 0 by construction.
     """
     from yugabyte_db_tpu.ops.row_gather import _unpack_literals
 
+    if sig.radix and (seen := addressed(sig, run).radix) != sig.radix:
+        raise ValueError(
+            f"a signature that addresses its buckets by the dictionaries "
+            f"{sig.radix} over a run whose group columns' leaves give "
+            f"{seen}")
     K, R, NB = sig.K, sig.R, sig.NB
     w_first, w_last = iparams[0], iparams[1]
     row_lo, row_hi = iparams[2], iparams[3]
@@ -791,6 +909,8 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
             "scanned": jnp.int32(0),
             "negs": jnp.int32(0),
         }
+        if sig.radix:       # the direct form: a bucket is its key
+            del acc["rep"], acc["key"]
         for i, ag in enumerate(sig.aggs):
             if ag.kind == "count":
                 acc[f"a{i}"] = jnp.zeros((NA,), jnp.int32)
@@ -838,14 +958,17 @@ def grouped_aggregate(sig: GroupAggSig, run, iparams, fparams):
     def accumulate(acc, base, start_idx, words, plane):
         """A grouped window, from its rows' mask words on."""
         sums, rep, key, collisions, negs, scanned = _grouped_window(
-            sig, words, plane, base, start_idx, acc["count"], acc["key"])
-        return add_sums(acc, {
-            "scanned": acc["scanned"] + scanned,
-            "negs": acc["negs"] + negs, "key": key,
-            "collisions": acc["collisions"] + collisions}, sums, rep)
+            sig, words, plane, base, start_idx, acc["count"], acc.get("key"))
+        new = {"scanned": acc["scanned"] + scanned,
+               "negs": acc["negs"] + negs,
+               "collisions": acc["collisions"] + collisions}
+        if key is not None:
+            new["key"] = key
+        return add_sums(acc, new, sums, rep)
 
     def add_sums(acc, new, sums, rep):
-        new["rep"] = jnp.minimum(acc["rep"], rep)
+        if rep is not None:
+            new["rep"] = jnp.minimum(acc["rep"], rep)
         for name, col in mask_at.items():
             new[name] = acc[name] + sums[:, col]
         for name, pieces in digits_at.items():
@@ -893,6 +1016,8 @@ def out_layout(sig: GroupAggSig) -> dict:
     NB = sig.NB
     shapes = {"count": (NB,), "rep": (NB,), "key": (NB, _key_planes(sig)),
               "collisions": (), "scanned": (), "negs": ()}
+    if sig.radix:
+        del shapes["rep"], shapes["key"]
     for i, ag in enumerate(sig.aggs):
         if ag.kind == "count":
             shapes[f"a{i}"] = (NB,)

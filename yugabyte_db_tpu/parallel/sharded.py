@@ -744,6 +744,8 @@ def sharded_grouped_aggregate(st: ShardedTablets, spec: ScanSpec, engine,
         metrics.count_device_dispatch(
             "dist_grouped_aggregate", stack_read_bytes(st, sig0), h2d=1,
             d2h=1)
+        # (hashed: a stack holds no "dict" leaf to address by)
+        group_agg.count_bucket_form(sig0)
     with phase("wait_fetch"):
         out = jax.device_get(out)
 

@@ -223,6 +223,15 @@ def _count_dispatch(entry: str, dev, sig, args, outs) -> None:
                                   d2h=len(jax.tree.leaves(outs)))
 
 
+def _count_grouped_dispatch(entry: str, dev, sig, args, outs) -> None:
+    """``_count_dispatch`` of an ops.group_agg program, and which way its
+    buckets are addressed (``yb_grouped_buckets{form}``)."""
+    from yugabyte_db_tpu.ops import group_agg
+
+    _count_dispatch(entry, dev, sig, args, outs)
+    group_agg.count_bucket_form(sig)
+
+
 def _sig_read_bytes(arrays: dict, sig) -> int:
     named: list = [cid for cid, _planes in getattr(sig, "group_cols", ())]
     arith: list = []
@@ -2782,8 +2791,16 @@ class TpuStorageEngine(StorageEngine):
         row_lo = crun.lower_row(spec.lower)
         row_hi = crun.upper_row(spec.upper)
         R = crun.R
-        K = group_agg.window_blocks(trun.dev.B, R)
-        sig = make_sig(trun.dev.B, K, crun.max_group_versions <= 1)
+        dev = trun.dev
+        K = group_agg.window_blocks(dev.B, R)
+        sig = make_sig(dev.B, K, crun.max_group_versions <= 1)
+        # The buckets by dictionary code where the resident leaves of
+        # the group columns are dictionaries (a run uploaded encoded, a
+        # device flush's) whose values the host holds to read a bucket
+        # back through.
+        if crun.encoded_arrays() is not None and all(
+                cid in crun.enc_dicts for cid, _planes in sig.group_cols):
+            sig = group_agg.addressed(sig, dev.arrays)
 
         if row_lo >= row_hi:
             agg = Aggregator(spec.aggregates, spec.group_by or [])
@@ -2813,7 +2830,7 @@ class TpuStorageEngine(StorageEngine):
         fn = group_agg.compiled_grouped(sig)
         dev = trun.dev
         out = fn(dev.arrays, params)
-        _count_dispatch("grouped_aggregate", dev, sig, params, out)
+        _count_grouped_dispatch("grouped_aggregate", dev, sig, params, out)
         return ("issued", out,
                 self._grouped_finish(trun, spec, exact_preds, sig))
 
@@ -2858,7 +2875,8 @@ class TpuStorageEngine(StorageEngine):
             fn = self._batched_grouped_fn(sig)
             dev = trun.dev
             res = fn(dev.arrays, params_b)
-            _count_dispatch("batched_grouped", dev, sig, params_b, res)
+            _count_grouped_dispatch("batched_grouped", dev, sig, params_b,
+                                    res)
             for i, (pi, trun_i, spec, exact, _params) in enumerate(grp):
                 fin1 = self._grouped_finish(trun_i, spec, exact, sig)
                 out.append((pi, res,
@@ -2883,14 +2901,15 @@ class TpuStorageEngine(StorageEngine):
             return give_up("negs")  # negative base values: digits invalid
         if int(res["collisions"]) > 0:
             return give_up("collision")  # two groups, one bucket
-        keys = np.asarray(res["key"])[:NB]
-
         group_names = list(spec.group_by or [])
         rows = []
-        reps = np.asarray(res["rep"])[:NB]
+        if not sig.radix:
+            keys = np.asarray(res["key"])[:NB]
+            reps = np.asarray(res["rep"])[:NB]
         for b in live:
-            gvals = self._decode_group(crun, spec, sig, keys[b],
-                                       int(reps[b]))
+            gvals = (self._decode_codes(crun, sig, int(b)) if sig.radix
+                     else self._decode_group(crun, spec, sig, keys[b],
+                                             int(reps[b])))
             if gvals is None:
                 return give_up("decode")
             aggs = []
@@ -2913,6 +2932,25 @@ class TpuStorageEngine(StorageEngine):
             (v is None, v) for v in r[:len(group_names)]))
         names = group_names + [a.output_name for a in spec.aggregates]
         return ScanResult(names, rows, None, int(res["scanned"]))
+
+    def _decode_codes(self, crun, sig, bucket: int):
+        """The direct form: a bucket's group values, read off the run's
+        dictionaries (``crun.enc_dicts``: the sorted FULL values, so
+        exact past the 8-byte prefix too) by the bucket's codes; a
+        dictionary's last slot is the NULL group."""
+        from yugabyte_db_tpu.ops import group_agg
+
+        out = []
+        for (cid, _planes), cap, code in zip(
+                sig.group_cols, sig.radix,
+                group_agg.bucket_codes(sig, bucket)):
+            if code == cap - 1:
+                out.append(None)
+                continue
+            raw = crun.enc_dicts[cid][code]
+            out.append(raw.decode("utf-8", "surrogateescape")
+                       if self._dtypes[cid] == DataType.STRING else raw)
+        return out
 
     def _decode_group(self, crun, spec, sig, key_planes, rep):
         """Bucket key planes (no collision counted) -> python group values.

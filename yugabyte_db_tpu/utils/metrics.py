@@ -817,6 +817,34 @@ def grouped_presence() -> dict[str, int]:
 grouped_presence()
 
 
+GROUPED_BUCKET_FORMS = ("direct", "hashed")
+
+
+def _grouped_buckets_counter(form: str) -> Counter:
+    return _span_entity(("grouped_buckets", form),
+                        form=form).counter("yb_grouped_buckets")
+
+
+def count_grouped_buckets(form: str) -> None:
+    """``yb_grouped_buckets{form=direct|hashed}``: one grouped-aggregate
+    program with group columns was dispatched (beside
+    ``yb_device_dispatches``: a vmapped batch and a mesh program are one
+    each) whose buckets are addressed by the group columns' dictionary
+    codes (``direct``: every group column a "dict" leaf of the run, the
+    product of the caps within ops.group_agg.NUM_BUCKETS) or by a hash
+    of the key planes (``hashed``: everything else)."""
+    _grouped_buckets_counter(form).increment()
+
+
+def grouped_buckets() -> dict[str, int]:
+    """Current ``yb_grouped_buckets`` by form."""
+    return {f: _grouped_buckets_counter(f).get()
+            for f in GROUPED_BUCKET_FORMS}
+
+
+grouped_buckets()
+
+
 MESH_SCAN_KINDS = ("agg", "rows")
 MESH_SCAN_OUTCOMES = ("served", "ineligible", "chip_loss")
 
