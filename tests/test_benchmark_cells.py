@@ -34,6 +34,35 @@ SEED = {0: 2_147_483_659, 1: 2_147_483_660}  # selfcheck.py's, by --trace
 
 CELLS = [w["name"] for w in BENCH["workloads"]]
 
+# The statement outside the handler, part by part (a rehearsal: the
+# order, not the sizes): in each pair the first, summed, is no more than
+# the second, summed; each part lies inside what it is a part of.
+PARTS = {
+    "tpch_power_q1q6": [
+        (["pg_parse_ms.power", "pg_plan_ms.power", "pg_scans_ms.power",
+          "pg_combine_ms.power", "pg_reply_ms.power"],
+         ["pg_statement_ms.power"]),
+        (["rpc_queue_ms.power", "tserver_read_rpc_ms.power",
+          "rpc_respond_ms.power"], ["rpc_call_ms.power"]),
+        (["rpc_call_ms.power"], ["pg_scans_ms.power"])],
+    "tpch_throughput_q1q6": [
+        (["pg_parse_ms.streams", "pg_scans_ms.streams"],
+         ["pg_statement_ms.streams"]),
+        (["rpc_queue_ms.streams", "tserver_read_rpc_ms.streams"],
+         ["rpc_call_ms.streams"])],
+    "tpch_mesh_q1_4chip": [
+        (["pg_plan_ms.mesh", "pg_scans_ms.mesh"], ["pg_statement_ms.mesh"]),
+        (["rpc_queue_ms.mesh", "tserver_multi_agg_rpc_ms.mesh"],
+         ["rpc_call_ms.mesh"]),
+        (["rpc_call_ms.mesh"], ["pg_scans_ms.mesh"]),
+        (["mesh_issue_lower_ms.mesh", "mesh_issue_dispatch_ms.mesh"],
+         ["mesh_issue_ms.mesh"])],
+    "kv_mixed_flush": [
+        (["tserver_write_rpc_ms.kv"], ["rpc_call_write_ms.kv"]),
+        (["tserver_read_rpc_ms.kv"], ["rpc_call_read_ms.kv"]),
+        (["raft_replicate_ms.kv"], ["tserver_write_rpc_ms.kv"])],
+}
+
 
 def rehearse(cell, trace):
     proc = subprocess.run(
@@ -53,6 +82,10 @@ def rehearse(cell, trace):
         dark = sorted(n for n, m in line["metrics"].items()
                       if not m["value"] > 0)
         assert not dark, f"per-layer metrics that read nothing: {dark}: {err}"
+        got = {n: m["value"] for n, m in line["metrics"].items()}
+        for parts, whole in PARTS[cell]:
+            assert sum(got[n] for n in parts) <= sum(got[n] for n in whole), \
+                (parts, whole, got)
 
 
 @pytest.mark.parametrize("trace", [0], ids=["plain"])

@@ -59,6 +59,74 @@ def test_span_lands_in_trace_histogram_and_ring_with_its_parent():
     assert abs(mine[-1]["ts"] / 1e6 - time.time()) < 60
 
 
+def test_a_mesh_phase_hook_names_a_part_of_the_issue_apart():
+    """``tserver/mesh_scan.py:_phase`` is the hook
+    ``sharded_grouped_aggregate`` times itself with: a phase goes to
+    ``yb_engine_phase_us{phase, route="mesh"}``, a part of the issue to
+    ``yb_mesh_issue_part_us{part}`` as span ``engine.issue.<part>``,
+    each published where it ends, under the request that is open."""
+    from yugabyte_db_tpu.tserver.mesh_scan import _phase
+
+    phase_h = metrics.engine_phase_histogram("issue", "mesh")
+    part_h = metrics.mesh_issue_part_histogram("lower")
+    n_phase, n_part = phase_h.count, part_h.count
+    with trace.trace_request("ts.multi_agg_scan") as t:
+        with _phase("issue"):
+            with _phase("issue", "lower"):
+                pass
+            assert (phase_h.count, part_h.count) == (n_phase, n_part + 1)
+    assert (phase_h.count, part_h.count) == (n_phase + 1, n_part + 1)
+    spans = {s["name"]: s for s in t.dump()["spans"]}
+    assert spans["engine.issue.lower"]["parent"] == "engine.issue"
+    assert spans["engine.issue"]["parent"] == "ts.multi_agg_scan"
+    assert spans["engine.issue.lower"]["route"] == "mesh"
+
+
+def test_the_ring_is_dumped_while_many_threads_record_into_it():
+    """The ring is on every span's path and a dump is an operator's
+    click: a dump taken while eight threads record loses nothing it
+    should hold and never raises, whatever the interleaving."""
+    import sys
+
+    ring = trace.TraceEventLog(capacity=512)
+    stop = threading.Event()
+    dumps, errors = [], []
+
+    def write(k):
+        for i in range(20_000):
+            ring.record("unit.ring", i, k, None, "t%d" % k)
+
+    def read():
+        try:
+            while not stop.is_set():
+                dumps.append(len(ring.dump()["traceEvents"]))
+        except Exception as e:  # noqa: BLE001 - the test's whole point
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        reader = threading.Thread(target=read)
+        writers = [threading.Thread(target=write, args=(k,))
+                   for k in range(8)]
+        reader.start()
+        for w in writers:
+            w.start()
+        for w in writers:
+            w.join(timeout=60)
+        stop.set()
+        reader.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors, errors
+    assert not reader.is_alive() and not any(w.is_alive() for w in writers)
+    assert dumps and max(dumps) <= 512
+    last = ring.dump()["traceEvents"]
+    assert len(last) == 512
+    assert {e["args"]["trace_id"] for e in last} <= {"t%d" % k
+                                                    for k in range(8)}
+
+
 def test_span_takes_a_histogram_of_its_own_in_seconds():
     h = metrics.jit_compile_histogram("unit_entry")
     trace.record_span("engine.compile", time.time_ns(), 250_000, h,
@@ -128,81 +196,199 @@ DDL = ("CREATE TABLE lineitem (l_orderkey BIGINT, l_linenumber INT, "
        "l_shipdate INT, PRIMARY KEY ((l_orderkey), l_linenumber))")
 
 
-@pytest.mark.parametrize("chips", [1, 8])
-def test_request_id_survives_frontend_pool_rpc_and_tserver(tmp_path, chips,
-                                                           monkeypatch):
-    """PG frontend -> pg-docop worker -> RPC payload ->
-    TabletServer.handle: one /rpcz sample of the tserver holds the
-    engine's three phases under the id the frontend gave the statement.
-    On a node with one chip that is one ``ts.scan`` a tablet; where the
-    tserver reports several and leads both tablets, ONE
-    ``ts.multi_agg_scan`` with the mesh's phases."""
+PG_PARTS = ("parse", "plan", "scans", "combine", "reply")
+
+
+def _new_series_counts(method):
+    return {
+        **{"pg." + p: metrics.pg_statement_part_histogram(p).count
+           for p in PG_PARTS},
+        **{"mesh." + p: metrics.mesh_issue_part_histogram(p).count
+           for p in ("lower", "dispatch")},
+        "rpc.call": metrics.rpc_call_histogram(method).count,
+        "rpc.respond": metrics.rpc_respond_histogram(method).count,
+        "pg.scan_wait": metrics.span_histogram("pg.scan_wait").count,
+    }
+
+
+@pytest.fixture(scope="module", params=[1, 8])
+def pg_q6(request, tmp_path_factory):
+    """ONE Q6 over a flushed two-tablet LINEITEM, PG wire -> pg-docop
+    worker -> socket RPC -> the one tserver that leads both tablets
+    (``tests/test_mesh_route.py``'s cluster), and what it left behind:
+    the frontend's /rpcz sample, the tserver's /rpcz over HTTP, how far
+    each histogram grew. ``request.param`` is the chips the tserver
+    reports: with one, a ``ts.scan`` a tablet; with several, ONE
+    ``ts.multi_agg_scan``."""
     from yugabyte_db_tpu.drivers.minipg import PgConnection
     from yugabyte_db_tpu.integration import MiniCluster
     from yugabyte_db_tpu.tools.admin_client import AdminClient
     from yugabyte_db_tpu.tserver.tablet_server import TabletServer
     from yugabyte_db_tpu.yql.pgsql import tpch
 
-    monkeypatch.setattr(TabletServer, "local_chips", lambda self: chips)
+    chips = request.param
     method, sent = (("ts.scan", 2) if chips == 1
                     else ("ts.multi_agg_scan", 1))
-    mc = MiniCluster(str(tmp_path), num_masters=1, num_tservers=1,
-                     transport="socket").start()
-    srv = None
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(TabletServer, "local_chips", lambda self: chips)
+        mc = MiniCluster(str(tmp_path_factory.mktemp(f"pg_q6_{chips}")),
+                         num_masters=1, num_tservers=1,
+                         transport="socket").start()
+        srv = None
+        try:
+            mc.wait_tservers_registered()
+            srv, addr = mc.start_pg_server(engine="tpu", num_tablets=2,
+                                           replication_factor=1,
+                                           rpc_timeout_s=120)
+            conn = PgConnection(*addr, timeout=120)
+            conn.execute(DDL)
+            cols = ("l_orderkey", "l_linenumber", "l_quantity",
+                    "l_extendedprice", "l_discount", "l_tax",
+                    "l_returnflag", "l_linestatus", "l_shipdate")
+            values = ",".join(
+                "(" + ",".join(repr(r[c]) for c in cols) + ")"
+                for r in tpch.generate_lineitem(120))
+            conn.execute(f"INSERT INTO lineitem ({','.join(cols)}) "
+                         f"VALUES {values}")
+            assert AdminClient(mc.transport, mc.master_uuids).flush_table(
+                "lineitem") == 2
+            before = _new_series_counts(method)
+            conn.execute(tpch.q6_sql())
+            conn.close()
+            after = _new_series_counts(method)
+            ts = next(iter(mc.tservers.values()))
+            import urllib.request
+
+            host, port = mc.start_webservers()[ts.uuid]
+            with urllib.request.urlopen(f"http://{host}:{port}/rpcz",
+                                        timeout=5) as r:
+                rpcz = json.load(r)
+            front = trace.FRONTEND_RPCZ.dump()["methods"][
+                "pg.statement"][-1]
+            yield {
+                "chips": chips, "method": method, "sent": sent,
+                "grew": {k: after[k] - before[k] for k in after},
+                "front": front, "rpcz": rpcz,
+                "mine": [s for s in rpcz["methods"][method]
+                         if s["trace_id"] == front["trace_id"]],
+                "tserver_text": ts.metrics.prometheus_text(),
+            }
+        finally:
+            if srv is not None:
+                srv.shutdown()
+            mc.shutdown()
+
+
+def test_request_id_survives_frontend_pool_rpc_and_tserver(pg_q6):
+    """PG frontend -> pg-docop worker -> RPC payload ->
+    TabletServer.handle: one /rpcz sample of the tserver holds the
+    engine's three phases under the id the frontend gave the statement.
+    On a node with one chip that is one ``ts.scan`` a tablet; where the
+    tserver reports several and leads both tablets, ONE
+    ``ts.multi_agg_scan`` with the mesh's phases."""
+    method, sent = pg_q6["method"], pg_q6["sent"]
+    # a unit a scan: two tablets through the pg-docop pool, or the
+    # leader's group as one
+    assert pg_q6["grew"]["pg.scan_wait"] == sent
+    names = [s["name"] for s in pg_q6["front"]["spans"]]
+    assert names.count("pg.scan_wait") == sent and "rpc.queue" in names
+    assert pg_q6["rpcz"]["frontends"]["methods"]["pg.statement"]
+    assert len(pg_q6["mine"]) == sent
+    for sample in pg_q6["mine"]:
+        assert sample["parent_span"] == "pg.statement"
+        spans = {s["name"]: s for s in sample["spans"]}
+        for phase in PHASES:
+            assert spans["engine." + phase]["parent"] == method
+            assert spans["engine." + phase]["duration_us"] >= 0
+            if pg_q6["chips"] > 1:
+                assert spans["engine." + phase]["route"] == "mesh"
+        assert spans["rpc.queue"]["duration_us"] >= 0
+    assert f'rpc_queue_us_count{{daemon="tserver",method="{method}"' \
+        in pg_q6["tserver_text"]
+
+
+def test_a_pg_statement_outside_its_handlers_is_five_parts(pg_q6):
+    """Exactly one ``pg.parse``, ``pg.plan``, ``pg.scans``,
+    ``pg.combine``, ``pg.reply`` under the statement, each observed
+    once in ``yb_pg_statement_part_us{part}``; together no more than
+    the statement, in the order a statement takes them."""
+    front = pg_q6["front"]
+    parts = [s for s in front["spans"]
+             if s["name"] in {"pg." + p for p in PG_PARTS}]
+    assert [s["name"] for s in parts] == ["pg." + p for p in PG_PARTS]
+    for s in parts:
+        assert s["parent"] == "pg.statement"
+        assert pg_q6["grew"][s["name"]] == 1
+    assert sum(s["duration_us"] for s in parts) <= front["duration_us"]
+    by = {s["name"]: s for s in parts}
+    assert by["pg.scans"]["units"] == pg_q6["sent"]
+    assert by["pg.scans"]["duration_us"] > 0
+    starts = [s["start_us"] for s in parts]
+    assert starts == sorted(starts)
+    # the last unit's answer in hand, not before
+    assert by["pg.combine"]["start_us"] >= \
+        by["pg.scans"]["start_us"] + by["pg.scans"]["duration_us"] - 1
+
+
+def test_each_unit_has_its_callers_round_trip_and_a_reply(pg_q6):
+    """One ``rpc.call`` a unit in the statement's own sample (the
+    pg-docop worker runs under its Trace), no shorter than what the
+    tserver's sample of that call holds; ``rpc_respond_us{method}``
+    grows once a call, ``rpc_call_us{method}`` too."""
+    method, sent = pg_q6["method"], pg_q6["sent"]
+    calls = [s for s in pg_q6["front"]["spans"]
+             if s["name"] == "rpc.call" and s["method"] == method]
+    assert len(calls) == sent
+    assert pg_q6["grew"]["rpc.call"] == sent
+    assert pg_q6["grew"]["rpc.respond"] == sent
+    served = sorted(s["duration_us"] for s in pg_q6["mine"])
+    assert len(served) == sent
+    for call, handler in zip(sorted(s["duration_us"] for s in calls),
+                             served):
+        assert call >= handler
+
+
+def test_a_mesh_request_issues_in_two_parts(pg_q6):
+    """``engine.issue.lower`` and ``engine.issue.dispatch`` once each
+    inside ``engine.issue`` of a ``ts.multi_agg_scan``; a per-tablet
+    scan has neither."""
+    grew = (pg_q6["grew"]["mesh.lower"], pg_q6["grew"]["mesh.dispatch"])
+    if pg_q6["chips"] == 1:
+        assert grew == (0, 0)
+        return
+    assert grew == (1, 1)
+    (sample,) = pg_q6["mine"]
+    spans = {s["name"]: s for s in sample["spans"]}
+    lower, disp = spans["engine.issue.lower"], spans["engine.issue.dispatch"]
+    issue = spans["engine.issue"]
+    assert lower["parent"] == disp["parent"] == "engine.issue"
+    assert lower["route"] == disp["route"] == "mesh"
+    assert lower["duration_us"] + disp["duration_us"] <= issue["duration_us"]
+    assert issue["start_us"] <= lower["start_us"] <= disp["start_us"]
+
+
+def test_a_local_call_observes_no_rpc_call(tmp_path):
+    """Over ``LocalTransport`` there is no ``Proxy.call`` and no
+    ``Messenger._dispatch``: no ``rpc.call``, no ``rpc.respond``, as
+    there is no ``rpc.queue``."""
+    from yugabyte_db_tpu.integration import MiniCluster
+
+    def series():
+        text = metrics.process_registry().prometheus_text()
+        return sorted(line for line in text.splitlines()
+                      if line.startswith(("rpc_call_us_count",
+                                          "rpc_respond_us_count")))
+
+    mc = MiniCluster(str(tmp_path), num_masters=1, num_tservers=1).start()
     try:
         mc.wait_tservers_registered()
-        srv, addr = mc.start_pg_server(engine="tpu", num_tablets=2,
-                                       replication_factor=1,
-                                       rpc_timeout_s=120)
-        conn = PgConnection(*addr, timeout=120)
-        conn.execute(DDL)
-        cols = ("l_orderkey", "l_linenumber", "l_quantity",
-                "l_extendedprice", "l_discount", "l_tax", "l_returnflag",
-                "l_linestatus", "l_shipdate")
-        values = ",".join(
-            "(" + ",".join(repr(r[c]) for c in cols) + ")"
-            for r in tpch.generate_lineitem(120))
-        conn.execute(f"INSERT INTO lineitem ({','.join(cols)}) "
-                     f"VALUES {values}")
-        assert AdminClient(mc.transport, mc.master_uuids).flush_table(
-            "lineitem") == 2
-        waits = metrics.span_histogram("pg.scan_wait").count
-        conn.execute(tpch.q6_sql())
-        conn.close()
-        # a unit a scan: two tablets through the pg-docop pool, or the
-        # leader's group as one
-        assert metrics.span_histogram("pg.scan_wait").count == waits + sent
-
-        front = trace.FRONTEND_RPCZ.dump()["methods"]["pg.statement"][-1]
-        names = [s["name"] for s in front["spans"]]
-        assert names.count("pg.scan_wait") == sent and "rpc.queue" in names
-        ts = next(iter(mc.tservers.values()))
-        addrs = mc.start_webservers()
-        import urllib.request
-
-        host, port = addrs[ts.uuid]
-        with urllib.request.urlopen(f"http://{host}:{port}/rpcz",
-                                    timeout=5) as r:
-            rpcz = json.load(r)
-        assert rpcz["frontends"]["methods"]["pg.statement"]
-        mine = [s for s in rpcz["methods"][method]
-                if s["trace_id"] == front["trace_id"]]
-        assert len(mine) == sent
-        for sample in mine:
-            assert sample["parent_span"] == "pg.statement"
-            spans = {s["name"]: s for s in sample["spans"]}
-            for phase in PHASES:
-                assert spans["engine." + phase]["parent"] == method
-                assert spans["engine." + phase]["duration_us"] >= 0
-                if chips > 1:
-                    assert spans["engine." + phase]["route"] == "mesh"
-            assert spans["rpc.queue"]["duration_us"] >= 0
-        text = ts.metrics.prometheus_text()
-        assert f'rpc_queue_us_count{{daemon="tserver",method="{method}"' \
-            in text
+        before = series()
+        with trace.trace_request("unit.local") as t:
+            assert len(mc.client().list_tservers()) == 1
+        assert series() == before
+        assert not [s for s in t.dump()["spans"]
+                    if s["name"].startswith("rpc.")]
     finally:
-        if srv is not None:
-            srv.shutdown()
         mc.shutdown()
 
 

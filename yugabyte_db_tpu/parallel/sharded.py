@@ -695,6 +695,37 @@ def stack_read_bytes(st: ShardedTablets, sig) -> int:
     return st._read_bytes[sig]
 
 
+def _lower_grouped(st: ShardedTablets, spec: ScanSpec, engine):
+    """The first part of a grouped mesh request's issue: the spec
+    lowered to ONE ops.group_agg signature and a parameter vector a
+    tablet, as the ``[T, P + F]`` array the program takes."""
+    exact, superset, host_only = engine._split_predicates(spec)
+    if superset or host_only:
+        raise GroupedIneligible("predicates the device cannot decide")
+    sig0 = vecs = None
+    K = group_agg.window_blocks(st.Bl, st.R)
+    flat = all(r.max_group_versions <= 1 for r in st.runs)
+    for t, run in enumerate(st.runs):
+        low = engine._grouped_lower(run, spec, exact)
+        if low is None:
+            raise GroupedIneligible("not a signature group_agg lowers")
+        make_sig, int_lits, f32_lits = low
+        sig = make_sig(st.Bl, K, flat)
+        if sig0 is not None and sig != sig0:
+            raise GroupedIneligible("tablets lower to different "
+                                    "signatures")
+        ip, fp = RG.pack_params(
+            0, 0, run.lower_row(spec.lower), run.upper_row(spec.upper),
+            engine._read_plane_ints(spec), int_lits, f32_lits)
+        vec = group_agg.pack_params(sig, ip, fp)
+        if sig0 is None:
+            # (pad tablets keep zero bounds: their shards walk no
+            # window and give empty tables)
+            sig0, vecs = sig, np.zeros((st.padded_T, vec.size), np.int32)
+        vecs[t] = vec
+    return sig0, vecs
+
+
 def sharded_grouped_aggregate(st: ShardedTablets, spec: ScanSpec, engine,
                               phase=None) -> ScanResult:
     """GROUP BY / expression aggregates over all tablets on the mesh, as
@@ -703,49 +734,31 @@ def sharded_grouped_aggregate(st: ShardedTablets, spec: ScanSpec, engine,
     the spec to ops.group_agg's signature and finishes a tablet's packed
     vector with that tablet's run, exactly as it does its own. Raises
     :class:`GroupedIneligible` where the per-tablet path has to serve.
-    ``phase(name)``, where given, is a context manager around the three
-    phases (issue, wait_fetch, finish)."""
-    phase = phase or (lambda _name: contextlib.nullcontext())
+    ``phase(name, part=None)``, where given, is a context manager around
+    the three phases (issue, wait_fetch, finish) and, inside issue,
+    around its two parts (lower; dispatch: the ONE jit call with its
+    parameter upload)."""
+    phase = phase or (lambda _name, _part=None: contextlib.nullcontext())
     with phase("issue"):
-        exact, superset, host_only = engine._split_predicates(spec)
-        if superset or host_only:
-            raise GroupedIneligible("predicates the device cannot decide")
-        sig0 = vecs = None
-        K = group_agg.window_blocks(st.Bl, st.R)
-        flat = all(r.max_group_versions <= 1 for r in st.runs)
-        for t, run in enumerate(st.runs):
-            low = engine._grouped_lower(run, spec, exact)
-            if low is None:
-                raise GroupedIneligible("not a signature group_agg lowers")
-            make_sig, int_lits, f32_lits = low
-            sig = make_sig(st.Bl, K, flat)
-            if sig0 is not None and sig != sig0:
-                raise GroupedIneligible("tablets lower to different "
-                                        "signatures")
-            ip, fp = RG.pack_params(
-                0, 0, run.lower_row(spec.lower), run.upper_row(spec.upper),
-                engine._read_plane_ints(spec), int_lits, f32_lits)
-            vec = group_agg.pack_params(sig, ip, fp)
-            if sig0 is None:
-                # (pad tablets keep zero bounds: their shards walk no
-                # window and give empty tables)
-                sig0, vecs = sig, np.zeros((st.padded_T, vec.size), np.int32)
-            vecs[t] = vec
-        Tl = st.padded_T // st.mesh.shape["t"]
-        fn = _compiled_dist_grouped(sig0, st.mesh, st.enc_struct, Tl, st.Bl)
-        if fn._cache_size():
-            out = fn(st.arrays, vecs)
-        else:
-            # The first requests after a flush come from every tserver
-            # of the process at once: one traces and compiles, the
-            # others find its program.
-            with _FIRST_CALL:
+        with phase("issue", "lower"):
+            sig0, vecs = _lower_grouped(st, spec, engine)
+        with phase("issue", "dispatch"):
+            Tl = st.padded_T // st.mesh.shape["t"]
+            fn = _compiled_dist_grouped(sig0, st.mesh, st.enc_struct, Tl,
+                                        st.Bl)
+            if fn._cache_size():
                 out = fn(st.arrays, vecs)
-        metrics.count_device_dispatch(
-            "dist_grouped_aggregate", stack_read_bytes(st, sig0), h2d=1,
-            d2h=1)
-        # (hashed: a stack holds no "dict" leaf to address by)
-        group_agg.count_bucket_form(sig0)
+            else:
+                # The first requests after a flush come from every
+                # tserver of the process at once: one traces and
+                # compiles, the others find its program.
+                with _FIRST_CALL:
+                    out = fn(st.arrays, vecs)
+            metrics.count_device_dispatch(
+                "dist_grouped_aggregate", stack_read_bytes(st, sig0), h2d=1,
+                d2h=1)
+            # (hashed: a stack holds no "dict" leaf to address by)
+            group_agg.count_bucket_form(sig0)
     with phase("wait_fetch"):
         out = jax.device_get(out)
 

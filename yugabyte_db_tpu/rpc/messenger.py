@@ -14,9 +14,10 @@ import selectors
 import socket
 import struct
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 
-from yugabyte_db_tpu.utils import codec, trace
+from yugabyte_db_tpu.utils import codec, metrics, trace
 
 _LEN = struct.Struct("<I")
 MAX_FRAME = 64 * 1024 * 1024
@@ -246,13 +247,25 @@ class Messenger:
             response = (call_id, "error", f"{type(e).__name__}: {e}")
         finally:
             trace.set_arrival(None)
+        wall_ns, t0 = time.time_ns(), time.perf_counter_ns()
         try:
             out = conn.context.serialize(response)
         except Exception:
             self._close_conn(conn)
             return
         if out:
-            self.send_on(conn, out)
+            with conn.out_lock:
+                conn.out.extend(out)
+        # The span rpc.respond ends BEFORE the reactor is woken: what
+        # this thread does after the wake-up it does while the reactor
+        # wants the interpreter for the reply's write, and a few
+        # microseconds there cost the caller tens (PERF.md section 6, PR
+        # 39). The handler's Trace is closed by now: histogram only.
+        trace.record_span("rpc.respond", wall_ns,
+                          (time.perf_counter_ns() - t0) // 1000,
+                          metrics.rpc_respond_histogram(str(method)))
+        if out:
+            self._wake()
 
     def add_service_pool(self, prefix: str, num_workers: int) -> None:
         """Route native-protocol methods starting with ``prefix`` onto a

@@ -12,9 +12,10 @@ from __future__ import annotations
 import socket
 import struct
 import threading
+import time
 
 from yugabyte_db_tpu.rpc.messenger import MAX_FRAME, RpcCallError
-from yugabyte_db_tpu.utils import codec
+from yugabyte_db_tpu.utils import codec, metrics, trace
 from yugabyte_db_tpu.utils.retry import Deadline
 
 _LEN = struct.Struct("<I")
@@ -54,6 +55,20 @@ class Proxy:
         longer than the one deadline they all debit."""
         if deadline is not None:
             timeout = deadline.timeout(timeout)
+        # The caller's side of the round trip, under the caller's Trace
+        # where it has one: what the server's rpc.queue, handler and
+        # rpc.respond leave of it is the sockets, the reactors and the
+        # hand-offs between threads.
+        wall_ns, t0 = time.time_ns(), time.perf_counter_ns()
+        try:
+            return self._call(method, body, timeout)
+        finally:
+            trace.record_span("rpc.call", wall_ns,
+                              (time.perf_counter_ns() - t0) // 1000,
+                              metrics.rpc_call_histogram(method),
+                              method=method)
+
+    def _call(self, method: str, body, timeout: float):
         with self._lock:
             if self._closed:
                 raise ConnectionError(f"proxy to {self.addr} is closed")

@@ -57,12 +57,17 @@ from yugabyte_db_tpu.utils import metrics, trace
 from yugabyte_db_tpu.utils.fault_injection import maybe_fault
 
 
-def _phase(name: str) -> trace.span:
+def _phase(name: str, part: str | None = None) -> trace.span:
     """A phase of a mesh request, beside the per-tablet batches':
-    ``yb_engine_phase_us{phase, route="mesh"}``."""
-    return trace.span("engine." + name,
-                      metrics.engine_phase_histogram(name, "mesh"),
-                      route="mesh")
+    ``yb_engine_phase_us{phase, route="mesh"}``; a ``part`` of its
+    issue (``lower``, ``dispatch``) is span ``engine.issue.<part>`` and
+    goes to ``yb_mesh_issue_part_us{part}``."""
+    if part is None:
+        return trace.span("engine." + name,
+                          metrics.engine_phase_histogram(name, "mesh"),
+                          route="mesh")
+    return trace.span(f"engine.{name}.{part}",
+                      metrics.mesh_issue_part_histogram(part), route="mesh")
 
 
 class MeshScanService:

@@ -20,6 +20,7 @@ everything in one pass, grouping series by metric name.
 from __future__ import annotations
 
 import bisect
+import functools
 import logging
 import threading
 import time
@@ -647,9 +648,14 @@ def observe_request_latency(proto: str, seconds: float) -> None:
 
 
 # -- span observability (utils/trace.py) ---------------------------------------
-# One labeled entity per (series, label values), made on first use; the
-# hot path is one dict lookup. docs/observability.md lists every series
-# here with the span that feeds it and who reads it.
+# One labeled entity per (series, label values), made on first use. A
+# span's histogram is looked up on every span, from threads that have
+# just been woken with cold caches, where the entity's own look-up (a
+# key tuple, a labels dict, two dicts and a lock) measured 4-7 us of a
+# span's 10-16 (PERF.md section 6, PR 39): the helpers that return a
+# histogram remember it by their arguments (``functools.cache``).
+# docs/observability.md lists every series here with the span that
+# feeds it and who reads it.
 _SPAN_ENTITIES: dict[tuple, MetricEntity] = {}
 
 
@@ -664,12 +670,14 @@ def _span_entity(key: tuple, **labels) -> MetricEntity:
     return ent
 
 
+@functools.cache
 def span_histogram(name: str) -> Histogram:
     """``yb_span_us{span=name}``: where a span with no histogram of
     its own is observed (microseconds)."""
     return _span_entity(("span", name), span=name).histogram("yb_span_us")
 
 
+@functools.cache
 def rpc_queue_histogram(method: str) -> Histogram:
     """``rpc_queue_us{method=pg|cql|redis}`` for the wire frontends (a
     tserver or master keeps its own, per method, beside
@@ -679,6 +687,7 @@ def rpc_queue_histogram(method: str) -> Histogram:
         "rpc_queue_us")
 
 
+@functools.cache
 def engine_phase_histogram(phase: str, route: str) -> Histogram:
     """``yb_engine_phase_us{phase=issue|wait_fetch|finish, route}``:
     one observation per scan batch and phase (storage/tpu_engine.py);
@@ -688,6 +697,7 @@ def engine_phase_histogram(phase: str, route: str) -> Histogram:
                         route=route).histogram("yb_engine_phase_us")
 
 
+@functools.cache
 def engine_issue_part_histogram(part: str) -> Histogram:
     """``yb_engine_issue_part_us{part=plan|dispatch|copy_out}``: the
     issue phase of a device scan batch in three parts, one observation
@@ -696,6 +706,43 @@ def engine_issue_part_histogram(part: str) -> Histogram:
         "yb_engine_issue_part_us")
 
 
+@functools.cache
+def pg_statement_part_histogram(part: str) -> Histogram:
+    """``yb_pg_statement_part_us{part=parse|plan|scans|combine|reply}``:
+    a PG statement outside its scan units' RPCs, one observation of
+    each part a statement that reaches it (yql/pgsql/wire.py ``_query``,
+    yql/pgsql/executor.py ``PgProcessor.execute``)."""
+    return _span_entity(("pg_part", part), part=part).histogram(
+        "yb_pg_statement_part_us")
+
+
+@functools.cache
+def rpc_call_histogram(method: str) -> Histogram:
+    """``rpc_call_us{method}``: the caller's side of a call over a
+    socket, request encoded until the reply's body is in hand
+    (rpc/proxy.py ``Proxy.call``)."""
+    return _span_entity(("call", method), method=method).histogram(
+        "rpc_call_us")
+
+
+@functools.cache
+def rpc_respond_histogram(method: str) -> Histogram:
+    """``rpc_respond_us{method}``: a handler's answer serialised and
+    queued on its connection (rpc/messenger.py ``_dispatch``)."""
+    return _span_entity(("respond", method), method=method).histogram(
+        "rpc_respond_us")
+
+
+@functools.cache
+def mesh_issue_part_histogram(part: str) -> Histogram:
+    """``yb_mesh_issue_part_us{part=lower|dispatch}``: the issue phase
+    of a grouped mesh request in two parts, one observation each per
+    request (parallel/sharded.py ``sharded_grouped_aggregate``)."""
+    return _span_entity(("mesh_issue_part", part), part=part).histogram(
+        "yb_mesh_issue_part_us")
+
+
+@functools.cache
 def apply_stall_histogram() -> Histogram:
     """``yb_apply_stall_us``: how long a memtable flush held the thread
     that applies committed Raft entries (span ``engine.flush`` with
@@ -703,6 +750,7 @@ def apply_stall_histogram() -> Histogram:
     return _span_entity(("apply_stall",)).histogram("yb_apply_stall_us")
 
 
+@functools.cache
 def compaction_histogram(route: str, kind: str) -> Histogram:
     """``yb_compaction_us{route, kind}``: one compaction from the runs
     as they were to the run list swapped (span ``engine.compact``).
@@ -722,6 +770,7 @@ def count_compaction(route: str, kind: str, by: str) -> None:
                  by=by).counter("yb_compactions").increment()
 
 
+@functools.cache
 def jit_compile_histogram(entry: str) -> Histogram:
     """``yb_jit_compile_seconds{entry}``: seconds a dispatch spent
     tracing and compiling, beside ``yb_jit_compiles{entry}``."""
@@ -729,6 +778,7 @@ def jit_compile_histogram(entry: str) -> Histogram:
         "yb_jit_compile_seconds", buckets=REQUEST_LATENCY_S_BUCKETS)
 
 
+@functools.cache
 def device_upload_histogram() -> Histogram:
     """``yb_device_upload_seconds``: host time of one run's upload
     (pad, ``device_put`` of every plane)."""

@@ -30,7 +30,7 @@ from yugabyte_db_tpu.models.schema import ColumnKind, ColumnSchema, Schema
 from yugabyte_db_tpu.storage import expr as X
 from yugabyte_db_tpu.storage.row_version import MAX_HT, RowVersion
 from yugabyte_db_tpu.storage.scan_spec import AggSpec, Predicate, ScanSpec
-from yugabyte_db_tpu.utils import trace
+from yugabyte_db_tpu.utils import metrics, trace
 from yugabyte_db_tpu.utils.status import AlreadyPresent, InvalidArgument
 from yugabyte_db_tpu.yql.pgsql import ast
 from yugabyte_db_tpu.yql.pgsql.operations import combine_grouped
@@ -43,6 +43,50 @@ class SerializationFailure(Exception):
 
 class FailedTransaction(Exception):
     """Statement issued inside an aborted block (PG code 25P02)."""
+
+
+class _StatementClock:
+    """Where one ``execute`` spent its time around its scan units, for
+    the spans ``pg.plan`` (entered until ``_prefetch_scans`` is first
+    called), ``pg.scans`` (the statement's own thread blocked on a
+    unit's result, summed; label ``units``) and ``pg.combine`` (the
+    last unit's result in hand until ``execute`` returns): children of
+    ``pg.statement``, ``yb_pg_statement_part_us{part}``. A statement
+    that scans nothing observes none of the three. Until ``finish`` it
+    only reads clocks: nothing is recorded between a statement's parse
+    and its units' replies."""
+
+    __slots__ = ("wall_ns", "t0", "planned", "blocked_ns", "units", "last")
+
+    def __init__(self):
+        self.wall_ns = time.time_ns()
+        self.t0 = time.perf_counter_ns()
+        self.planned = None      # when _prefetch_scans was first called
+        self.blocked_ns = 0
+        self.units = 0
+        self.last = self.t0
+
+    def scans_begin(self) -> None:
+        if self.planned is None:
+            self.planned = time.perf_counter_ns()
+
+    def blocked(self, since_ns: int) -> None:
+        self.last = time.perf_counter_ns()
+        self.blocked_ns += self.last - since_ns
+        self.units += 1
+
+    def finish(self) -> None:
+        if self.planned is None:
+            return
+        now = time.perf_counter_ns()
+        for part, since, dur_ns, labels in (
+                ("plan", self.t0, self.planned - self.t0, {}),
+                ("scans", self.planned, self.blocked_ns,
+                 {"units": self.units}),
+                ("combine", self.last, now - self.last, {})):
+            trace.record_span(
+                "pg." + part, self.wall_ns + since - self.t0, dur_ns // 1000,
+                metrics.pg_statement_part_histogram(part), **labels)
 
 
 @dataclass
@@ -77,6 +121,7 @@ class PgProcessor:
         self._txn_failed = False  # aborted block awaiting COMMIT/ROLLBACK
         self._yb_tables: dict = {}
         self._currvals: dict[str, int] = {}  # per-session currval state
+        self._clock = _StatementClock()      # of the statement executing
 
     @property
     def in_txn(self) -> bool:
@@ -91,6 +136,12 @@ class PgProcessor:
 
     # -- entry point -------------------------------------------------------
     def execute(self, sql, params: list | None = None) -> PgResult | None:
+        clock = self._clock = _StatementClock()
+        res = self._execute(sql, params)
+        clock.finish()
+        return res
+
+    def _execute(self, sql, params: list | None) -> PgResult | None:
         stmt = parse_statement(sql) if isinstance(sql, str) else sql
         self._params = params or []
         if isinstance(stmt, ast.TxnControl):
@@ -1675,10 +1726,15 @@ class PgProcessor:
         ``pg-docop`` worker (submit until the worker starts it; 0 on the
         synchronous branch): the pool is one for every session of the
         process."""
+        clock = self._clock
+        clock.scans_begin()
         if len(tablets) <= 1:
             for t in tablets:
                 trace.record_span("pg.scan_wait", time.time_ns(), 0)
-                yield t, t.scan(spec_of(t))
+                since = time.perf_counter_ns()
+                res = t.scan(spec_of(t))
+                clock.blocked(since)
+                yield t, res
             return
         import collections
 
@@ -1701,7 +1757,10 @@ class PgProcessor:
                     time.time_ns(), time.perf_counter_ns())))
                 idx += 1
             t, fut = futs.popleft()
-            yield t, fut.result()
+            since = time.perf_counter_ns()
+            res = fut.result()
+            clock.blocked(since)
+            yield t, res
 
     def _scan_dicts(self, handle, where, preds, needed, push_limit):
         """Row dicts matching WHERE: index-driven when an '='-bound

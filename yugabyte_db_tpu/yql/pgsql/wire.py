@@ -24,7 +24,7 @@ from __future__ import annotations
 import struct
 
 from yugabyte_db_tpu.rpc.messenger import ConnectionContext, Messenger
-from yugabyte_db_tpu.utils import trace
+from yugabyte_db_tpu.utils import metrics, trace
 from yugabyte_db_tpu.utils.status import (AlreadyPresent, InvalidArgument,
                                           NotFound)
 from yugabyte_db_tpu.yql.pgsql.executor import PgProcessor, PgResult
@@ -120,6 +120,14 @@ def data_row(row: tuple) -> bytes:
             b = _text(v)
             parts.append(struct.pack(">i", len(b)) + b)
     return _msg(b"D", b"".join(parts))
+
+
+def _part(part: str) -> trace.span:
+    """One of the frontend's own parts of a statement (``pg.parse``,
+    ``pg.reply``; the executor has the three between them):
+    ``yb_pg_statement_part_us{part}``."""
+    return trace.span("pg." + part,
+                      metrics.pg_statement_part_histogram(part))
 
 
 # -- connection context ------------------------------------------------------
@@ -292,7 +300,9 @@ class PgServiceImpl:
         if kind == "P":  # Parse: name, query, n param-type oids
             name, pos = self._cstr(payload, 0)
             query, pos = self._cstr(payload, pos)
-            stmts = parse_script(query)
+            # (outside any statement: the histogram only)
+            with _part("parse"):
+                stmts = parse_script(query)
             if len(stmts) > 1:
                 raise ValueError(
                     "cannot insert multiple commands into a prepared "
@@ -405,7 +415,8 @@ class PgServiceImpl:
         sql = payload.rstrip(b"\x00").decode("utf-8", "replace")
         out = bytearray()
         try:
-            stmts = parse_script(sql)
+            with _part("parse"):
+                stmts = parse_script(sql)
         except Exception as e:  # noqa: BLE001 - parse error to client
             return bytes(error_response(str(e), "42601")
                          + ready_for_query(txn_status()))
@@ -433,15 +444,17 @@ class PgServiceImpl:
             except Exception as e:  # noqa: BLE001
                 out += error_response(str(e))
                 break
-            if res is None:
-                out += command_complete("OK")
-            elif res.command.startswith(("SELECT", "select")) or res.columns:
-                out += row_description(res)
-                for r in res.rows:
-                    out += data_row(r)
-                out += command_complete(f"SELECT {len(res.rows)}")
-            else:
-                out += command_complete(res.command)
+            with _part("reply"):
+                if res is None:
+                    out += command_complete("OK")
+                elif res.command.startswith(("SELECT", "select")) \
+                        or res.columns:
+                    out += row_description(res)
+                    for r in res.rows:
+                        out += data_row(r)
+                    out += command_complete(f"SELECT {len(res.rows)}")
+                else:
+                    out += command_complete(res.command)
         out += ready_for_query(txn_status())
         return bytes(out)
 
