@@ -88,6 +88,45 @@ def rehearse(cell, trace):
                 (parts, whole, got)
 
 
+# Who moved a reply (PR 40): the labelled counter's growth over the
+# window, through the reader that was there. {metric: (series, by)}
+REPLY_COUNTERS = {
+    "rpc_reply_writes_worker.power": ("rpc_reply_writes", "worker"),
+    "rpc_reply_reads_caller.power": ("rpc_reply_reads", "caller"),
+    "rpc_reply_reads_caller.kv": ("rpc_reply_reads", "caller"),
+    "rpc_reply_reads_peer.kv": ("rpc_reply_reads", "peer"),
+}
+
+
+@pytest.mark.parametrize("metric", sorted(REPLY_COUNTERS))
+def test_reply_counter_metric_resolves_and_reads_its_label(metric):
+    """Each entry resolves to its data file and to a reader the
+    benchmark had; on a registry without the series (the parent commit
+    under this benchmark) it reads 0, so that the parent's traced line
+    stays valid (``contract.validate`` refuses a line that lacks a
+    listed metric); on one with it, the growth of its own label."""
+    import importlib
+
+    series, by = REPLY_COUNTERS[metric]
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    assert entry["source"] == "program_counter"
+    assert entry["layer"] == "RPC + tablet"
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           metric + ".json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "span_counter_delta"
+    assert spec["args"] == {"name": series, "labels": {"by": by}}
+    read = importlib.import_module(
+        "benchmark.readers." + spec["reader"]).read
+    other = {("rpc_call_us_count", (("method", "ts.scan"),)): 7.0}
+    assert read(spec["args"], {"registry": (other, other)}) == 0
+    rest = "reactor" if by == "worker" else \
+        ("peer" if by == "caller" else "caller")
+    before = {(series, (("by", by),)): 5.0, (series, (("by", rest),)): 1.0}
+    after = {(series, (("by", by),)): 47.0, (series, (("by", rest),)): 4.0}
+    assert read(spec["args"], {"registry": (before, after)}) == 42
+
+
 @pytest.mark.parametrize("trace", [0], ids=["plain"])
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_rehearses(cell, trace):

@@ -208,6 +208,8 @@ def _new_series_counts(method):
         "rpc.call": metrics.rpc_call_histogram(method).count,
         "rpc.respond": metrics.rpc_respond_histogram(method).count,
         "pg.scan_wait": metrics.span_histogram("pg.scan_wait").count,
+        **{"writes." + by: n for by, n in metrics.rpc_reply_writes().items()},
+        **{"reads." + by: n for by, n in metrics.rpc_reply_reads().items()},
     }
 
 
@@ -346,6 +348,25 @@ def test_each_unit_has_its_callers_round_trip_and_a_reply(pg_q6):
     for call, handler in zip(sorted(s["duration_us"] for s in calls),
                              served):
         assert call >= handler
+
+
+def test_who_moved_each_reply_is_counted_by_label(pg_q6):
+    """``rpc_reply_writes{by}`` grows once a reply of a socket server
+    (the units' and the PG frontend's own to its client among them),
+    ``rpc_reply_reads{by}`` once a call over a socket that got its
+    reply; both series stand in the process registry under both
+    labels."""
+    grew, sent = pg_q6["grew"], pg_q6["sent"]
+    assert grew["writes.worker"] + grew["writes.reactor"] >= sent + 1
+    assert grew["writes.worker"] >= 1
+    assert grew["reads.caller"] + grew["reads.peer"] >= sent
+    assert grew["reads.caller"] >= 1
+    text = metrics.process_registry().prometheus_text()
+    for series, labels in (("rpc_reply_writes", metrics.RPC_REPLY_WRITERS),
+                           ("rpc_reply_reads", metrics.RPC_REPLY_READERS)):
+        assert f"# TYPE {series} counter" in text
+        for by in labels:
+            assert f'{series}{{by="{by}"}} ' in text
 
 
 def test_a_mesh_request_issues_in_two_parts(pg_q6):

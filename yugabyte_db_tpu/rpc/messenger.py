@@ -2,9 +2,12 @@
 
 Reference analog: src/yb/rpc/messenger.cc + reactor.cc — a small number of
 event-loop threads own all sockets; complete inbound calls are handed to a
-worker pool (service_pool.cc); responses are queued back to the reactor via
-a wakeup pipe. ConnectionContext (connection_context.h) turns raw bytes
-into calls and serializes responses, so CQL/RESP servers reuse this loop.
+worker pool (service_pool.cc). The worker that has a response writes it to
+the connection's non-blocking socket itself; only what the socket does not
+take (a large reply, a full buffer) is queued for the reactor, which is
+woken over a pipe and writes the rest. ConnectionContext
+(connection_context.h) turns raw bytes into calls and serializes responses,
+so CQL/RESP servers reuse this loop.
 """
 
 from __future__ import annotations
@@ -21,6 +24,8 @@ from yugabyte_db_tpu.utils import codec, metrics, trace
 
 _LEN = struct.Struct("<I")
 MAX_FRAME = 64 * 1024 * 1024
+_WRITES_BY_WORKER = metrics.rpc_reply_writes_counter("worker")
+_WRITES_BY_REACTOR = metrics.rpc_reply_writes_counter("reactor")
 
 
 class RpcCallError(Exception):
@@ -228,7 +233,7 @@ class Messenger:
             self._dispatch(conn, call, stamp)
 
     def _dispatch(self, conn: _Connection, call, stamp=None) -> None:
-        """Worker-side: run the handler, enqueue the response.
+        """Worker-side: run the handler, write the response.
         ``stamp`` is when the call's frame was parsed
         (utils.trace.record_queue_wait reads it in the handler).
 
@@ -253,19 +258,44 @@ class Messenger:
         except Exception:
             self._close_conn(conn)
             return
-        if out:
-            with conn.out_lock:
-                conn.out.extend(out)
-        # The span rpc.respond ends BEFORE the reactor is woken: what
-        # this thread does after the wake-up it does while the reactor
-        # wants the interpreter for the reply's write, and a few
-        # microseconds there cost the caller tens (PERF.md section 6, PR
-        # 39). The handler's Trace is closed by now: histogram only.
+        # The span rpc.respond ends BEFORE the reply leaves: the send is
+        # what wakes the caller, and what this thread does after it, it
+        # does while the caller wants the interpreter (PERF.md section 6,
+        # PRs 39 and 40). The handler's Trace is closed by now: histogram
+        # only.
         trace.record_span("rpc.respond", wall_ns,
                           (time.perf_counter_ns() - t0) // 1000,
                           metrics.rpc_respond_histogram(str(method)))
         if out:
-            self._wake()
+            self._write_reply(conn, out)
+
+    def _write_reply(self, conn: _Connection, out: bytes) -> None:
+        """The thread that has the reply writes it. Frames of one
+        connection keep their order: the socket is written here only
+        while nothing is queued (``conn.out`` empty, under its lock);
+        what the socket does not take is queued and left to the reactor,
+        and while the reactor is mid-write a reply queues behind it."""
+        with conn.out_lock:
+            if conn.closed:
+                return
+            if not conn.out:
+                # Counted before the send, on the common path's side of
+                # it; a reply the socket takes only a part of moves over.
+                _WRITES_BY_WORKER.increment()
+                try:
+                    n = conn.sock.send(out)
+                except (BlockingIOError, InterruptedError):
+                    n = 0
+                except OSError:
+                    self._close_conn(conn)
+                    return
+                if n == len(out):
+                    return
+                _WRITES_BY_WORKER.increment(-1)
+                out = memoryview(out)[n:]
+            _WRITES_BY_REACTOR.increment()
+            conn.out.extend(out)
+        self._wake()
 
     def add_service_pool(self, prefix: str, num_workers: int) -> None:
         """Route native-protocol methods starting with ``prefix`` onto a

@@ -733,6 +733,51 @@ def rpc_respond_histogram(method: str) -> Histogram:
         "rpc_respond_us")
 
 
+RPC_REPLY_WRITERS = ("worker", "reactor")
+RPC_REPLY_READERS = ("caller", "peer")
+
+
+@functools.cache
+def rpc_reply_writes_counter(by: str) -> Counter:
+    """``rpc_reply_writes{by=worker|reactor}``: one a reply of a socket
+    server (rpc/messenger.py ``_write_reply``): ``worker`` where the
+    thread that ran the handler wrote the whole frame to the socket
+    itself, ``reactor`` where any part of it was queued for the reactor
+    (the socket took a part or nothing, or frames were queued already).
+    ``worker`` is counted before the send and taken back where the
+    socket took only a part, so that nothing runs after a send that
+    took it all."""
+    return _span_entity(("reply_writes", by), by=by).counter(
+        "rpc_reply_writes")
+
+
+def rpc_reply_writes() -> dict[str, int]:
+    """Current ``rpc_reply_writes`` by writer."""
+    return {by: rpc_reply_writes_counter(by).get()
+            for by in RPC_REPLY_WRITERS}
+
+
+@functools.cache
+def rpc_reply_reads_counter(by: str) -> Counter:
+    """``rpc_reply_reads{by=caller|peer}``: one a call over a socket
+    that got its reply (rpc/proxy.py ``Proxy._call``): ``caller`` where
+    the calling thread read the reply's frame from the socket itself,
+    ``peer`` where another caller of the same proxy, reading for its
+    own reply, handed it over."""
+    return _span_entity(("reply_reads", by), by=by).counter(
+        "rpc_reply_reads")
+
+
+def rpc_reply_reads() -> dict[str, int]:
+    """Current ``rpc_reply_reads`` by reader."""
+    return {by: rpc_reply_reads_counter(by).get()
+            for by in RPC_REPLY_READERS}
+
+
+rpc_reply_writes()
+rpc_reply_reads()
+
+
 @functools.cache
 def mesh_issue_part_histogram(part: str) -> Histogram:
     """``yb_mesh_issue_part_us{part=lower|dispatch}``: the issue phase
