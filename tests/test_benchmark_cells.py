@@ -57,6 +57,16 @@ PARTS = {
         (["rpc_call_ms.mesh"], ["pg_scans_ms.mesh"]),
         (["mesh_issue_lower_ms.mesh", "mesh_issue_dispatch_ms.mesh"],
          ["mesh_issue_ms.mesh"])],
+    "tpch_q1q6_after_refresh": [
+        (["pg_plan_ms.refresh", "pg_scans_ms.refresh"],
+         ["pg_statement_ms.refresh"]),
+        (["rpc_queue_ms.refresh", "tserver_read_rpc_ms.refresh"],
+         ["rpc_call_ms.refresh"]),
+        (["rpc_call_ms.refresh"], ["pg_scans_ms.refresh"]),
+        (["engine_issue_plan_ms.refresh", "engine_issue_dispatch_ms.refresh",
+          "engine_issue_copy_out_ms.refresh"], ["engine_issue_ms.refresh"]),
+        (["engine_issue_ms.refresh", "engine_wait_fetch_ms.refresh",
+          "engine_finish_ms.refresh"], ["tserver_read_rpc_ms.refresh"])],
     "kv_mixed_flush": [
         (["tserver_write_rpc_ms.kv"], ["rpc_call_write_ms.kv"]),
         (["tserver_read_rpc_ms.kv"], ["rpc_call_read_ms.kv"]),
@@ -131,3 +141,100 @@ def test_reply_counter_metric_resolves_and_reads_its_label(metric):
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_rehearses(cell, trace):
     rehearse(cell, trace)
+
+
+# -- tpch_q1q6_after_refresh: the refresh reference, by brute force -----------
+
+def _refresh_config():
+    from benchmark.run import load_json, merged
+
+    cfg = load_json("benchmark", "configs", "tpch_lineitem_refresh_rf3.json")
+    return merged(cfg, cfg["rehearsal"])
+
+
+@pytest.mark.parametrize("seed", [1, 2_147_483_659, 3_000_043_001])
+def test_refresh_reference_is_base_less_deleted_plus_inserted(seed):
+    """At the rehearsal's size, row by row in Python: the table the
+    refresh reference answers from is the base population's rows without
+    every line of the deleted orders, and RF1's rows (what
+    ``batches("burst")`` hands the loader) after them; Q1 and Q6 over it
+    are the plain sums."""
+    from benchmark.references import tpch_lineitem, tpch_lineitem_refresh
+
+    cfg = _refresh_config()
+    base = [r for rows in tpch_lineitem.Reference(cfg, seed).batches()
+            for r in rows]
+    ref = tpch_lineitem_refresh.Reference(cfg, seed)
+    assert [r for rows in ref.batches() for r in rows] == base
+    burst = [r for rows in ref.batches("burst") for r in rows]
+    deleted = dict(tpch_lineitem_refresh.delete_keys(cfg, seed))
+    orders = cfg["scale"]["refresh_pairs"] * cfg["scale"]["refresh_orders"]
+    assert len(deleted) == orders == 24
+    assert sorted(deleted) == sorted({r["l_orderkey"] for r in base})[:orders]
+    # RF1: as many new orders, 1-7 lines, keys the population leaves free
+    new_orders = {r["l_orderkey"] for r in burst}
+    assert len(new_orders) == orders
+    assert all(8 < k % 32 <= 16 for k in new_orders)
+    assert not new_orders & {r["l_orderkey"] for r in base}
+    assert all(1 <= n <= 7 for n in (
+        sum(r["l_orderkey"] == k for r in burst) for k in new_orders))
+    table = [r for r in base if r["l_orderkey"] not in deleted] + burst
+    assert len(base) - len(table) + len(burst) == sum(deleted.values())
+    assert ref.filled == len(table)
+    assert (ref.inserted, ref.deleted) == (len(burst), sum(deleted.values()))
+    for c in tpch_lineitem.COLS:
+        want = [ord(r[c]) if isinstance(r[c], str) else r[c] for r in table]
+        assert ref.col[c][:ref.filled].tolist() == want, c
+    hit = [r for r in table if 8766 <= r["l_shipdate"] < 9131
+           and 4 <= r["l_discount"] <= 6 and r["l_quantity"] < 25]
+    assert ref.q6(8766, 9131, 4, 6, 25) == [[sum(
+        r["l_extendedprice"] * r["l_discount"] for r in hit)]] and hit
+    groups = {}
+    for r in table:
+        if r["l_shipdate"] <= 10471:
+            g = groups.setdefault((r["l_returnflag"], r["l_linestatus"]),
+                                  [0, 0, 0])
+            g[0] += r["l_quantity"]
+            g[1] += r["l_extendedprice"]
+            g[2] += 1
+    assert [(row[0], row[1], row[2], row[3], row[8])
+            for row in ref.q1(10471)] == [
+        (f, s, q, p, n) for (f, s), (q, p, n) in sorted(groups.items())]
+
+
+def test_refresh_generator_deletes_what_the_reference_deleted():
+    """The load generator takes its DELETE statements from the reference
+    module, from the plan's seed and configuration alone (no table)."""
+    from benchmark.generators import sql_streams_refresh
+    from benchmark.references import tpch_lineitem_refresh
+    from benchmark.run import load_json
+
+    cfg = _refresh_config()
+    traffic = load_json("benchmark", "traffic",
+                        "tpch_streams_1_after_refresh.json")
+    assert traffic["generator"] == "sql_streams_refresh"
+    plan = {"params": traffic["params"], "seed": 77, "workers": 1,
+            "worker": 0, "addr": {"pg": ["127.0.0.1", 1]},
+            "config": {k: cfg[k] for k in ("schema", "scale", "load")}}
+    gen = sql_streams_refresh.Generator(plan)
+    assert gen.refresh_connections == 6
+    assert gen.refresh_keys == tpch_lineitem_refresh.delete_keys(cfg, 77)
+    ref = tpch_lineitem_refresh.Reference(cfg, 77)
+    ref.fill()
+    assert ref.deleted == sum(lines for _k, lines in gen.refresh_keys)
+    # the statements of the window are cell 1's, letter for letter
+    assert traffic["params"]["statements"] == load_json(
+        "benchmark", "traffic", "tpch_streams_1.json")["params"]["statements"]
+
+
+@pytest.mark.parametrize("seed", [1, 2_147_483_659, 77])
+def test_refresh_control_fails_both_ways(seed):
+    """``benchmark/control.py`` for the new cell: the reference's own
+    answers pass, the float32 sums fail AND the table without RF2 fails."""
+    from benchmark.control import control
+
+    out = control("tpch_q1q6_after_refresh", seed, small=True)
+    assert out["sound_wrong"] == 0
+    assert out["control_wrong_float32"] > 0
+    assert out["control_wrong_without_rf2"] > 0
+    assert out["passed"]

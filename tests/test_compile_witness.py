@@ -11,6 +11,8 @@ compile behaviour never contradicts a static @compile_contract fact).
 import functools
 import json
 import tempfile
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -93,6 +95,42 @@ def test_factory_counts_one_compile_per_signature():
     assert row["compiles"] == 3 and row["steady"] == 0
     assert row["budget"] == 4
     assert any("test_compile_witness" in s for s in row["sites"])
+
+
+def test_threads_that_miss_at_once_share_one_wrapper_and_one_compile():
+    """``functools.lru_cache`` calls the factory once a thread that
+    misses before the first call has returned (two tablets' first scans
+    of one new query); they get ONE jit object, so the program is traced
+    once and the others wait for it."""
+    made, traced = [], []
+
+    @functools.lru_cache(maxsize=None)
+    @compile_contract("test_toy_race", max_compiles=4)
+    def toy(n):
+        made.append(n)
+        time.sleep(0.05)        # the window the other threads miss in
+
+        def body(x):
+            traced.append(n)
+            return x * n
+        return jax.jit(body)
+
+    got = []
+    gate = threading.Barrier(4)
+
+    def one():
+        gate.wait()
+        fn = toy(7)
+        got.append((fn, fn(jnp.arange(3)).tolist()))
+
+    threads = [threading.Thread(target=one) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert made == [7] and traced == [7]
+    assert len({id(fn) for fn, _out in got}) == 1
+    assert [out for _fn, out in got] == [[0, 7, 14]] * 4
 
 
 def test_direct_jit_counts_compiles():

@@ -875,12 +875,15 @@ def test_a_plain_bool_leaf_takes_the_rows_form_and_agrees(plain):
         assert f'yb_grouped_presence{{form="{form}"}}' in text
 
 
-def test_the_overlays_masked_run_takes_the_rows_form():
+def test_the_overlays_masked_run_keeps_the_packed_form():
     """The delta overlay's masked primary (``_MaskedRun``): its ``valid``
-    is a plain bool plane with the dirty rows cleared, beside the run's
-    "bits" leaves. A grouped program over it resolves by rows, and
-    answers as the packed form does over a packed ``valid`` with the
-    same rows cleared."""
+    stays a "bits" leaf, the dirty rows' bits cleared in the packed
+    words, so a grouped program over it is the run's own (same pytree,
+    no new trace, the packed form). It answers as the rows form does
+    over a plain bool ``valid`` with the same rows cleared (a device
+    flush's run: the form the overlay's mask took before PR 43)."""
+    import jax.numpy as jnp
+
     from yugabyte_db_tpu.ops import encodings, group_agg
 
     _cpu, tpu, ht = _load(num=700, host_flush=True)
@@ -892,20 +895,25 @@ def test_the_overlays_masked_run_takes_the_rows_form():
     arrays = tpu.runs[0].dev.arrays
     idx = np.arange(3, 700, 11, dtype=np.int32)
     masked = tpu._masked_primary(tpu.runs[0], idx).dev.arrays
-    assert encodings.leaf_kind(masked["valid"]) is None
+    assert encodings.leaf_kind(masked["valid"]) == "bits"
     assert encodings.leaf_kind(masked["tomb"]) == "bits"
     fn = group_agg.compiled_grouped(sig)
     before = _presence_counts()
     whole = group_agg.unpack(sig, np.asarray(fn(arrays, params)))
     got = group_agg.unpack(sig, np.asarray(fn(masked, params)))
+    # one trace serves both: the masked run has the run's own signature
     assert _presence_counts() == {"packed": before["packed"] + 1,
-                                  "rows": before["rows"] + 1}
+                                  "rows": before["rows"]}
     valid = np.array(encodings.decode_leaf(arrays["valid"], sig.B, sig.R))
     assert valid.reshape(-1)[idx].all()
     valid.reshape(-1)[idx] = False
-    cleared = dict(arrays, valid=encodings.encode_bool_plane(valid))
+    assert (np.asarray(encodings.decode_leaf(
+        masked["valid"], sig.B, sig.R)) == valid).all()
+    cleared = dict(arrays, valid=jnp.asarray(valid))
     _assert_same_bits(got, group_agg.unpack(sig, np.asarray(fn(cleared,
                                                                params))))
+    assert _presence_counts() == {"packed": before["packed"] + 1,
+                                  "rows": before["rows"] + 1}
     assert int(got["scanned"]) == int(whole["scanned"]) - idx.size
 
 

@@ -199,6 +199,40 @@ def test_an_ineligible_group_is_served_a_tablet_at_a_time(pg_cluster):
     assert _delta(_scan_rpcs(ts), rpcs)["ts.scan"] == 2
 
 
+@pytest.mark.parametrize("pg_cluster", [8], indirect=True)
+def test_a_mesh_request_waits_for_the_sessions_acked_write(pg_cluster,
+                                                           monkeypatch):
+    """A write is acked at COMMIT and applied after; until the apply
+    lands the tablets still look flushed and idle. A mesh request reads
+    at a fresh read point, so like ``ts.scan``'s gate it waits for safe
+    time to reach what the session observed (``propagated_ht``): the
+    statement after an INSERT counts the inserted row, however late the
+    apply (here 0.3 s: the mesh answered from below the write before)."""
+    import time
+
+    from yugabyte_db_tpu.storage.tpu_engine import TpuStorageEngine
+    from yugabyte_db_tpu.yql.pgsql import tpch
+
+    mc, conn, rows, ts = pg_cluster
+    before = conn.execute(tpch.q6_sql()).rows[0][0]
+    apply = TpuStorageEngine.apply
+
+    def late_apply(self, versions, *args, **kwargs):
+        time.sleep(0.3)
+        return apply(self, versions, *args, **kwargs)
+
+    monkeypatch.setattr(TpuStorageEngine, "apply", late_apply)
+    conn.execute("INSERT INTO lineitem (l_orderkey, l_linenumber, "
+                 "l_quantity, l_extendedprice, l_discount, l_tax, "
+                 "l_returnflag, l_linestatus, l_shipdate) VALUES "
+                 "(900002, 1, 3, 7000, 6, 1, 'N', 'O', 9200)")
+    rpcs = _scan_rpcs(ts)
+    assert conn.execute(tpch.q6_sql()).rows[0][0] == before + 7000 * 6
+    # (the memtable holds the row once the wait is over: demoted)
+    assert _delta(_scan_rpcs(ts), rpcs) == {"ts.multi_agg_scan": 1,
+                                            "ts.scan": 2}
+
+
 def test_a_tserver_reports_its_chips_and_the_master_hands_them_on(tmp_path):
     """``local_chips`` rides the heartbeat; ``get_table_locations`` gives
     it for every replica (1 for a server that runs no TPU engine)."""

@@ -446,6 +446,10 @@ def _specs_for(route, schema, tpu, ht):
                               columns={cid["a"]: 5, cid["d"]: 1})])
         return [ScanSpec(read_ht=ht + 10, aggregates=[
             AggSpec("count", None), AggSpec("sum", "a")])]
+    if route == "overlay_deferred":   # grouped: run + live memtable
+        _specs_for("issued", schema, tpu, ht)
+        return [ScanSpec(read_ht=ht + 10, group_by=["d"],
+                         aggregates=[AggSpec("count", None)])]
     if route == "mixed":
         return _specs_for("page", schema, tpu, ht) \
             + _specs_for("agg_deferred", schema, tpu, ht)
@@ -454,7 +458,7 @@ def _specs_for(route, schema, tpu, ht):
 
 @pytest.mark.parametrize("route", [
     "host", "page", "gather", "agg_deferred", "grouped_deferred", "issued",
-    "mixed", "breaker_host"])
+    "overlay_deferred", "mixed", "breaker_host"])
 def test_each_engine_phase_is_observed_once_a_batch(route):
     schema, _cpu, tpu, ht = _load(300)
     if route == "breaker_host":

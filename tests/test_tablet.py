@@ -86,6 +86,39 @@ def test_wal_segment_roll_and_gc(tmp_path):
     assert set(range(30, 51)) <= idxs
 
 
+@pytest.mark.parametrize("gone", ["first", "middle"])
+def test_wal_read_tolerates_a_segment_gc_unlinked_after_the_listing(
+        tmp_path, monkeypatch, gone):
+    """``read_all`` lists the segments and opens them one by one; a
+    ``gc`` on another thread may unlink one in between (ROADMAP C17: a
+    FileNotFoundError inside restart_tserver). The reader goes on with
+    what is left, as if the segment had gone before the listing."""
+    log = Log(str(tmp_path / "wal"), segment_bytes=256, fsync=False)
+    for i in range(1, 51):
+        log.append(LogEntry(OpId(1, i), i, "write", ["x" * 30]))
+    log.sync()
+    listed = log.segment_paths()
+    assert len(listed) > 3
+    victim = listed[0 if gone == "first" else 2]
+    lost = {e.op_id.index for e in Log._read_segment(victim, 0)[0]}
+    read_segment = Log._read_segment
+    opened = []
+
+    def unlink_then_read(path, min_index):
+        # (between the listing and this open: what gc does)
+        if path == victim and os.path.exists(victim):
+            os.unlink(victim)
+        opened.append(path)
+        return read_segment(path, min_index)
+
+    monkeypatch.setattr(Log, "_read_segment",
+                        staticmethod(unlink_then_read))
+    got = [e.op_id.index for e in log.read_all()]
+    assert opened == listed                 # every listed segment tried
+    assert got == [i for i in range(1, 51) if i not in lost]
+    assert lost and not os.path.exists(victim)
+
+
 # -- MvccManager -----------------------------------------------------------
 
 def test_mvcc_safe_time_blocks_on_pending():

@@ -55,8 +55,8 @@ nothing else:
     planes of Q1 was materialised, 0.65 of the program's 2.0 ms on the
     v5e;
   - *by rows* (``_rows_window``), for everything else: a run that is
-    not flat, a plain bool plane among the presence planes (a device
-    flush's run; the delta overlay's masked ``valid``). Bit for bit the
+    not flat (the delta overlay's mini-run), a plain bool plane among
+    the presence planes (a device flush's run). Bit for bit the
     same words: the oracle of tests/test_group_agg.py.
   ``yb_grouped_presence{form}`` counts the programs traced in each form.
   In the kernel:

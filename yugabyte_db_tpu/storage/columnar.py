@@ -116,6 +116,10 @@ class ColumnarRun:
         # dictionary-encoded column's sorted value list so the engine
         # can translate string predicates to code-range compares.
         self._enc_cache: tuple | None = None
+        # True: upload the plain planes whatever the flag says (the
+        # delta overlay's mini-run: an encoded tree's structure follows
+        # the content, and every rebuild would meet another program).
+        self.plain_planes = False
         self.enc_dicts: dict[int, list[bytes]] = {}
         self.enc_stats: dict | None = None
         self.kv_ready = False  # True once every block's keys are decoded
@@ -127,10 +131,13 @@ class ColumnarRun:
     # -- construction ------------------------------------------------------
     @staticmethod
     def build(schema: Schema, entries: list[tuple[bytes, list[RowVersion]]],
-              rows_per_block: int = DEFAULT_ROWS_PER_BLOCK) -> "ColumnarRun":
+              rows_per_block: int = DEFAULT_ROWS_PER_BLOCK,
+              plain_planes: bool = False) -> "ColumnarRun":
         """entries: (key asc, versions ht-desc) — MemTable.drain_sorted() or a
-        compaction merge. Packs key groups into blocks without splitting."""
+        compaction merge. Packs key groups into blocks without splitting.
+        ``plain_planes``: the run uploads plain whatever the flag says."""
         run = ColumnarRun(schema, rows_per_block)
+        run.plain_planes = plain_planes
         R = run.R
         for key, versions in entries:
             n = len(versions)
@@ -498,6 +505,8 @@ class ColumnarRun:
         re-uploads after eviction reuse the same compressed tree."""
         from yugabyte_db_tpu.utils.flags import FLAGS
 
+        if self.plain_planes:
+            return None
         key = (FLAGS.get("tpu_plane_encoding"), len(self.cols))
         if self._enc_cache is not None and self._enc_cache[0] == key:
             return self._enc_cache[1]

@@ -105,9 +105,15 @@ class TpuRun:
     accounting can't drop planes a dispatch still references."""
 
     def __init__(self, crun: ColumnarRun, device_tracker=None,
-                 device=None, path: str | None = None):
+                 device=None, path: str | None = None,
+                 pad_blocks: int = PAD_BLOCKS, label: str = "run"):
         self.crun = crun
         self.path = path        # its file under RunPersistence, if any
+        # Block-axis padding of the upload: PAD_BLOCKS for a tablet's
+        # runs (a multiple of every window); the overlay's mini-run pads
+        # to its own power of two, so its programs run over thousands of
+        # rows and not PAD_BLOCKS x R.
+        self.pad_blocks = pad_blocks
         self.host_index = None  # storage.host_page.HostPageIndex, lazy
         self._dev_nbytes_hint: int | None = None
         # The owning device: every demand (re-)upload for this run
@@ -115,7 +121,7 @@ class TpuRun:
         # run's bytes into another chip's budget bucket.
         self.jax_device = device if device is not None else _place_run()
         self._res_key = hbm_cache().register(
-            self, device_tracker, "run",
+            self, device_tracker, label,
             device=device_label(self.jax_device))
 
     def _build_dev(self):
@@ -124,14 +130,16 @@ class TpuRun:
         # is left of them shows in the first fetch's wait)
         with trace.span("engine.upload", metrics.device_upload_histogram(),
                         seconds=True) as sp:
-            d = DeviceRun(self.crun, PAD_BLOCKS, device=self.jax_device)
+            d = DeviceRun(self.crun, self.pad_blocks,
+                          device=self.jax_device)
             sp.labels["bytes"] = nbytes = d.nbytes
         metrics.count_device_upload_bytes(nbytes)
         return d, nbytes
 
     def _nbytes_hint(self) -> int:
         if self._dev_nbytes_hint is None:
-            self._dev_nbytes_hint = plane_nbytes(self.crun, PAD_BLOCKS)
+            self._dev_nbytes_hint = plane_nbytes(self.crun,
+                                                 self.pad_blocks)
         return self._dev_nbytes_hint
 
     @property
@@ -251,7 +259,8 @@ def _sig_read_bytes(arrays: dict, sig) -> int:
 
 def _batch_route(plans: list) -> str:
     """A batch's label: ``_plan_scan``'s tag when its plans share one
-    (host, page, issued, agg_deferred, grouped_deferred, gather)."""
+    (host, page, issued, agg_deferred, grouped_deferred,
+    overlay_deferred, gather)."""
     tags = {plan[0] for plan in plans}
     return tags.pop() if len(tags) == 1 else "mixed" if tags else "empty"
 
@@ -293,10 +302,15 @@ class _OverlayState:
     masked primary, the key-sorted dirty rows with a parallel key list
     and by-key map (what the incremental copy-on-write update bisects
     into), the cleared primary row indices, the memtable version count
-    the state includes, and the per-read-point host-partial cache."""
+    the state includes, the per-read-point host-partial cache, and
+    ``delta``: the dirty keys' version lists as ONE small multi-version
+    run on the device (a TpuRun, built by the first grouped aggregate
+    that needs it: TpuStorageEngine._overlay_delta_run), retired and
+    forgotten when the engine's cache lets the state go (``dropped``: a
+    scan that still holds the state then builds a run of its own)."""
 
     __slots__ = ("masked", "rows", "keys", "by_key", "idx", "mem_count",
-                 "partial")
+                 "partial", "delta", "dropped")
 
     def __init__(self, masked, rows, keys, by_key, idx, mem_count):
         self.masked = masked
@@ -306,6 +320,8 @@ class _OverlayState:
         self.idx = idx
         self.mem_count = mem_count
         self.partial: dict = {}
+        self.delta: TpuRun | None = None
+        self.dropped = False
 
 
 # _overlay_apply_delta verdict: the delta can't be applied (no memtable
@@ -353,6 +369,10 @@ class TpuStorageEngine(StorageEngine):
         # residency entry until the cache is dropped.
         self._overlay_pinned: TpuRun | None = None
         self._overlay_ext_key: int | None = None
+        # Serializes the lazy build and the pin of a state's mini-run
+        # against the cache letting that state go (one builds and pins,
+        # the other retires).
+        self._overlay_delta_lock = threading.Lock()
         # Fault domain: the breaker quarantines the device dispatch path
         # after repeated device faults; while open (and for one probe's
         # worth of half-open) every scan re-serves byte-identically from
@@ -1075,6 +1095,7 @@ class TpuStorageEngine(StorageEngine):
         the primary run and its masked-valid residency accounting. Must
         run whenever the run set changes (flush/compact/restore/alter) —
         validity checks alone would leak the pin."""
+        self._retire_overlay_delta(None)
         self._overlay_cache = None
         if self._overlay_pinned is not None:
             self._overlay_pinned.unpin()
@@ -1740,6 +1761,7 @@ class TpuStorageEngine(StorageEngine):
             try:
                 batch = self._issue_batch(specs, deadline)
                 route = batch.route
+                sp.labels["sources"] = batch.sources
             finally:
                 _set_phase(sp, "issue", route)
         return batch
@@ -1771,8 +1793,10 @@ class TpuStorageEngine(StorageEngine):
             with _issue_part("plan"):
                 agg_sink: list = []
                 grouped_sink: list = []
+                batch = {"pins": pins}
                 plans = [self._plan_scan(s, agg_sink=agg_sink,
-                                         grouped_sink=grouped_sink)
+                                         grouped_sink=grouped_sink,
+                                         batch=batch)
                          for s in specs]
 
                 results: list = [None] * len(plans)
@@ -1783,6 +1807,7 @@ class TpuStorageEngine(StorageEngine):
                 pre_work = []
                 deferred: list = []
                 gdeferred: list = []
+                odeferred: list = []
                 for pi, plan in enumerate(plans):
                     if plan[0] == "host":
                         host_plans.append((pi, plan[1]))
@@ -1796,6 +1821,8 @@ class TpuStorageEngine(StorageEngine):
                         deferred.append(pi)
                     elif plan[0] == "grouped_deferred":
                         gdeferred.append(pi)
+                    elif plan[0] == "overlay_deferred":
+                        odeferred.append((pi, plan[1]))
                     else:
                         gathers.append((pi, plan[1]))
                 # Residency pins for the issue→finish window: every run a
@@ -1839,6 +1866,10 @@ class TpuStorageEngine(StorageEngine):
                              for pi, (trun, spec, exact, payload)
                              in zip(gdeferred, grouped_sink)]
                     issued_outs.extend(self._plan_grouped_batch(items))
+                # Multi-source grouped aggregates: two programs a spec,
+                # over the overlay's masked primary and its mini-run.
+                issued_outs.extend(
+                    (pi, *dispatch()) for pi, dispatch in odeferred)
                 # Page items defer wholesale to finish() (device work
                 # first); host_page.serve_pages runs them through the
                 # native page server.
@@ -1860,7 +1891,8 @@ class TpuStorageEngine(StorageEngine):
                                gathers, states, pending, dispatches,
                                pages, pre_work, pins, specs=specs,
                                deadline=deadline,
-                               route=_batch_route(plans))
+                               route=_batch_route(plans),
+                               sources=batch.get("sources", 1))
         except BaseException:
             for trun in pins:
                 trun.unpin()
@@ -2149,13 +2181,20 @@ class TpuStorageEngine(StorageEngine):
             off += n_take
 
     def _plan_scan(self, spec: ScanSpec, agg_sink: list | None = None,
-                   grouped_sink: list | None = None):
+                   grouped_sink: list | None = None,
+                   batch: dict | None = None):
         """-> ("host", finish()) | ("issued", outs, finish(fetched))
            | ("gather", _GatherScan) | ("agg_deferred",) /
            ("grouped_deferred",) for single-source device (grouped)
            aggregates, which land in the sinks — the caller dispatches
            those together (one vmapped program per signature group;
-           _plan_device_aggregate_batch / _plan_grouped_batch)."""
+           _plan_device_aggregate_batch / _plan_grouped_batch)
+           | ("overlay_deferred", dispatch) for a grouped aggregate over
+           several sources (_plan_overlay_grouped).
+           ``batch``: the scan batch's ``pins`` (runs a plan pinned for
+           the issue -> finish window; without a batch the plan unpins
+           them itself) and ``sources`` (the most sources a spec of the
+           batch read, where more than one)."""
         if agg_sink is None:
             agg_sink = []
         if grouped_sink is None:
@@ -2203,15 +2242,36 @@ class TpuStorageEngine(StorageEngine):
             if eligible and single_source and runs:
                 agg_sink.append((runs[0], spec, exact))
                 return ("agg_deferred",)
-            if eligible and not single_source and (runs or mem_live):
+            if not single_source and (runs or mem_live):
                 # Multi-source (overlapping runs / live memtable): the
                 # cached delta overlay keeps this a pure device scan —
                 # primary run with dirty keys masked out of its valid
-                # plane + a mini-run holding the dirty keys' full merged
-                # version sets (disjoint partials, combined on host).
-                ov = self._overlay(mem)
-                if ov is not None:
-                    return self._plan_overlay_aggregate(ov, spec, exact)
+                # plane + the dirty keys' full merged version sets, as
+                # a device mini-run for a grouped program and a cached
+                # host fold for a flat one (disjoint partials, combined
+                # on host). Counted once, whoever serves it.
+                if batch is not None:
+                    batch["sources"] = max(batch.get("sources", 1),
+                                           len(runs) + bool(mem_live))
+                if spec.group_by or has_expr:
+                    if superset or host_only:
+                        metrics.count_overlay_scan("grouped", "host", "spec")
+                    else:
+                        plan = self._plan_overlay_grouped(
+                            mem, spec, exact, batch,
+                            lambda: self._row_scan(
+                                spec, runs, mem_live, pred_split,
+                                aggregate=True, mem=mem))
+                        if plan is not None:
+                            return plan
+                elif not eligible:
+                    metrics.count_overlay_scan("flat", "host", "spec")
+                else:
+                    ov = self._overlay(mem)
+                    if ov is not None:
+                        metrics.count_overlay_scan("flat", "device")
+                        return self._plan_overlay_aggregate(ov, spec, exact)
+                    metrics.count_overlay_scan("flat", "host", "dirty_set")
             if single_source and runs:
                 return ("gather", self._plan_gather(
                     runs[0], spec, pred_split, aggregate=True))
@@ -2885,24 +2945,44 @@ class TpuStorageEngine(StorageEngine):
 
 
     def _finish_grouped(self, crun, spec, sig, vec, fallback):
-        from yugabyte_db_tpu.ops import group_agg
+        """One program's fetched vector to the scan's result."""
+        return self._finish_grouped_programs(spec, [(crun, sig, vec)],
+                                             fallback)
 
-        def give_up(reason):
-            # The program's answer is thrown away and the scan served
-            # again as a host row scan: never silently.
-            metrics.count_grouped_agg_fallback(reason)
-            return fallback()
+    def _finish_grouped_programs(self, spec, programs, fallback):
+        """The fetched vectors of the programs that answer ONE scan over
+        disjoint key sets, ``[(crun, sig, vec)]``, to its result; a
+        partial the host cannot take (``_grouped_partial``) throws the
+        programs' answers away and serves the scan again as a host row
+        scan: never silently."""
+        partials = []
+        for crun, sig, vec in programs:
+            part = self._grouped_partial(crun, spec, sig, vec)
+            if isinstance(part, str):
+                metrics.count_grouped_agg_fallback(part)
+                return fallback()
+            partials.append(part)
+        return self._grouped_result(spec, partials)
+
+    def _grouped_partial(self, crun, spec, sig, vec):
+        """Decode one grouped program's fetched vector over ``crun``:
+        -> (scanned, {group values: [(value, inputs seen) an aggregate]})
+        with sums as the digit vectors' integers and a count as (n, 1),
+        or the reason the answer cannot be used (``negs``, ``collision``,
+        ``decode``). Groups are keyed by VALUE: every run has its own
+        dictionary and hash table, so bucket numbers of two runs do not
+        correspond."""
+        from yugabyte_db_tpu.ops import group_agg
 
         res = group_agg.unpack(sig, vec)
         NB = sig.NB
         count = np.asarray(res["count"])[:NB]
         live = np.nonzero(count > 0)[0]
         if int(res["negs"]) > 0:
-            return give_up("negs")  # negative base values: digits invalid
+            return "negs"  # negative base values: digits invalid
         if int(res["collisions"]) > 0:
-            return give_up("collision")  # two groups, one bucket
-        group_names = list(spec.group_by or [])
-        rows = []
+            return "collision"  # two groups, one bucket
+        groups = {}
         if not sig.radix:
             keys = np.asarray(res["key"])[:NB]
             reps = np.asarray(res["rep"])[:NB]
@@ -2911,27 +2991,42 @@ class TpuStorageEngine(StorageEngine):
                      else self._decode_group(crun, spec, sig, keys[b],
                                              int(reps[b])))
             if gvals is None:
-                return give_up("decode")
+                return "decode"
             aggs = []
-            for i, (a, ga) in enumerate(zip(spec.aggregates, sig.aggs)):
+            for i, ga in enumerate(sig.aggs):
                 if ga.kind == "count":
-                    aggs.append(int(np.asarray(res[f"a{i}"])[b]))
+                    aggs.append((int(np.asarray(res[f"a{i}"])[b]), 1))
                 else:
                     digits = np.asarray(res[f"a{i}"])[b]
                     v = sum(int(d) << (16 * k)
                             for k, d in enumerate(digits))
-                    # SQL sum over zero non-null inputs is NULL.
-                    n_in = int(np.asarray(res[f"n{i}"])[b])
-                    aggs.append(v if n_in else None)
-            rows.append(tuple(gvals) + tuple(aggs))
-        if not rows and not spec.group_by:
+                    aggs.append((v, int(np.asarray(res[f"n{i}"])[b])))
+            groups[tuple(gvals)] = aggs
+        return int(res["scanned"]), groups
+
+    def _grouped_result(self, spec, partials):
+        """Combine the partials of disjoint key sets (one a program),
+        order and name: counts and sums add by group value, a group may
+        come from one partial alone, and a sum over zero non-null inputs
+        in all of them is NULL."""
+        scanned = sum(p[0] for p in partials)
+        merged: dict = {}
+        for _scanned, groups in partials:
+            for g, aggs in groups.items():
+                have = merged.get(g)
+                merged[g] = aggs if have is None else [
+                    (v + w, n + m) for (v, n), (w, m) in zip(have, aggs)]
+        group_names = list(spec.group_by or [])
+        if not merged and not group_names:
             agg = Aggregator(spec.aggregates, [])
             return ScanResult(agg.column_names(), agg.results(), None,
-                              int(res["scanned"]))
+                              scanned)
+        rows = [g + tuple(v if n else None for v, n in aggs)
+                for g, aggs in merged.items()]
         rows.sort(key=lambda r: tuple(
             (v is None, v) for v in r[:len(group_names)]))
         names = group_names + [a.output_name for a in spec.aggregates]
-        return ScanResult(names, rows, None, int(res["scanned"]))
+        return ScanResult(names, rows, None, scanned)
 
     def _decode_codes(self, crun, sig, bucket: int):
         """The direct form: a bucket's group values, read off the run's
@@ -3051,16 +3146,18 @@ class TpuStorageEngine(StorageEngine):
     @staticmethod
     @compile_contract("scatter_invalid_bits", max_compiles=64)
     @jax.jit
-    def scatter_invalid_bits(bw, idx):
-        """Bit-packed valid plane (--tpu_plane_encoding): decode the
-        words and scatter-clear in ONE fused program — the masked
-        overlay substitutes a plain bool plane, which every kernel
-        accepts because decode dispatch is per-leaf."""
-        B, W = bw.shape
-        bits = (bw[:, :, None] >> jnp.arange(32, dtype=jnp.int32)) \
-            & jnp.int32(1)
-        flat = bits.astype(jnp.bool_).reshape(B * W * 32)
-        return flat.at[idx].set(False, mode="drop").reshape(B, W * 32)
+    def scatter_invalid_bits(bw, widx, keep):
+        """Bit-packed valid plane (--tpu_plane_encoding): clear the
+        dirty rows' bits in the packed words themselves, ``bw[widx] &=
+        keep`` (``widx``: the flat numbers of the words that hold a
+        dirty row, each once, padded out of range; ``keep``: the word's
+        mask with those rows' bits 0). The masked plane stays a "bits"
+        leaf, so a program over the masked primary has the signature of
+        the run's own (ops.group_agg's packed form, the very programs a
+        single-source scan compiled)."""
+        flat = bw.reshape(-1)
+        cur = flat.at[widx].get(mode="fill", fill_value=0)
+        return flat.at[widx].set(cur & keep, mode="drop").reshape(bw.shape)
 
     def _overlay(self, mem):
         """The cached delta-overlay state for the current engine content:
@@ -3080,12 +3177,15 @@ class TpuStorageEngine(StorageEngine):
           bucketed index vector (KBs), never a full mask plane;
         - scans = one already-compiled flat dispatch over the masked
           primary + a cached host fold of the dirty rows (exact MVCC
-          merge + predicates at the spec's read point).
+          merge + predicates at the spec's read point); a grouped
+          aggregate folds the dirty rows on the device instead, as a
+          second dispatch over the state's mini-run
+          (_plan_overlay_grouped, _overlay_delta_run).
 
         Nothing here builds a device run or compiles a multi-version
-        kernel, so the first post-write scan pays only the dirty-set
-        collection (the VERDICT-flagged 3s rebuild was the overlay
-        mini-run's upload + lookback compile + a 26MB mask upload).
+        kernel (the mini-run is built by the first grouped aggregate
+        that needs it), so the first post-write flat scan pays only the
+        dirty-set collection.
         Rebuilds amortize two ways: (run-set identity, memtable version
         counter) keying makes the steady-state scan a pure cache hit,
         and when only the version counter moved the state is advanced
@@ -3107,12 +3207,38 @@ class TpuStorageEngine(StorageEngine):
                 if c_ver == mem.num_versions:
                     return state
                 if state is not None and mem.num_versions > c_ver:
-                    inc = self._overlay_apply_delta(state, mem, c_ver)
+                    with self._overlay_build_span("delta") as sp:
+                        inc = self._overlay_apply_delta(state, mem, c_ver)
+                        if inc is not None and inc is not _OVERLAY_REBUILD:
+                            self._note_overlay_built(sp, inc)
                     if inc is not _OVERLAY_REBUILD:
                         ver = (inc.mem_count if inc is not None
                                else mem.num_versions)
                         self._cache_overlay(runs, mem, inc, ver)
                         return inc
+        with self._overlay_build_span("full") as sp:
+            state = self._overlay_build(runs, mem)
+            if state is not None:
+                self._note_overlay_built(sp, state)
+        return state
+
+    @staticmethod
+    def _overlay_build_span(how: str):
+        """Span ``engine.overlay.build`` -> ``yb_overlay_build_us{how}``."""
+        return trace.span("engine.overlay.build",
+                          metrics.overlay_build_histogram(how), how=how)
+
+    @staticmethod
+    def _note_overlay_built(sp, state: _OverlayState) -> None:
+        versions = sum(len(e[1]) for e in state.rows)
+        sp.labels.update(dirty_keys=len(state.rows),
+                         primary_rows_masked=int(state.idx.size),
+                         delta_versions=versions)
+        metrics.set_overlay_size(len(state.rows), versions)
+
+    def _overlay_build(self, runs, mem):
+        """The full build: every dirty key collected and merged over all
+        sources, the primary masked (``_overlay``)."""
         primary = max(runs, key=lambda t: t.crun.total_rows())
         deltas = [t for t in runs if t is not primary]
 
@@ -3172,28 +3298,46 @@ class TpuStorageEngine(StorageEngine):
         return state
 
     def _masked_primary(self, primary: TpuRun, idx) -> _MaskedRun:
-        """The primary's device arrays with ``idx`` rows scatter-cleared
-        from the valid plane; the index vector pads to a _MASK_BUCKETS
-        size so at most a handful of scatter programs ever compile."""
+        """The primary's device arrays with ``idx`` rows cleared from the
+        valid plane, which keeps the leaf kind it has (a plain bool
+        plane, or packed words); the index vector pads to a
+        _MASK_BUCKETS size so at most a handful of scatter programs ever
+        compile."""
         vleaf = primary.dev.arrays["valid"]
-        packed = encodings.leaf_kind(vleaf) == "bits"
-        size = (vleaf["bits"]["bw"].size * 32 if packed else vleaf.size)
-        bucket = next((b for b in self._MASK_BUCKETS
-                       if b >= idx.size), idx.size)
-        # Pad with an out-of-range index; mode="drop" discards it.
-        pidx = np.full(bucket, size, dtype=np.int32)
-        pidx[:idx.size] = idx
-        masked_valid = (
-            TpuStorageEngine.scatter_invalid_bits(
-                vleaf["bits"]["bw"], jnp.asarray(pidx)) if packed
-            else TpuStorageEngine.scatter_invalid(
-                vleaf, jnp.asarray(pidx)))
+        if encodings.leaf_kind(vleaf) == "bits":
+            bw = vleaf["bits"]["bw"]
+            # A row's bit: word row // 32 of the flat plane (a block's R
+            # is a multiple of 32), bit row % 32.
+            words, at = np.unique(idx >> 5, return_inverse=True)
+            keep = np.full(words.size, -1, np.int32)
+            np.bitwise_and.at(
+                keep, at,
+                ~(np.int32(1) << (idx & 31).astype(np.int32)))
+            bucket = next((b for b in self._MASK_BUCKETS
+                           if b >= words.size), words.size)
+            # Pad with an out-of-range word; mode="drop" discards it.
+            pwords = np.full(bucket, bw.size, dtype=np.int32)
+            pwords[:words.size] = words
+            pkeep = np.full(bucket, -1, dtype=np.int32)
+            pkeep[:words.size] = keep
+            masked_valid = {"bits": {"bw": (
+                TpuStorageEngine.scatter_invalid_bits(
+                    bw, jnp.asarray(pwords), jnp.asarray(pkeep)))}}
+        else:
+            bucket = next((b for b in self._MASK_BUCKETS
+                           if b >= idx.size), idx.size)
+            # Pad with an out-of-range index; mode="drop" discards it.
+            pidx = np.full(bucket, vleaf.size, dtype=np.int32)
+            pidx[:idx.size] = idx
+            masked_valid = TpuStorageEngine.scatter_invalid(
+                vleaf, jnp.asarray(pidx))
         masked_arrays = dict(primary.dev.arrays, valid=masked_valid)
         return _MaskedRun(primary, masked_arrays)
 
     def _cache_overlay(self, runs, mem, state, ver) -> None:
         """Publish an overlay cache entry, moving the primary-run pin
         and the masked-valid residency accounting with it."""
+        self._retire_overlay_delta(state)
         new_primary = state.masked.source if state is not None else None
         old = self._overlay_pinned
         if old is not new_primary:
@@ -3305,6 +3449,118 @@ class TpuStorageEngine(StorageEngine):
             masked = state.masked
         return _OverlayState(masked, rows, keys, by_key, idx,
                              since + len(delta))
+
+    def _retire_overlay_delta(self, keep) -> None:
+        """The cache is about to let its state go (for ``keep``, or for
+        nothing): retire that state's mini-run, its device bytes back
+        with the tracker once no scan in flight pins it."""
+        cache = self._overlay_cache
+        old = cache[3] if cache is not None else None
+        if old is None or old is keep:
+            return
+        with self._overlay_delta_lock:
+            old.dropped = True
+            if old.delta is not None:
+                old.delta.retire()
+                old.delta = None
+
+    def _overlay_delta_run(self, state: _OverlayState):
+        """The dirty keys' full version lists as ONE small multi-version
+        run on the device, PINNED: -> (the TpuRun, its DeviceRun); the
+        caller unpins. Built once a state, by the first grouped
+        aggregate that needs it (a flat aggregate folds the dirty rows
+        on the host and never asks), uploaded through the residency
+        manager as any run (label ``overlay_delta``), with plain planes
+        (``ColumnarRun.plain_planes``) and its block axis padded to its
+        own power of two, so the programs over it have one signature a
+        size class. Per-row Python belongs here, to the BUILD; a scan
+        folds the run with a device program. The pin is taken under the
+        lock that ``_retire_overlay_delta`` retires under, so a state the
+        cache lets go at any moment leaves this scan a registered,
+        accounted run until its unpin."""
+        with self._overlay_delta_lock:
+            delta = state.delta
+            if delta is None:
+                with self._overlay_build_span("mini_run") as sp:
+                    crun = ColumnarRun.build(
+                        self.schema, [(e[0], e[1]) for e in state.rows],
+                        self.rows_per_block, plain_planes=True)
+                    pad = 1 << (crun.B - 1).bit_length()
+                    delta = TpuRun(crun, self.device_tracker,
+                                   pad_blocks=pad, label="overlay_delta")
+                    sp.labels.update(dirty_keys=len(state.rows),
+                                     delta_versions=crun.num_versions,
+                                     blocks=pad)
+                if not state.dropped:
+                    state.delta = delta
+            dev = delta.pin("high")
+            if state.dropped:
+                # A reader of a state the cache already let go: the run
+                # serves this scan alone and goes with its pin.
+                delta.retire()
+        return delta, dev
+
+    def _plan_overlay_grouped(self, mem, spec: ScanSpec, exact_preds,
+                              batch, host_scan):
+        """A GROUP BY / expression aggregate over several sources as two
+        dispatches of the grouped program and ONE fetch
+        (-> ("overlay_deferred", dispatch), which the batch calls in its
+        dispatch part: -> (outs, finish(fetched))): over the masked
+        primary (as flat as the run is: with its packed valid plane kept
+        packed, the program a single-source scan compiled) and over the
+        overlay's mini-run (the MVCC resolve at the spec's read point
+        inside the program, so a read point before a delete still sees
+        the row). The two cover disjoint key sets; their partials are
+        combined by group value (``_grouped_result``). None where the
+        overlay does not apply (the dirty set passed half the primary)
+        or the spec cannot be lowered: the caller's host row scan, which
+        is also what a partial the host cannot take falls back to
+        (``yb_grouped_agg_fallbacks``)."""
+        from yugabyte_db_tpu.ops import group_agg
+        from yugabyte_db_tpu.utils.sync_point import sync_point
+
+        ov = self._overlay(mem)
+        sync_point("tpu_engine:overlay_grouped:state_taken")
+        if ov is None:
+            metrics.count_overlay_scan("grouped", "host", "dirty_set")
+            return None
+        prep = self._grouped_prep(ov.masked, spec, exact_preds)
+        if prep is None:
+            metrics.count_overlay_scan("grouped", "host", "spec")
+            return None
+        delta, delta_dev = self._overlay_delta_run(ov)
+        if batch is not None:
+            batch["pins"].append(delta)
+        try:
+            prep_d = self._grouped_prep(delta, spec, exact_preds)
+        finally:
+            if batch is None:
+                delta.unpin()
+        if prep_d is None:
+            metrics.count_overlay_scan("grouped", "host", "spec")
+            return None
+
+        def dispatch():
+            outs, decode = [], []
+            for dev, crun, entry, (kind, payload) in (
+                    (ov.masked.dev, ov.masked.crun, "grouped_aggregate",
+                     prep),
+                    (delta_dev, delta.crun, "overlay_delta_aggregate",
+                     prep_d)):
+                if kind == "empty":
+                    continue
+                sig, params = payload
+                out = group_agg.compiled_grouped(sig)(dev.arrays, params)
+                _count_grouped_dispatch(entry, dev, sig, params, out)
+                outs.append(out)
+                decode.append((crun, sig))
+            metrics.count_overlay_scan("grouped", "device")
+            return outs, lambda fetched: self._finish_grouped_programs(
+                spec, [(crun, sig, vec)
+                       for (crun, sig), vec in zip(decode, fetched)],
+                host_scan)
+
+        return ("overlay_deferred", dispatch)
 
     def _overlay_host_partial(self, ov, spec: ScanSpec):
         """Exact host fold of the dirty rows at spec's read point:
@@ -3617,8 +3873,10 @@ class _AsyncBatch:
 
     def __init__(self, eng, results, host_plans, issued_outs, gathers,
                  states, pending, dispatches, pages=(), pre_work=(),
-                 pins=(), specs=(), deadline=None, route="mixed"):
+                 pins=(), specs=(), deadline=None, route="mixed",
+                 sources=1):
         self.route = route        # _batch_route of the plans
+        self.sources = sources    # the most sources one of its specs read
         self._fetch_ns = 0        # phase "wait_fetch", summed over rounds
         self.eng = eng
         self.results = results
@@ -3744,6 +4002,7 @@ class _HostServeBatch:
     pins are held; finish() serves the whole batch from the host."""
 
     route = "breaker_host"
+    sources = 0     # (nothing was planned: the host serves every spec)
 
     def __init__(self, eng, specs, deadline=None):
         self.eng = eng

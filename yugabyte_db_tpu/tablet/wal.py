@@ -207,7 +207,15 @@ class Log:
         (a partial last record after a crash is dropped, matching WAL
         recovery semantics)."""
         for path in self.segment_paths():
-            entries, clean = self._read_segment(path, min_index)
+            try:
+                entries, clean = self._read_segment(path, min_index)
+            except FileNotFoundError:
+                # Unlinked between the listing and the open, by another
+                # thread's gc (whole segments below the flushed frontier:
+                # nothing a replay still needs) or divergence repair (a
+                # dropped suffix): read on as if it had gone before the
+                # listing.
+                continue
             yield from entries
             if not clean:
                 return  # stop replay at first torn/corrupt record globally

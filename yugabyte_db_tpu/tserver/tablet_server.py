@@ -1361,14 +1361,32 @@ class TabletServer:
                                     "leader_hint": peer.raft.leader_uuid()}
             peers.append(peer)
         spec = wire.decode_spec(p["spec"])
+        # One deadline across ALL waits: serial per-peer waits must not
+        # sum past the client's propagated budget.
+        deadline = self._rpc_deadline(p)
         if spec.read_ht == wire.MAX_HT:
+            prop = p.get("propagated_ht") or 0
+            if prop:
+                from yugabyte_db_tpu.utils.hybrid_time import HybridTime
+
+                # Session read-your-writes under pipelined apply, as in
+                # _read_gate: a write is acked at COMMIT and its pending
+                # HT holds safe time below it until the apply lands, so
+                # a fresh read waits for safe time to reach what the
+                # client already observed. Without the wait the tablets
+                # still look flushed and idle, and the mesh would answer
+                # from below an acknowledged write.
+                seen = HybridTime(prop)
+                for peer in peers:
+                    peer.tablet.clock.update(seen)
+                    if not peer.tablet.mvcc.wait_for_safe_time(
+                            seen,
+                            timeout=deadline.timeout(self.READ_GATE_WAIT_S)):
+                        return None, None, {"code": "timed_out"}
             # Every tablet can already serve its own safe time; the min is
             # serveable by all without waiting and repeatable everywhere.
             spec.read_ht = min(pr.read_time().value for pr in peers)
         else:
-            # One deadline across ALL pins: serial per-peer waits must not
-            # sum past the client's propagated budget.
-            deadline = self._rpc_deadline(p)
             for peer in peers:
                 if deadline.expired():
                     return None, None, {"code": "timed_out"}

@@ -44,6 +44,7 @@ import json
 import os
 import threading
 import time
+import weakref
 import zlib
 
 # entry name -> declared max_compiles, in registration order. Filled at
@@ -264,7 +265,7 @@ class ContractedJit:
     Transparent otherwise — attribute access delegates to the jit
     object, so ``.lower``/``.clear_cache`` etc. keep working."""
 
-    __slots__ = ("_fn", "_entry")
+    __slots__ = ("_fn", "_entry", "__weakref__")
 
     def __init__(self, fn, entry: str):
         self._fn = fn
@@ -332,10 +333,25 @@ def compile_contract(entry: str, max_compiles: int):
             wrapped = ContractedJit(obj, entry)
             return wrapped
 
+        # One wrapper a signature while anything holds it: threads that
+        # miss the ``lru_cache`` above at the same moment (two tablets'
+        # first scans of a new query, a statement asked again) each call
+        # the factory, and each jit object of their own would trace and
+        # compile the same program side by side under the GIL. One jit
+        # object compiles once: jax makes its other callers wait.
+        made = weakref.WeakValueDictionary()
+        lock = threading.Lock()
+
         @functools.wraps(obj)
         def factory(*args, **kwargs):
-            out = obj(*args, **kwargs)
-            return ContractedJit(out, entry) if _is_jitted(out) else out
+            key = (args, tuple(sorted(kwargs.items())))
+            with lock:
+                out = made.get(key)
+                if out is None:
+                    out = obj(*args, **kwargs)
+                    if _is_jitted(out):
+                        out = made[key] = ContractedJit(out, entry)
+            return out
 
         factory.__compile_contract__ = (entry, max_compiles)
         return factory

@@ -884,6 +884,55 @@ def grouped_agg_fallbacks() -> dict[str, int]:
 grouped_agg_fallbacks()
 
 
+@functools.cache
+def overlay_build_histogram(how: str) -> Histogram:
+    """``yb_overlay_build_us{how=full|delta|mini_run}``: one build of
+    the delta overlay's state (span ``engine.overlay.build``,
+    storage/tpu_engine.py ``_overlay``): ``full`` collects every dirty
+    key and masks the primary, ``delta`` advances a cached state by the
+    memtable's versions since, ``mini_run`` lays the dirty keys' version
+    lists out as the small device run a grouped aggregate folds."""
+    return _span_entity(("overlay_build", how), how=how).histogram(
+        "yb_overlay_build_us")
+
+
+def count_overlay_scan(kind: str, outcome: str, reason: str = "") -> None:
+    """``yb_overlay_scans{kind=grouped|flat, outcome=device|host,
+    reason}``: one aggregate over several sources (overlapping runs, a
+    live memtable): answered by device programs over the delta overlay,
+    or by the host row scan because the dirty set passed half the
+    primary (``dirty_set``) or the spec cannot be lowered (``spec``)."""
+    _span_entity(("overlay_scan", kind, outcome, reason), kind=kind,
+                 outcome=outcome, reason=reason).counter(
+                     "yb_overlay_scans").increment()
+
+
+def overlay_scans() -> dict[tuple, int]:
+    """Current ``yb_overlay_scans`` by (kind, outcome, reason)."""
+    return {key[1:]: ent.counter("yb_overlay_scans").get()
+            for key, ent in list(_SPAN_ENTITIES.items())
+            if key[0] == "overlay_scan"}
+
+
+def set_overlay_size(dirty_keys: int, delta_versions: int) -> None:
+    """Gauges ``yb_overlay_dirty_keys`` and ``yb_overlay_delta_versions``:
+    the dirty keys of the overlay state this process built last, and
+    the versions their merged lists hold (the mini-run's rows)."""
+    ent = _span_entity(("overlay_size",))
+    ent.gauge("yb_overlay_dirty_keys").set(dirty_keys)
+    ent.gauge("yb_overlay_delta_versions").set(delta_versions)
+
+
+# (the device outcomes read 0 on /metrics from the start)
+for _kind in ("grouped", "flat"):
+    _span_entity(("overlay_scan", _kind, "device", ""), kind=_kind,
+                 outcome="device", reason="").counter("yb_overlay_scans")
+    _span_entity(("overlay_scan", _kind, "host", "dirty_set"), kind=_kind,
+                 outcome="host", reason="dirty_set").counter(
+                     "yb_overlay_scans")
+set_overlay_size(0, 0)
+
+
 GROUPED_PRESENCE_FORMS = ("packed", "rows")
 
 

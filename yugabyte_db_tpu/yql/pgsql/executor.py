@@ -484,8 +484,9 @@ class PgProcessor:
             return [(kv, dict(zip(res.columns, r))) for r in res.rows]
         preds = self._predicates(schema, where)
         out = []
-        for tablet in handle.tablets:
+        for tablet, lower, upper in self._hash_section(handle, eq):
             res = tablet.scan(ScanSpec(
+                lower=lower, upper=upper,
                 read_ht=self._read_ht(tablet), predicates=preds))
             for r in res.rows:
                 d = dict(zip(res.columns, r))
@@ -493,6 +494,29 @@ class PgProcessor:
         if self._txn is not None:
             out = self._overlay_own_writes(handle, preds, out)
         return out
+
+    def _hash_section(self, handle, eq: dict):
+        """(tablet, lower, upper) to scan for a WHERE whose equalities
+        are ``eq``: where every hash column is bound (``DELETE ... WHERE
+        l_orderkey = k``: TPC-H's RF2), the one tablet that owns the
+        hash code and the key range of that hash section; else every
+        tablet, unbounded."""
+        from yugabyte_db_tpu.models.encoding import (encode_doc_key_prefix,
+                                                     prefix_successor)
+        from yugabyte_db_tpu.models.partition import compute_hash_code
+
+        schema = handle.schema
+        hash_cols = schema.hash_columns
+        if not hash_cols or any(
+                c.name not in eq or isinstance(eq[c.name], ast.SubQuery)
+                for c in hash_cols):
+            return [(t, b"", b"") for t in handle.tablets]
+        kv = {c.name: self._coerce(c, eq[c.name]) for c in hash_cols}
+        hc = compute_hash_code(schema, kv)
+        prefix = encode_doc_key_prefix(
+            hc, [(kv[c.name], c.dtype) for c in hash_cols], [])
+        return [(self.cluster.tablet_for_hash(handle, hc), prefix,
+                 prefix_successor(prefix))]
 
     def _txn_point_get(self, handle, kv):
         """Point resolution inside a txn: read-your-writes (own buffered
