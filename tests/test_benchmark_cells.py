@@ -137,6 +137,102 @@ def test_reply_counter_metric_resolves_and_reads_its_label(metric):
     assert read(spec["args"], {"registry": (before, after)}) == 42
 
 
+def _layer_metric(name):
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+def test_lookback_scans_metric_reads_the_resolve_counter():
+    """``overlay_delta_lookback_scans.refresh`` (PR 44) is data alone: the
+    reader that was there over ``yb_grouped_resolve{form="lookback"}``.
+    From a program without the series (the parent commit under this
+    benchmark) it reads 0 and the parent's traced line stays valid; from
+    one with it, the growth of its own label and of no other."""
+    import importlib
+
+    metric = "overlay_delta_lookback_scans.refresh"
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    assert entry == BENCH["per_layer"][-1]
+    assert entry == {
+        "name": metric, "unit": "programs", "better": "higher",
+        "source": "program_counter", "layer": "device programs",
+        "moves": "q6_p50_ms", "workloads": ["tpch_q1q6_after_refresh"]}
+    spec = _layer_metric(metric)
+    assert spec["layer"] == entry["layer"]
+    assert spec["reader"] == "span_counter_delta"
+    assert spec["args"] == {"name": "yb_grouped_resolve",
+                            "labels": {"form": "lookback"}}
+    read = importlib.import_module(
+        "benchmark.readers." + spec["reader"]).read
+    other = {("yb_grouped_buckets", (("form", "direct"),)): 7.0}
+    assert read(spec["args"], {"registry": (other, other)}) == 0
+
+    def series(flat, lookback, segmented):
+        return {("yb_grouped_resolve", (("form", f),)): float(n)
+                for f, n in (("flat", flat), ("lookback", lookback),
+                             ("segmented", segmented))}
+
+    assert read(spec["args"], {"registry": (series(9, 5, 1),
+                                            series(99, 47, 3))}) == 42
+
+
+def test_the_mini_runs_programs_are_the_modules_the_metrics_name():
+    """``overlay_delta_program_ms.refresh`` and
+    ``overlay_delta_hbm_roofline_pct.refresh`` name the mini-run's two
+    modules exactly, and ``named_program_time`` refuses a traced run in
+    which neither ran: the tag takes no ``lookback``, so the programs of
+    the benchmark's two statements over a run that is not flat carry
+    those names whichever way they resolve (and the flat ones cell 1's)."""
+    from tests.test_group_agg import benchmark_signatures
+    from yugabyte_db_tpu.ops import group_agg
+
+    def modules(metric):
+        return _layer_metric(metric)["args"]["modules"]
+
+    def names(**form):
+        return ["jit_" + group_agg.compiled_grouped(sig).__name__
+                for sig in benchmark_signatures(4, 2048, **form)]
+
+    assert names(flat=False, lookback=2) \
+        == names(flat=False, lookback=32) == names(flat=False) \
+        == modules("overlay_delta_program_ms.refresh") \
+        == modules("overlay_delta_hbm_roofline_pct.refresh")
+    assert names() == modules("q1_program_device_ms.power") \
+        + modules("q6_program_device_ms.power") \
+        == modules("overlay_base_program_ms.refresh")
+
+
+def test_a_statement_after_a_refresh_counts_its_resolve_forms():
+    """``yb_grouped_resolve{form}``, one a dispatch of an ops.group_agg
+    program (Q6's ungrouped one too): a multi-source Q1 or Q6 counts one
+    ``flat`` (the masked primary) and one ``lookback`` (the mini-run,
+    two versions a dirty key at most), ``segmented`` none."""
+    from tests.test_overlay_grouped import SPECS, Pair
+    from yugabyte_db_tpu.storage.row_version import MAX_HT
+    from yugabyte_db_tpu.utils import metrics
+
+    p = Pair(orders=60)
+    try:
+        p.refresh()
+        for name in ("q1", "q6"):
+            before = metrics.grouped_resolve()
+            p.same(name)
+            assert metrics.grouped_resolve() == dict(
+                before, flat=before["flat"] + 1,
+                lookback=before["lookback"] + 1)
+            delta = p.tpu._overlay_cache[3].delta
+            spec = SPECS[name](MAX_HT)
+            _kind, (sig, _params) = p.tpu._grouped_prep(delta, spec,
+                                                        spec.predicates)
+            assert (sig.flat, sig.lookback) == (False, 2)
+        text = metrics.process_registry().prometheus_text()
+        for form in metrics.GROUPED_RESOLVE_FORMS:
+            assert f'yb_grouped_resolve{{form="{form}"}}' in text
+    finally:
+        p.close()
+
+
 @pytest.mark.parametrize("trace", [0], ids=["plain"])
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_rehearses(cell, trace):

@@ -169,6 +169,81 @@ def test_multiversion_grouped():
     assert cpu.scan(spec2).rows == tpu.scan(spec2).rows
 
 
+# -- a run that is not flat: the lookback form is the segmented form ----------
+
+@pytest.fixture(scope="module")
+def three_versions():
+    """``test_multiversion_grouped``'s table: three versions a key."""
+    return _load(num=300, versions=3)
+
+
+def _both_resolves(tpu, trun, arrays, spec, hashed=False):
+    """``spec``'s packed vector over ``arrays`` from the program the
+    engine plans for ``trun`` (the lookback form) and from the same
+    signature with ``lookback=0`` (the segmented form): (the planned
+    signature, its parameters, the two vectors)."""
+    import dataclasses
+
+    from yugabyte_db_tpu.ops import group_agg
+
+    _kind, (sig, params) = tpu._grouped_prep(trun, spec, spec.predicates)
+    if hashed:
+        sig = dataclasses.replace(sig, NB=group_agg.NUM_BUCKETS, radix=())
+    segmented = dataclasses.replace(sig, lookback=0)
+    assert not sig.flat and sig.tag() == segmented.tag()
+    assert (sig.resolve_form, segmented.resolve_form) == ("lookback",
+                                                          "segmented")
+    return sig, params, [
+        np.asarray(group_agg.compiled_grouped(s)(arrays, params))
+        for s in (sig, segmented)]
+
+
+@pytest.mark.parametrize("shape", ["q1_direct", "q1_hashed", "q6_ungrouped"])
+def test_the_lookback_forms_vector_is_the_segmented_forms_bit_for_bit(
+        three_versions, shape):
+    """A grouped program over a run of three versions a key resolves its
+    window by bounded lookback (``_grouped_prep``: the next power of two
+    over the run's ``max_group_versions``); ``count``, ``rep``, ``key``,
+    ``collisions``, ``scanned``, ``negs`` and every digit sum are the
+    segmented resolve's to the bit, at a read point after every version
+    and at ones that see the older versions, and the answer is the CPU
+    oracle's."""
+    cpu, tpu, ht = three_versions
+    trun = tpu.runs[0]
+    assert trun.crun.max_group_versions == 3
+    for read_ht in (ht + 1, ht - 300, 10 + 450):
+        spec = ScanSpec(
+            read_ht=read_ht, aggregates=list(Q1_AGGS),
+            group_by=[] if shape == "q6_ungrouped" else ["flag", "status"],
+            predicates=[Predicate("d", "<", 900)])
+        sig, _params, (lookback, segmented) = _both_resolves(
+            tpu, trun, trun.dev.arrays, spec, hashed=shape == "q1_hashed")
+        assert sig.lookback == 4
+        assert bool(sig.radix) == (shape == "q1_direct")
+        assert (lookback == segmented).all(), (shape, read_ht)
+        assert int(lookback.sum()) != 0
+        assert cpu.scan(spec).rows == tpu.scan(spec).rows
+
+
+def test_a_run_past_the_lookback_bound_keeps_the_segmented_form():
+    """What the build recorded of the run decides: 33 versions of a key
+    are past ``lookback_fold.MAX_LOOKBACK``, the signature keeps
+    ``lookback == 0`` and its segment ops, and answers as the oracle."""
+    from yugabyte_db_tpu.ops import lookback_fold
+
+    cpu, tpu, ht = _load(num=12, versions=33)
+    assert tpu.runs[0].crun.max_group_versions == 33 \
+        > lookback_fold.MAX_LOOKBACK
+    for read_ht in (ht + 1, ht - 40):
+        spec = ScanSpec(read_ht=read_ht, aggregates=list(Q6_AGGS),
+                        predicates=[Predicate("d", ">=", 100)])
+        _kind, (sig, _params) = tpu._grouped_prep(tpu.runs[0], spec,
+                                                  spec.predicates)
+        assert (sig.flat, sig.lookback) == (False, 0)
+        assert sig.resolve_form == "segmented"
+        assert cpu.scan(spec).rows == tpu.scan(spec).rows
+
+
 def test_int32_group_column_and_count_col():
     cpu, tpu, ht = _load(num=1000)
     spec = ScanSpec(read_ht=ht + 1, group_by=["disc"],
@@ -534,21 +609,82 @@ def _equations(jaxpr, inside_kernel=False):
                         or eqn.primitive.name == "pallas_call")
 
 
-@pytest.mark.parametrize("group_by", [[], ["flag", "status"]],
-                         ids=["ungrouped", "grouped"])
+def _mini_run_programs_hold_no_serialized_op():
+    """The delta overlay's mini-run (the dirty keys' version lists after
+    inserts, overwrites and row tombstones: plain planes, not flat)
+    under the grouped (hashed: plain string planes) and the ungrouped
+    program: the lookback resolve leaves no ``scatter`` (a segment op),
+    ``gather``, ``cumsum`` or ``sort`` in the traced program but the
+    hashed kernel's own look-up of a row's bucket key, a lane gather
+    inside the kernel."""
+    import jax
+
+    from yugabyte_db_tpu.ops import group_agg
+
+    cpu, tpu, ht = _load(num=300)
+    schema = cpu.schema
+    cid = {c.name: c.col_id for c in schema.columns}
+
+    def key(i):
+        return schema.encode_primary_key(
+            {"k": f"r{i:06d}"}, compute_hash_code(schema, {"k": f"r{i:06d}"}))
+
+    writes = [RowVersion(key(i), ht=ht + 1, tombstone=True)
+              for i in range(0, 60, 3)]
+    writes += [RowVersion(key(i), ht=ht + 2, columns={cid["qty"]: 7})
+               for i in range(100, 130, 2)]
+    writes += [RowVersion(key(i), ht=ht + 3, liveness=True, columns={
+        cid["flag"]: "A", cid["status"]: "F", cid["qty"]: 3,
+        cid["price"]: 500, cid["disc"]: 1, cid["tax"]: 1, cid["d"]: 5})
+        for i in range(1000, 1010)]
+    for e in (cpu, tpu):
+        e.apply(list(writes))
+    for group_by in ([], ["flag", "status"]):
+        spec = ScanSpec(read_ht=ht + 4, group_by=group_by,
+                        aggregates=list(Q1_AGGS),
+                        predicates=[Predicate("d", "<", 900)])
+        assert cpu.scan(spec).rows == tpu.scan(spec).rows
+        delta = tpu._overlay_cache[3].delta
+        assert delta.crun.max_group_versions == 2
+        dev = delta.pin("high")
+        try:
+            sig, params, (lookback, segmented) = _both_resolves(
+                tpu, delta, dev.arrays, spec)
+            assert (lookback == segmented).all()
+            eqns = list(_equations(jax.make_jaxpr(functools.partial(
+                group_agg._packed, sig))(dev.arrays, params).jaxpr))
+        finally:
+            delta.unpin()
+        assert (sig.lookback, sig.radix) == (2, ())
+        names = [name for name, _e, _k in eqns]
+        assert names.count("pallas_call") == (1 if group_by else 0)
+        serialized = [(name, in_kernel) for name, _e, in_kernel in eqns
+                      if any(op in name for op in
+                             ("scatter", "gather", "cumsum", "sort"))]
+        # (one look-up a key plane a segment of 128 buckets)
+        assert set(serialized) <= {("gather", True)}, serialized
+        assert bool(serialized) == bool(group_by)
+
+
+@pytest.mark.parametrize("group_by", [[], ["flag", "status"], None],
+                         ids=["ungrouped", "grouped", "mini_run"])
 def test_no_scatter_in_the_lowered_program(group_by):
     """The guard that keeps a serialized TPU scatter from coming back:
     neither the ungrouped (Q6) nor the grouped flat (Q1) signature traces
     to one, inside the window loop or outside it. The grouped one is ONE
     kernel a window that holds the int8 product; what XLA is left with
     stacks the kernel's dozen row vectors and never the C columns of
-    pieces (PR 25's ``[C, N]`` operand, 1.08 ms a call on the v5e)."""
+    pieces (PR 25's ``[C, N]`` operand, 1.08 ms a call on the v5e).
+    ``mini_run``: nor do the programs over a run that is not flat, the
+    overlay's (60 segment ops and gathers of 72 us each before PR 44)."""
     import jax
 
     from yugabyte_db_tpu.ops import group_agg
 
     from yugabyte_db_tpu.ops import encodings
 
+    if group_by is None:
+        return _mini_run_programs_hold_no_serialized_op()
     _cpu, tpu, ht = _load(num=300, host_flush=True)
     spec = ScanSpec(read_ht=ht + 1, group_by=group_by,
                     aggregates=list(Q1_AGGS),
@@ -954,6 +1090,43 @@ def test_the_ungrouped_lowering_does_not_reach_the_packed_form(monkeypatch):
                                                                   params)
 
 
+# LINEITEM's value columns as the benchmark's DDL numbers them.
+BENCHMARK_KINDS = {12: "i32", 13: "i32", 14: "i32", 15: "i64", 16: "i32",
+                   17: "i32", 18: "str", 19: "str", 20: "i32", 21: "i32",
+                   22: "i32", 23: "str", 24: "str", 25: "str", 26: "str",
+                   27: "str"}
+
+
+def benchmark_signatures(K, R, flat=True, lookback=0):
+    """(Q1's, Q6's) signatures as the PG executor pushes the benchmark's
+    two statements down (Q1's ``avg``s as a sum and a count each), over
+    a run of ``K`` blocks of ``R`` rows in one window, hashed."""
+    from yugabyte_db_tpu.ops import group_agg, scan
+
+    G = group_agg.GAgg
+    disc, tax = ("-", ("k", 100), ("c", 16)), ("+", ("k", 100), ("c", 17))
+    shape = dict(
+        B=K, R=R, K=K, NB=group_agg.NUM_BUCKETS,
+        cols=tuple(scan.ColSig(c, k) for c, k in BENCHMARK_KINDS.items()),
+        apply_preds=True, flat=flat, lookback=lookback)
+    q1 = group_agg.GroupAggSig(
+        preds=(scan.PredSig(20, "i32", "<="),),
+        group_cols=((18, 2), (19, 2)),
+        aggs=(G("sum_prod", 14, 1, (), (14,)), G("sum_prod", 15, 2, (), (15,)),
+              G("sum_prod", 15, 2, (disc,), (15, 16)),
+              G("sum_prod", 15, 2, (disc, tax), (15, 16, 17)),
+              G("sum_prod", 14, 1, (), (14,)), G("count", 14, 1, (), (14,)),
+              G("sum_prod", 15, 2, (), (15,)), G("count", 15, 1, (), (15,)),
+              G("count", None, 1, (), ())), **shape)
+    q6 = group_agg.GroupAggSig(
+        preds=(scan.PredSig(20, "i32", ">="), scan.PredSig(20, "i32", "<"),
+               scan.PredSig(16, "i32", ">="), scan.PredSig(16, "i32", "<="),
+               scan.PredSig(14, "i32", "<")),
+        group_cols=(),
+        aggs=(G("sum_prod", 15, 2, (("c", 16),), (15, 16)),), **shape)
+    return q1, q6
+
+
 @pytest.fixture(scope="module")
 def one_described_v5e():
     """A sharding on one chip of a described, not attached, v5e: the
@@ -995,23 +1168,8 @@ def test_q1_compiles_for_the_v5e_with_one_relayout_of_its_masks(
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     K, R = 384, 2048
-    kinds = {12: "i32", 13: "i32", 14: "i32", 15: "i64", 16: "i32",
-             17: "i32", 18: "str", 19: "str", 20: "i32", 21: "i32",
-             22: "i32", 23: "str", 24: "str", 25: "str", 26: "str",
-             27: "str"}
-    G = group_agg.GAgg
-    disc, tax = ("-", ("k", 100), ("c", 16)), ("+", ("k", 100), ("c", 17))
-    sig = group_agg.GroupAggSig(
-        B=K, R=R, K=K, NB=group_agg.NUM_BUCKETS,
-        cols=tuple(scan.ColSig(c, k) for c, k in kinds.items()),
-        preds=(scan.PredSig(20, "i32", "<="),), apply_preds=True, flat=True,
-        group_cols=((18, 2), (19, 2)),
-        aggs=(G("sum_prod", 14, 1, (), (14,)), G("sum_prod", 15, 2, (), (15,)),
-              G("sum_prod", 15, 2, (disc,), (15, 16)),
-              G("sum_prod", 15, 2, (disc, tax), (15, 16, 17)),
-              G("sum_prod", 14, 1, (), (14,)), G("count", 14, 1, (), (14,)),
-              G("sum_prod", 15, 2, (), (15,)), G("count", 15, 1, (), (15,)),
-              G("count", None, 1, (), ())))
+    kinds = BENCHMARK_KINDS
+    sig, _q6 = benchmark_signatures(K, R)
     assert sig.tag() == "g2a9p1f1_d1ea91"     # the benchmark's Q1
 
     def S(shape, dtype=jnp.int32):

@@ -196,6 +196,44 @@ def test_a_key_deleted_and_inserted_again(pair, name):
     pair.same(name)
 
 
+# -- the mini-run's resolve: bounded lookback, the segmented form's bits --------
+
+@pytest.mark.parametrize("name", ["q1", "q6"])
+def test_the_mini_runs_lookback_program_is_the_segmented_one_bit_for_bit(
+        pair, name):
+    """The mini-run of a refresh holds an inserted row as one version and
+    a deleted or overwritten one as two: its programs (Q1's hashed, its
+    string planes being plain; Q6's ungrouped) resolve by a lookback of
+    2, and their packed vectors are the segmented resolve's to the bit
+    at read points before, between and after the writes."""
+    from tests.test_group_agg import _both_resolves
+
+    marks = [pair.ht]
+    pair.insert(range(10_000, 10_012))
+    marks.append(pair.ht)
+    pair.overwrite(range(20, 30))
+    pair.delete(range(1, 13))
+    marks += [pair.ht - 20, pair.ht, MAX_HT]
+    pair.same(name)
+    delta = pair.tpu._overlay_cache[3].delta
+    assert delta.crun.max_group_versions == 2
+    dev = delta.pin("high")
+    try:
+        vectors = []
+        for rp in marks:
+            sig, _params, (got, want) = _both_resolves(
+                pair.tpu, delta, dev.arrays, SPECS[name](rp))
+            assert (sig.lookback, sig.radix) == (2, ())
+            assert bool(sig.group_cols) == (name == "q1")
+            assert (got == want).all(), (name, rp)
+            vectors.append(got)
+    finally:
+        delta.unpin()
+    # (the read points are told apart: before the writes every dirty key
+    # that existed shows its base row, after them the deleted are gone)
+    assert (vectors[0] != vectors[-1]).any()
+
+
 # -- (e) a group only in the delta; a group whose every row is deleted --------
 
 def test_groups_that_come_and_go_with_the_delta():

@@ -3,7 +3,8 @@
 The TPC-H Q1 shape — few, low-cardinality groups over millions of rows —
 runs as ONE device dispatch: a fori_loop over windows (one window for a
 run of up to a million rows, ``window_blocks``) resolves MVCC visibility
-(ops.scan.resolve_window, which also decodes the run's encoded planes),
+(ops.scan.resolve_window or, for a run of few versions a key,
+lookback_fold.resolve_window; both decode the run's encoded planes),
 applies the predicates, and reduces the window into a fixed bucket table
 without a scatter (XLA serializes a TPU scatter: 9 ns a row; the twelve
 of Q1's old program were 71 of its 82 ms). The signature decides how,
@@ -59,6 +60,28 @@ nothing else:
     the presence planes (a device flush's run). Bit for bit the
     same words: the oracle of tests/test_group_agg.py.
   ``yb_grouped_presence{form}`` counts the programs traced in each form.
+  A window of a run that is not flat (by rows always, grouped or not)
+  merges each key's versions at the read point in one of two forms, by
+  what the build recorded of the run (``sig.lookback``, set in
+  ``_grouped_prep`` from ``max_group_versions``; no flag; not in
+  ``tag()``):
+  - *lookback*, where the run's largest key group is within
+    lookback_fold.MAX_LOOKBACK (the overlay's mini-run: a deleted row is
+    a tombstone over its base row, 2): ``lookback - 1`` static shifts
+    along the row axis and elementwise selects
+    (lookback_fold.resolve_window, the resolve ops.lookback_fold's
+    ungrouped fold runs too). A key group's entry is its first ROW,
+    which holds the group's merged planes as values: predicates and
+    planes read them as a flat window's, a row is its own rowid, and
+    the kernel is handed what a flat window hands it;
+  - *segmented*, past the bound: ops.scan.resolve_window's cumsum,
+    segment ops and gathers, each serialized on the TPU (72 us apiece
+    over 8,192 rows on the v5e, some 60 a call). A key group's entry is
+    its NUMBER in the window, its planes are gathered through
+    ``col_idx`` and the kernel takes the groups' rowids as one more row.
+  Bit for bit the same vector (tests/test_group_agg.py,
+  tests/test_grouped_lookback.py). ``yb_grouped_resolve{form}`` counts
+  the dispatches of each, and of flat programs.
   In the kernel:
   - sums and counts: the bucket one-hot ``[NB, T]`` (bucket == iota)
     times ONE matrix ``[C, T]`` of everything a bucket sums — the 0/1
@@ -121,7 +144,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from yugabyte_db_tpu.ops import encodings
+from yugabyte_db_tpu.ops import encodings, lookback_fold
 from yugabyte_db_tpu.ops.scan import (I32_MAX, _eval_pred, presence_is_packed,
                                       resolve_flat_packed, resolve_window)
 from yugabyte_db_tpu.utils import jitting, metrics
@@ -158,14 +181,34 @@ class GroupAggSig:
     # The direct form: each group column's dictionary cap (NB is their
     # product). () is the hashed form.
     radix: tuple = ()
+    # A bound on the versions of the run's largest key group
+    # (lookback_fold.bound of ``max_group_versions``: a power of two up
+    # to MAX_LOOKBACK) where the run is not flat: its windows resolve
+    # MVCC by that many static shifts. 0: by segment ops (or flat).
+    lookback: int = 0
 
     def tag(self) -> str:
         """What the query decides of the program, for its name
         (utils.jitting.tag): TPC-H Q1 is ``g2a8p1f1_...``, Q6
-        ``g0a1p4f1_...``, whatever the run's size and whichever way its
-        buckets are addressed."""
+        ``g0a1p4f1_...``, whatever the run's size, whichever way its
+        buckets are addressed and whichever way a window that is not
+        flat resolves its versions."""
         return jitting.tag(groups=self.group_cols, aggs=self.aggs,
                            preds=self.preds, flat=self.flat)
+
+    @property
+    def resolve_form(self) -> str:
+        """How a window merges its versions (``yb_grouped_resolve``)."""
+        return ("flat" if self.flat else "lookback" if self.lookback
+                else "segmented")
+
+    @property
+    def own_rows(self) -> bool:
+        """A window's entries are its ROWS, each at its own rowid and
+        with its own (merged) planes: the flat and the lookback forms.
+        The segmented form's are key groups, numbered along the window,
+        that point at rows."""
+        return self.resolve_form != "segmented"
 
 
 def _eval_factor(expr, plane):
@@ -333,9 +376,11 @@ def addressed(sig: GroupAggSig, run) -> GroupAggSig:
     return dataclasses.replace(sig, NB=NUM_BUCKETS, radix=())
 
 
-def count_bucket_form(sig: GroupAggSig) -> None:
-    """One dispatch of ``sig``'s program in ``yb_grouped_buckets{form}``
-    (a signature without group columns has no buckets: neither form)."""
+def count_dispatch_forms(sig: GroupAggSig) -> None:
+    """One dispatch of ``sig``'s program in ``yb_grouped_resolve{form}``
+    and, with group columns, in ``yb_grouped_buckets{form}`` (a
+    signature without has no buckets: neither form)."""
+    metrics.count_grouped_resolve(sig.resolve_form)
     if sig.group_cols:
         metrics.count_grouped_buckets("direct" if sig.radix else "hashed")
 
@@ -549,10 +594,11 @@ def _window_kernel(sig: GroupAggSig, S: int, *refs):
     stat | p, b.
 
     x_ref[V, S, 128]: the mask words (``_mask_bits``), the planes of
-    ``_kernel_rows`` and, of a run that is not flat, the rowid of each
-    key group's first row; base_ref[1, 128]: the rowid of the window's
-    first row (a flat row's own is that, its tile's offset and its place
-    in the tile: made here, not handed in); cnt0_ref[NBP, 1] /
+    ``_kernel_rows`` and, of a window whose entries are key groups
+    (``sig.own_rows`` False), the rowid of each group's first row;
+    base_ref[1, 128]: the rowid of the window's first row (a row's own
+    is that, its tile's offset and its place in the tile: made here,
+    not handed in); cnt0_ref[NBP, 1] /
     key0_ref[NBP, KW]: the buckets' counts and key pieces of the windows
     before. Outputs: sums[NBP, CP]
     (the window's; column order: key pieces, then ``_columns``'),
@@ -674,7 +720,7 @@ def _window_kernel(sig: GroupAggSig, S: int, *refs):
             lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
 
             def first_row(s, rep):
-                if sig.flat:
+                if sig.own_rows:
                     rowid = base_ref[...] + ((i * S + s) * 128 + lane)
                 else:
                     rowid = x_ref[words + len(planes), pl.ds(s, 1), :]
@@ -713,8 +759,8 @@ def _grouped_window(sig: GroupAggSig, words, plane, base, start_idx,
     ``words`` (``_mask_bits``; ``[N]`` int32 each), ``plane`` as
     ``_columns`` takes it (``[N]`` vectors), ``base`` the rowid of the
     window's first row, ``start_idx[N]`` the first row of each key group
-    in the window (read of a run that is not flat only: a flat row is
-    its own group), and the accumulator's ``count[NB]`` and
+    in the window (read of the segmented form only: in the others a
+    group's entry is its row), and the accumulator's ``count[NB]`` and
     ``key[NB, KP]`` of the windows before. Returns (sums[NB, C] in
     ``_columns``' order, rep[NB], key[NB, KP], collisions, negs,
     scanned); the direct form reads neither ``base`` nor the last three
@@ -727,7 +773,7 @@ def _grouped_window(sig: GroupAggSig, words, plane, base, start_idx,
     KP5, C, CP, NBP, KW = _kernel_dims(sig)
     direct = sig.radix != ()
     rows = list(words) + [plane(*cp) for cp in _kernel_rows(sig)[1]]
-    if not sig.flat and not direct:
+    if not sig.own_rows and not direct:
         rows.append(base + start_idx)
     n = rows[0].shape[0]
     T = _tile_rows(sig, n)
@@ -782,31 +828,42 @@ def _grouped_window(sig: GroupAggSig, words, plane, base, start_idx,
 
 def _rows_window(sig: GroupAggSig, run, b0, row_lo, row_hi, read,
                  pred_literals):
-    """A window by rows: every plane ``resolve_window`` reads laid out
-    by rows (XLA fuses the unpacks into the ungrouped program's
-    reductions; for the kernel it materialises each). Returns
-    (``resolve_window``'s dict, the mask of the entries that are key
-    groups, the match mask, plane)."""
-    r = resolve_window(dataclasses.replace(sig, apply_preds=False), run, b0,
-                       row_lo, row_hi, *read, pred_literals)
-    gvalid = r["ridx"] < r["num_groups"]
+    """A window by rows: every plane the resolve reads laid out by rows
+    (XLA fuses the unpacks into the ungrouped program's reductions; for
+    the kernel it materialises each). The signature says which resolve:
+    flat; *lookback* (a run that is not flat whose largest key group is
+    within ``sig.lookback``: lookback_fold.resolve_window, shifts and
+    selects, a group's entry its first row holding the merged planes);
+    *segmented* (ops.scan.resolve_window's segment ops, a group's entry
+    its number in the window, its planes gathered through ``col_idx``).
+    Bit for bit the same sums either way. Returns (the resolve's dict
+    with ``start_idx``, the mask of the entries that are key groups,
+    the match mask, plane)."""
+    if sig.lookback:
+        r = lookback_fold.resolve_window(sig, run, b0, row_lo, row_hi, *read)
+        gvalid = r["group_start"]
+    else:
+        r = resolve_window(dataclasses.replace(sig, apply_preds=False), run,
+                           b0, row_lo, row_hi, *read, pred_literals)
+        gvalid = r["ridx"] < r["num_groups"]
     cmp_w = r["cmp_w"]
-    col_idx = r["col_idx"]
     col_notnull = r["col_notnull"]
-    # The predicates, on the window's planes themselves where every
-    # row is its own group: resolve_window would index them through
-    # col_idx, and a gather by arange is still a gather on the TPU
-    # (117 us a predicate column a window of 16,384 rows on the v5e).
+    # The predicates and the planes, on the window's (merged) planes
+    # themselves where an entry is its row: the segmented form indexes
+    # them through col_idx, and a gather by arange is still a gather on
+    # the TPU (117 us a predicate column a window of 16,384 rows on the
+    # v5e; 72 us each over the overlay's 8,192).
     m = r["pre_pred"] & gvalid
     if sig.apply_preds:
         for ps, lit in zip(sig.preds, pred_literals):
             m = m & col_notnull[ps.col_id] & _eval_pred(
                 ps, cmp_w.get(ps.col_id), r["arith_w"].get(ps.col_id),
-                slice(None) if sig.flat else col_idx[ps.col_id], lit)
+                slice(None) if sig.own_rows else r["col_idx"][ps.col_id],
+                lit)
 
     def plane(cid, pi):
-        return (cmp_w[cid][:, pi] if sig.flat
-                else cmp_w[cid][col_idx[cid], pi])
+        return (cmp_w[cid][:, pi] if sig.own_rows
+                else cmp_w[cid][r["col_idx"][cid], pi])
 
     return r, gvalid, m, plane
 

@@ -96,10 +96,13 @@ class ScanSig:
                         # merge degenerates to elementwise masks (no
                         # segment ops / gathers) — the post-compaction
                         # fast path
-    lookback: int = 0   # run's max versions per key group (0 = unknown/
-                        # flat): small bounds unlock the shifted-mask
-                        # resolve (ops.lookback_fold) instead of
-                        # segmented scans
+    lookback: int = 0   # a bound on the run's versions per key group
+                        # (lookback_fold.bound: the next power of two; 0
+                        # = flat, or past MAX_LOOKBACK): small bounds
+                        # unlock the shifted-mask resolve
+                        # (lookback_fold.resolve, which the grouped
+                        # program's GroupAggSig.lookback selects too)
+                        # instead of segmented scans
 
     def tag(self) -> str:
         """What the query decides of the program, for its name

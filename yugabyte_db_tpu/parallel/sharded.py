@@ -758,7 +758,7 @@ def sharded_grouped_aggregate(st: ShardedTablets, spec: ScanSpec, engine,
                 "dist_grouped_aggregate", stack_read_bytes(st, sig0), h2d=1,
                 d2h=1)
             # (hashed: a stack holds no "dict" leaf to address by)
-            group_agg.count_bucket_form(sig0)
+            group_agg.count_dispatch_forms(sig0)
     with phase("wait_fetch"):
         out = jax.device_get(out)
 

@@ -232,12 +232,13 @@ def _count_dispatch(entry: str, dev, sig, args, outs) -> None:
 
 
 def _count_grouped_dispatch(entry: str, dev, sig, args, outs) -> None:
-    """``_count_dispatch`` of an ops.group_agg program, and which way its
-    buckets are addressed (``yb_grouped_buckets{form}``)."""
+    """``_count_dispatch`` of an ops.group_agg program, which way its
+    windows resolve their versions (``yb_grouped_resolve{form}``) and
+    which way its buckets are addressed (``yb_grouped_buckets{form}``)."""
     from yugabyte_db_tpu.ops import group_agg
 
     _count_dispatch(entry, dev, sig, args, outs)
-    group_agg.count_bucket_form(sig)
+    group_agg.count_dispatch_forms(sig)
 
 
 def _sig_read_bytes(arrays: dict, sig) -> int:
@@ -2759,9 +2760,9 @@ class TpuStorageEngine(StorageEngine):
     def _grouped_lower(self, crun, spec: ScanSpec, exact_preds):
         """A GROUP BY / expression-aggregate spec over ``crun`` as
         ops.group_agg takes it: ``(make_sig, int_lits, f32_lits)``, with
-        ``make_sig(B, K, flat)`` the signature of a program over ``B``
-        blocks in windows of ``K`` (the run's own for the engine's
-        program, a mesh shard's for parallel.sharded's), or None where
+        ``make_sig(B, K, flat, lookback=0)`` the signature of a program
+        over ``B`` blocks in windows of ``K`` (the run's own for the
+        engine's program, a mesh shard's for parallel.sharded's), or None where
         the spec is not device-lowerable."""
         from yugabyte_db_tpu.ops import group_agg
         from yugabyte_db_tpu.storage import expr as X
@@ -2826,11 +2827,12 @@ class TpuStorageEngine(StorageEngine):
         pred_sigs = self._pred_sigs_only(exact_preds)
         int_lits, f32_lits = self._pred_host_literals(exact_preds)
 
-        def make_sig(B: int, K: int, flat: bool):
+        def make_sig(B: int, K: int, flat: bool, lookback: int = 0):
             return group_agg.GroupAggSig(
                 B=B, R=crun.R, K=K, NB=group_agg.NUM_BUCKETS,
                 cols=self._col_sigs(), preds=pred_sigs, apply_preds=True,
-                flat=flat, group_cols=tuple(group_cols), aggs=tuple(gaggs))
+                flat=flat, group_cols=tuple(group_cols), aggs=tuple(gaggs),
+                lookback=lookback)
 
         return make_sig, int_lits, f32_lits
 
@@ -2841,7 +2843,7 @@ class TpuStorageEngine(StorageEngine):
         for empty ranges, or ("params", (sig, params)) ready for a
         single or vmapped-batch dispatch (``params``: the program's one
         int32 vector, group_agg.pack_params)."""
-        from yugabyte_db_tpu.ops import group_agg, row_gather
+        from yugabyte_db_tpu.ops import group_agg, lookback_fold, row_gather
 
         crun = trun.crun
         lowered = self._grouped_lower(crun, spec, exact_preds)
@@ -2853,7 +2855,12 @@ class TpuStorageEngine(StorageEngine):
         R = crun.R
         dev = trun.dev
         K = group_agg.window_blocks(dev.B, R)
-        sig = make_sig(dev.B, K, crun.max_group_versions <= 1)
+        # A run that is not flat resolves its versions by bounded
+        # lookback where its largest key group allows (the overlay's
+        # mini-run: a tombstone over its base row, 2), by segment ops
+        # past the bound: what the build recorded of the run decides.
+        sig = make_sig(dev.B, K, crun.max_group_versions <= 1,
+                       lookback_fold.bound(crun.max_group_versions))
         # The buckets by dictionary code where the resident leaves of
         # the group columns are dictionaries (a run uploaded encoded, a
         # device flush's) whose values the host holds to read a bucket
@@ -3698,15 +3705,11 @@ class TpuStorageEngine(StorageEngine):
         # lookback rides in the compile signature: set it ONLY when the
         # lookback route can serve this run (otherwise every distinct
         # version count would recompile the byte-identical fallbacks),
-        # and round up to a power of two so drifting counts share at
-        # most 5 compiled variants.
-        lb = 0
-        if not flat and \
-                crun.max_group_versions <= lookback_fold.MAX_LOOKBACK:
-            lb = 1 << (crun.max_group_versions - 1).bit_length()
-        sig = dscan.ScanSig(B=trun.dev.B, R=R, K=K, cols=self._col_sigs(),
-                            preds=tuple(sigs), aggs=dev_aggs,
-                            apply_preds=True, flat=flat, lookback=lb)
+        # rounded up to a power of two (lookback_fold.bound).
+        sig = dscan.ScanSig(
+            B=trun.dev.B, R=R, K=K, cols=self._col_sigs(),
+            preds=tuple(sigs), aggs=dev_aggs, apply_preds=True, flat=flat,
+            lookback=lookback_fold.bound(crun.max_group_versions))
         if flat_fold.supports(sig):
             route = "flat"
         elif lookback_fold.supports(sig):

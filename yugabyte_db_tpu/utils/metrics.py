@@ -989,6 +989,36 @@ def grouped_buckets() -> dict[str, int]:
 grouped_buckets()
 
 
+GROUPED_RESOLVE_FORMS = ("flat", "lookback", "segmented")
+
+
+def _grouped_resolve_counter(form: str) -> Counter:
+    return _span_entity(("grouped_resolve", form),
+                        form=form).counter("yb_grouped_resolve")
+
+
+def count_grouped_resolve(form: str) -> None:
+    """``yb_grouped_resolve{form=flat|lookback|segmented}``: one
+    ops.group_agg program was dispatched, with group columns or without
+    (beside ``yb_device_dispatches``: a vmapped batch and a mesh program
+    are one each), whose windows merge their versions elementwise
+    (``flat``: a run of one version a key), by static shifts and selects
+    (``lookback``: a run that is not flat whose largest key group is
+    within ops.lookback_fold.MAX_LOOKBACK, as the delta overlay's
+    mini-run) or by segment ops and gathers (``segmented``: past the
+    bound, and every mesh stack that is not flat)."""
+    _grouped_resolve_counter(form).increment()
+
+
+def grouped_resolve() -> dict[str, int]:
+    """Current ``yb_grouped_resolve`` by form."""
+    return {f: _grouped_resolve_counter(f).get()
+            for f in GROUPED_RESOLVE_FORMS}
+
+
+grouped_resolve()
+
+
 MESH_SCAN_KINDS = ("agg", "rows")
 MESH_SCAN_OUTCOMES = ("served", "ineligible", "chip_loss")
 
